@@ -24,20 +24,6 @@ table, async pull/push SGD) for TPUs:
 
 __version__ = "0.1.0"
 
-# Partitionable threefry is sharding-invariant by construction: the legacy
-# lowering lets XLA specialize random-bit computation to the output sharding,
-# so jax.random under jit with sharded operands/outputs (e.g. the grouped
-# mesh plane's negative-pool sampling inside a donated-state train_step, or
-# table init under out_shardings) produces DIFFERENT values per mesh layout.
-# Training must not depend on mesh shape; flip the default library-wide.
-# An explicit JAX_THREEFRY_PARTITIONABLE=0 env still wins (user override).
-import os as _os
-
-if "JAX_THREEFRY_PARTITIONABLE" not in _os.environ:
-    import jax as _jax
-
-    _jax.config.update("jax_threefry_partitionable", True)
-
 from swiftsnails_tpu.utils.config import Config, global_config, load_config
 
 __all__ = [

@@ -1,15 +1,24 @@
 """ctypes bindings for the native data-pipeline core (libsnails.cpp).
 
 Compiled on demand with g++ (no pybind11 — plain C ABI + ctypes, per the
-environment's binding guidance) and cached next to the source. Every entry
-point has a pure-Python fallback in :mod:`swiftsnails_tpu.data`; callers check
-:func:`available` or rely on the wrappers which raise cleanly when the
-toolchain is unavailable.
+environment's binding guidance) next to the source, under a name keyed on
+the source's content, so a fresh copy of the tree (whose mtimes say nothing)
+never loads a library built from other source. ``*.so`` stays out of git.
+
+Every entry point has a pure-Python twin in :mod:`swiftsnails_tpu.data`, at
+roughly a sixth of the rate. Training picks between them with
+:func:`use_native`: the native pipeline unless the config says
+``use_native: 0`` — and if the library then fails to build, that is a
+:class:`NativeBuildError` carrying the compiler's output, not a quiet drop
+to the slow producers. :func:`available` remains for optional callers and
+for tests that skip without a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -19,31 +28,56 @@ import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "libsnails.cpp")
-# SSN_NATIVE_SO points at an alternate build (e.g. the ASan/TSan builds made
-# by tools/native_sanitize.sh); the default is built on demand next to _SRC.
-_SO = os.environ.get("SSN_NATIVE_SO") or os.path.join(_DIR, "libsnails.so")
+_CXX = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread"]
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_error: Optional[str] = None
 
 
-def _build() -> Optional[str]:
-    """Compile the shared library if stale; returns error text or None."""
-    if os.environ.get("SSN_NATIVE_SO"):
-        return None if os.path.exists(_SO) else f"SSN_NATIVE_SO not found: {_SO}"
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+class NativeBuildError(RuntimeError):
+    """The native library could not be built or loaded."""
+
+
+def _so_path() -> str:
+    """Where the library for the committed source lives.
+
+    ``SSN_NATIVE_SO`` points at an alternate build (e.g. the ASan/TSan
+    builds made by tools/native_sanitize.sh); otherwise the name carries a
+    digest of the source and the compile line."""
+    override = os.environ.get("SSN_NATIVE_SO")
+    if override:
+        return override
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_DIR, f"libsnails-{h.hexdigest()[:16]}.so")
+
+
+def _build(so: str) -> Optional[str]:
+    """Compile ``so`` from the source if absent; returns error text or None."""
+    if os.path.exists(so):
         return None
-    cmd = [
-        "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        "-o", _SO, _SRC,
-    ]
+    if os.environ.get("SSN_NATIVE_SO"):
+        return f"SSN_NATIVE_SO not found: {so}"
+    # build under a private name, then rename: concurrent builders (replica
+    # processes starting together) each publish a complete file or nothing
+    tmp = f"{so[:-3]}.{os.getpid()}.tmp.so"
+    cmd = _CXX + ["-o", tmp, _SRC]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
     except (OSError, subprocess.TimeoutExpired) as e:
-        return f"g++ invocation failed: {e}"
+        return f"{' '.join(cmd)}: invocation failed: {e}"
     if proc.returncode != 0:
-        return f"g++ failed:\n{proc.stderr}"
+        return (f"{' '.join(cmd)}: exit {proc.returncode}\n"
+                f"{proc.stderr or proc.stdout}")
+    os.replace(tmp, so)
+    for stale in glob.glob(os.path.join(_DIR, "libsnails*.so")):
+        if stale != so:
+            try:
+                os.remove(stale)
+            except OSError:
+                pass
     return None
 
 
@@ -54,17 +88,17 @@ def _load():
     with _lib_lock:
         if _lib is not None or _build_error is not None:
             return _lib
-        err = _build()
+        so = _so_path()
+        err = _build(so)
         if err is not None:
             _build_error = err
             return None
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         c = ctypes
         try:
             _bind(lib, c)
         except AttributeError as e:
-            # e.g. SSN_NATIVE_SO pointing at a build of older source: treat
-            # as unavailable (callers fall back to Python) instead of raising
+            # e.g. SSN_NATIVE_SO pointing at a build of older source
             _build_error = f"native library missing symbols (stale build?): {e}"
             return None
         _lib = lib
@@ -157,8 +191,19 @@ def build_error() -> Optional[str]:
 def _require():
     lib = _load()
     if lib is None:
-        raise RuntimeError(f"native pipeline unavailable: {_build_error}")
+        raise NativeBuildError(f"native pipeline unavailable: {_build_error}")
     return lib
+
+
+def use_native(config) -> bool:
+    """Whether this run takes the native pipeline. ``use_native: 0`` opts
+    out; otherwise the library must build — a failed build raises
+    :class:`NativeBuildError` rather than leaving a host-bound run with no
+    message."""
+    if not config.get_bool("use_native", True):
+        return False
+    _require()
+    return True
 
 
 def _ptr(a: np.ndarray):

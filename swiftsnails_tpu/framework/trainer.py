@@ -1093,7 +1093,7 @@ class TrainLoop:
         ``ledger_path`` is configured, append the durable run record."""
         try:
             from swiftsnails_tpu.telemetry.goodput import (
-                goodput_report, peaks_from_config,
+                goodput_report, peaks_for,
             )
             from swiftsnails_tpu.telemetry.ledger import env_fingerprint
 
@@ -1108,9 +1108,7 @@ class TrainLoop:
                 audit=audit,
                 steps=steps,
                 items=items,
-                peaks=peaks_from_config(
-                    self.trainer.config, getattr(devs[0], "device_kind", None)
-                ),
+                peaks=peaks_for(devs[0].device_kind, devs[0].platform),
                 n_chips=n_chips,
             )
             self.metrics.log({"goodput": report, "step": steps})

@@ -160,7 +160,7 @@ class SparseCTRTrainer(Trainer):
         else:
             from swiftsnails_tpu.data import native
 
-            if cfg.get_bool("use_native", True) and native.available():
+            if native.use_native(cfg):
                 self.labels, self.feats = native.read_ctr(
                     cfg.get_str("data"), self.num_fields
                 )
@@ -415,7 +415,7 @@ class SparseCTRTrainer(Trainer):
         from swiftsnails_tpu.data.ctr import read_ctr_stream as py_stream
 
         start, end = self._byte_span
-        if self.config.get_bool("use_native", True) and native.available():
+        if native.use_native(self.config):
             yield from native.read_ctr_stream(
                 self._data_path, self.num_fields, rows_per_chunk, start, end
             )

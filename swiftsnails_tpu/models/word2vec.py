@@ -511,7 +511,10 @@ class Word2VecTrainer(Trainer):
         constraints and optimization barriers on the operands or result do
         not stop it — the sum IS the lowering of the concat. Expressing the
         same value as pad-to-length + elementwise add never invokes the
-        concat partitioner, and elementwise ops partition soundly."""
+        concat partitioner, and elementwise ops partition soundly.
+        (Observed on jax 0.4.x. Kept under jax 0.9.0: with it the 2x2 mesh
+        on four chips matches the 1x1 mesh bit for bit — chip_smoke.py leg
+        C — and it has not been re-tested WITHOUT it on the chip.)"""
         if self.mesh is None or len(parts) == 1:
             return jnp.concatenate(parts)
         total = sum(p.shape[0] for p in parts)
@@ -522,6 +525,28 @@ class Word2VecTrainer(Trainer):
             out = padded if out is None else out + padded
             off += p.shape[0]
         return out
+
+    def _shard_substeps(self, c_t, x_t):
+        """Lay the ``[t, b, ...]`` substep stack out with ``b`` split over
+        ``data``, as every substep's ``shard_map`` will want it. The macro
+        batch arrives split over ``data`` along its one leading axis; left
+        to pick a layout for the reshaped stack itself, the partitioner
+        produces a scanned program the XLA TPU compiler rejects on a 2x2
+        mesh (``Reshape should have supported layout before reaching the
+        emitter``, jax 0.9.0 / libtpu 0.0.34; ``steps_per_call: 1`` and a
+        1x1 mesh compile). Which rows a substep trains on does not change:
+        the first-macro loss on four chips equals the 1x1 mesh's bit for
+        bit (chip_smoke.py leg C)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from swiftsnails_tpu.parallel.mesh import DATA_AXIS
+
+        def split_b(x):
+            spec = P(None, DATA_AXIS, *([None] * (x.ndim - 2)))
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(self.mesh, spec))
+
+        return split_b(c_t), split_b(x_t)
 
     def _id_cat(self, *parts):
         """Concatenate row-id vectors (mesh-safe, see _mesh_safe_cat)."""
@@ -617,7 +642,7 @@ class Word2VecTrainer(Trainer):
     def batches(self) -> Iterator[Dict[str, np.ndarray]]:
         from swiftsnails_tpu.data import native
 
-        use_native = self.config.get_bool("use_native", True) and native.available()
+        use_native = native.use_native(self.config)
         rng = np.random.default_rng(self.seed)
         counts = self.vocab.counts
         # progress = fraction of this process's corpus consumed (raw tokens x
@@ -1274,6 +1299,8 @@ class Word2VecTrainer(Trainer):
         keys = jax.random.split(rng, t)
         c_t = centers.reshape(t, b)
         x_t = contexts.reshape((t, b) + contexts.shape[1:])
+        if self.mesh is not None:
+            c_t, x_t = self._shard_substeps(c_t, x_t)
         on_grouped_mesh = (
             self.fused and self.grouped and self.mesh is not None
         )

@@ -118,6 +118,18 @@ class ReplicaSpawner:
         env.setdefault("JAX_PLATFORMS", "cpu")
         if self.env:
             env.update(self.env)
+        if env["JAX_PLATFORMS"].strip().lower() != "cpu":
+            from swiftsnails_tpu.utils.platform_pin import holds_accelerator
+
+            if holds_accelerator():
+                # a chip belongs to one process: the child would hang in
+                # backend init behind this process, not fail
+                raise RuntimeError(
+                    f"replica asked for JAX_PLATFORMS="
+                    f"{env['JAX_PLATFORMS']!r} but this process already "
+                    "holds the accelerator; serve replicas on cpu (the "
+                    "default) or spawn them from a process that has not "
+                    "initialized jax")
         proc = subprocess.Popen(
             cmd, stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
             env=env, text=True)

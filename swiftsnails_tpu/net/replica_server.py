@@ -158,9 +158,9 @@ class ServantRpcServer:
 
 
 def main(argv=None) -> int:
-    from swiftsnails_tpu.utils.platform_pin import repin_from_env
+    from swiftsnails_tpu.utils.compile_cache import configure_compile_cache
 
-    repin_from_env()
+    configure_compile_cache()
     ap = argparse.ArgumentParser(
         prog="replica_server",
         description="serve one checkpoint over TCP (pull/topk/score/health)")

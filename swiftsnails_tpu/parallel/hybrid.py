@@ -44,8 +44,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftsnails_tpu.utils.compat import shard_map
-
 from swiftsnails_tpu.parallel.access import AccessMethod
 from swiftsnails_tpu.parallel.comm import (
     reduce_scatter_quantized,
@@ -191,7 +189,7 @@ def head_pull(mesh: Mesh, head: jax.Array, rows: jax.Array,
 
     out_spec = P(DATA_AXIS, None, None) if (
         layout == "packed") else P(DATA_AXIS, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS)),
@@ -308,7 +306,7 @@ def head_push(mesh: Mesh, head: jax.Array, head_slots: Dict[str, jax.Array],
         return new_p, new_s
 
     slot_spec = P(DATA_AXIS) if zero else P()
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), {k: slot_spec for k in slot_keys},

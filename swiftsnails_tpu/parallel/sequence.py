@@ -31,7 +31,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from swiftsnails_tpu.parallel.mesh import SEQ_AXIS
-from swiftsnails_tpu.utils.compat import shard_map
 
 _NEG_INF = -1e30
 
@@ -128,7 +127,7 @@ def ring_attention(
     sharding. L must divide evenly by the seq axis size.
     """
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -171,7 +170,7 @@ def ulysses_attention(
             f"heads {q.shape[2]} not divisible by {axis_name} axis {mesh.shape[axis_name]}"
         )
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

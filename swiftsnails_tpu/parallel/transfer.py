@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from swiftsnails_tpu.utils.compat import shard_map
-
 from swiftsnails_tpu.parallel.access import AccessMethod
 from swiftsnails_tpu.parallel.comm import (
     all_gather_quantized,
@@ -124,7 +122,7 @@ def pull_collective(
         vals = jnp.where(owned[:, None], vals, 0)
         return psum_quantized(vals, MODEL_AXIS, comm_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_pull,
         mesh=mesh,
         in_specs=(P(MODEL_AXIS, None), P(DATA_AXIS)),
@@ -174,7 +172,7 @@ def push_collective(
         return apply_rows(table_shard, slot_shards, uniq, merged, access, lr)
 
     shard_spec = P(MODEL_AXIS, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -215,7 +213,7 @@ def pull_collective_packed(
         vals = jnp.where(owned[:, None, None], vals, 0)
         return psum_quantized(vals, MODEL_AXIS, comm_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_pull,
         mesh=mesh,
         in_specs=(P(MODEL_AXIS, None, None), P(DATA_AXIS)),
@@ -259,7 +257,7 @@ def push_collective_packed(
         return new.table, dict(new.slots)
 
     shard_spec = P(MODEL_AXIS, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -321,7 +319,7 @@ def pull_collective_packed_small(
         vals = jnp.where(owned[:, None], vals, 0)
         return psum_quantized(vals, MODEL_AXIS, comm_dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_pull,
         mesh=mesh,
         in_specs=(P(MODEL_AXIS, None, None), P(DATA_AXIS)),
@@ -368,7 +366,7 @@ def push_collective_packed_small(
         return new.table, dict(new.slots)
 
     shard_spec = P(MODEL_AXIS, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -447,7 +445,7 @@ def push_collective_bucketed(
         return table, slots, dropped
 
     shard_spec = P(MODEL_AXIS, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -544,7 +542,7 @@ def pull_collective_packed_dedup(
         out = vals.at[inv].get(mode="promise_in_bounds")
         return out, uniq, inv, lax.psum(overflow, DATA_AXIS)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local_pull,
         mesh=mesh,
         in_specs=(P(MODEL_AXIS, None, None), P(DATA_AXIS)),
@@ -612,7 +610,7 @@ def push_collective_packed_dedup(
     shard_spec = P(MODEL_AXIS, None, None)
     idx_args = () if index is None else tuple(index)
     idx_specs = () if index is None else (P(DATA_AXIS), P(DATA_AXIS))
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -667,7 +665,7 @@ def push_collective_packed_bucketed(
         return new.table, dict(new.slots), dropped
 
     shard_spec = P(MODEL_AXIS, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local_push,
         mesh=mesh,
         in_specs=(shard_spec, {k: shard_spec for k in slot_keys},
@@ -742,7 +740,7 @@ def scatter_slots_collective(mesh: Mesh, plane: jax.Array, slot_ids,
         local = jnp.where((local >= 0) & (local < per), local, per)
         return shard.at[local].set(vals.astype(shard.dtype), mode="drop")
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, P(), P()),
         out_specs=spec,

@@ -14,7 +14,7 @@ SURVEY §5 lists glog lines and a chrono ``Timer`` as its entire surface):
   async collective forms alike;
 * :mod:`~swiftsnails_tpu.telemetry.ledger` — durable append-only JSONL run
   ledger (atomic tmp+rename writes): bench results, training runs, outage
-  events, black-box dumps; ``BENCH_LAST_GOOD.json`` is a derived view;
+  events, black-box dumps — a record, never replayed as a result;
 * :mod:`~swiftsnails_tpu.telemetry.goodput` — MFU, step-time decomposition
   (compute vs collective vs host-blocked), words/sec-vs-roofline, combining
   tracer spans with the HLO audit's cost analysis;
@@ -79,9 +79,7 @@ from swiftsnails_tpu.telemetry.goodput import (
 from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
     config_hash,
-    derive_last_good,
     env_fingerprint,
-    load_bench_cache,
     validate_bench_payload,
 )
 from swiftsnails_tpu.telemetry.ops import render_ops, render_ops_from_ledger
@@ -134,10 +132,8 @@ __all__ = [
     "collective_stats",
     "compiled_collective_bytes",
     "config_hash",
-    "derive_last_good",
     "env_fingerprint",
     "goodput_report",
-    "load_bench_cache",
     "peaks_for",
     "step_time_decomposition",
     "summarize_file",

@@ -22,9 +22,12 @@ wire: the sync form's result is the 1/N owned slice of the summed operand,
 so billing the result alone undercounts the traffic N-fold (every element
 of the full operand crosses the interconnect exactly as in an all-reduce's
 reduce phase). For ``reduce-scatter``/``all-reduce-scatter`` the billed
-bytes are therefore the max shape atom across the instruction's operand
-list as well as its result, with the same dtype-exact sub-byte rule
-(``(n*bits+7)//8``) as everywhere else.
+bytes are therefore the max shape atom across the instruction's operands
+as well as its result, with the same dtype-exact sub-byte rule
+(``(n*bits+7)//8``) as everywhere else. The HLO text jax 0.9.0 emits names
+operands without their shapes (``reduce-scatter(%bitcast)``), so an
+operand's shape is looked up from its own defining line earlier in the same
+computation; shapes printed inline (older text) are read where they stand.
 """
 
 from __future__ import annotations
@@ -68,6 +71,11 @@ _DEFINING_RE = re.compile(
     r"(?P<op>%s)(?P<start>-start)?\(" % "|".join(COLLECTIVE_OPS)
 )
 _SHAPE_ATOM_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
+# any instruction's "<name> = <shape>" head, and operand references
+_ANY_DEF_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
+    r"(?P<shape>\([^=]*?\)|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)\s")
+_OPERAND_REF_RE = re.compile(r"%([\w.\-]+)")
 _OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _SCOPE_RE = re.compile(r"(ssn_[\w\-.]+)")
 
@@ -124,7 +132,13 @@ def collective_stats(hlo_text: str) -> Dict:
     by_scope: Dict[str, int] = {}
     by_table: Dict[str, int] = {}
     total = 0
+    defs: Dict[str, str] = {}  # instruction name -> shape, this computation
     for line in hlo_text.splitlines():
+        if line.rstrip().endswith("{"):  # a computation header: new scope
+            defs = {}
+        d = _ANY_DEF_RE.match(line)
+        if d is not None:
+            defs[d.group("name")] = d.group("shape")
         m = _DEFINING_RE.search(line)
         if m is None:
             continue
@@ -144,6 +158,10 @@ def collective_stats(hlo_text: str) -> Dict:
             if cut != -1:
                 tail = tail[:cut]
             nbytes = max(nbytes, _shape_bytes(tail))
+            # operands named without shapes: the call parens close at the
+            # first ")", and each %name resolves to its defining shape
+            for ref in _OPERAND_REF_RE.findall(tail.split(")", 1)[0]):
+                nbytes = max(nbytes, _shape_bytes(defs.get(ref, "")))
         entry = ops.setdefault(op, {"count": 0, "bytes": 0})
         entry["count"] += 1
         entry["bytes"] += nbytes

@@ -13,70 +13,59 @@ Combines the two raw signal sources PR 1 built —
 compute/memory-roofline bound for the compiled step).
 
 Everything here is pure host-side arithmetic over already-recorded data:
-no device work, no extra hot-path cost. Peaks come from a per-device-kind
-table (published chip specs) overridable via the ``peak_flops`` /
-``peak_hbm_gbps`` / ``peak_ici_gbps`` config keys — on CPU (tier-1 tests,
-smoke runs) there is no meaningful peak, so MFU degrades to ``None``
-rather than inventing a number.
+no device work, no extra hot-path cost. Peaks come from one table keyed by
+the exact ``device_kind`` jax reports. A TPU whose kind is not in the table
+is an error — never ``None`` and never a neighbour's number; off the chip
+(tier-1 tests, CPU smoke runs) there is no peak and MFU is ``None``.
 """
 
 from __future__ import annotations
 
 from typing import Dict, Iterable, Optional, Sequence
 
-# Published per-chip peaks: (bf16 TFLOP/s, HBM GB/s, ICI GB/s per link-set).
-# Keyed by substrings of jax's ``device_kind`` / platform names; first match
-# wins. These anchor MFU the way the pjit-at-scale reports do (arXiv:
-# 2204.06514 reports hardware FLOP/s utilization against the chip peak).
-_PEAKS = (
-    ("v6", (918.0, 1640.0, 448.0)),       # Trillium / v6e
-    ("v5p", (459.0, 2765.0, 600.0)),
-    ("v5 lite", (197.0, 819.0, 200.0)),   # v5e device_kind is "TPU v5 lite"
-    ("v5e", (197.0, 819.0, 200.0)),
-    ("v5", (459.0, 2765.0, 600.0)),
-    ("v4", (275.0, 1228.0, 300.0)),
-    ("v3", (123.0, 900.0, 100.0)),
-    ("v2", (46.0, 700.0, 62.0)),
-)
+# Published per-chip peaks, keyed by jax's ``device_kind``:
+# (bf16 TFLOP/s, HBM GB/s, chip-to-chip interconnect GB/s). Source: Google
+# Cloud TPU documentation, the per-generation system-architecture pages
+# ("TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s interconnect).
+PEAKS = {
+    "TPU v6 lite": (918.0, 1640.0, 448.0),   # Trillium / v6e
+    "TPU v5 lite": (197.0, 819.0, 200.0),    # v5e
+    "TPU v4": (275.0, 1228.0, 300.0),
+    "TPU v3": (123.0, 900.0, 100.0),
+    "TPU v2": (46.0, 700.0, 62.0),
+}
 
 
-def peaks_for(device_kind: Optional[str]) -> Dict[str, Optional[float]]:
-    """Peak FLOP/s, HBM B/s, ICI B/s for a device kind (None when unknown,
-    e.g. CPU — never invent a utilization denominator)."""
-    if device_kind:
-        kind = device_kind.lower()
-        for key, (tf, hbm, ici) in _PEAKS:
-            if key in kind:
-                return {
-                    "flops_per_s": tf * 1e12,
-                    "hbm_bytes_per_s": hbm * 1e9,
-                    "ici_bytes_per_s": ici * 1e9,
-                    "source": f"builtin table ({key})",
-                }
+class UnknownDeviceError(LookupError):
+    """A TPU ``device_kind`` with no row in :data:`PEAKS`."""
+
+
+def peaks_for(device_kind: Optional[str],
+              platform: Optional[str] = None) -> Dict[str, Optional[float]]:
+    """Peak FLOP/s, HBM B/s, interconnect B/s for ``device_kind``.
+
+    Exact-key lookup. An unknown kind on ``platform == "tpu"`` raises
+    :class:`UnknownDeviceError`; anything else (CPU, no device) has no
+    utilization denominator and gets ``None`` peaks."""
+    row = PEAKS.get(device_kind) if device_kind else None
+    if row is not None:
+        tf, hbm, ici = row
+        return {
+            "flops_per_s": tf * 1e12,
+            "hbm_bytes_per_s": hbm * 1e9,
+            "ici_bytes_per_s": ici * 1e9,
+            "source": f"goodput.PEAKS[{device_kind!r}]",
+        }
+    if platform == "tpu":
+        raise UnknownDeviceError(
+            f"device_kind {device_kind!r} is not in goodput.PEAKS "
+            f"({sorted(PEAKS)}); add its published peaks with their source")
     return {
         "flops_per_s": None,
         "hbm_bytes_per_s": None,
         "ici_bytes_per_s": None,
-        "source": "unknown device kind",
+        "source": "no accelerator",
     }
-
-
-def peaks_from_config(cfg, device_kind: Optional[str]) -> Dict:
-    """Table peaks with config-key overrides (``peak_flops`` in FLOP/s,
-    ``peak_hbm_gbps`` / ``peak_ici_gbps`` in GB/s)."""
-    peaks = peaks_for(device_kind)
-    if cfg is not None:
-        pf = cfg.get_float("peak_flops", 0.0)
-        if pf > 0:
-            peaks["flops_per_s"] = pf
-            peaks["source"] = "config"
-        hbm = cfg.get_float("peak_hbm_gbps", 0.0)
-        if hbm > 0:
-            peaks["hbm_bytes_per_s"] = hbm * 1e9
-        ici = cfg.get_float("peak_ici_gbps", 0.0)
-        if ici > 0:
-            peaks["ici_bytes_per_s"] = ici * 1e9
-    return peaks
 
 
 # ------------------------------------------------------ span decomposition ---
@@ -177,7 +166,7 @@ def goodput_report(
     * ``steps`` / ``items``: loop totals (items = words/examples);
     * ``step_seconds``: measured per-step seconds — derived from the spans
       when absent;
-    * ``peaks``: :func:`peaks_for` / :func:`peaks_from_config` output;
+    * ``peaks``: :func:`peaks_for` output;
     * ``n_chips``: devices sharing the audited step's FLOPs (per-chip MFU).
     """
     peaks = peaks or peaks_for(None)

@@ -1,14 +1,9 @@
 """Durable run ledger: append-only, atomically-written JSONL run records.
 
-The reference system's only run record was stdout from Hadoop reducers; this
-repo's was barely better — round 5's headline bench artifact lived in one
-fragile ``BENCH_LAST_GOOD.json`` that a workspace restart erased (it had to be
-hand-reconstructed, ``BENCH_r05.json`` ``errors[0]``), and a 27-failure
-accelerator outage was logged by hand in ``docs/OUTAGE_r5_probe.txt``. The
-ledger replaces both: every bench run, training run, outage/probe event, and
-black-box dump appends one self-describing record, and the single-file cache
-becomes a **derived view** regenerated from the ledger
-(:func:`derive_last_good`).
+The reference system's only run record was stdout from Hadoop reducers.
+Here every bench run, training run, outage event and black-box dump appends
+one self-describing record; nothing is ever replayed from it as a result —
+a bench that cannot measure fails, it does not re-emit an old number.
 
 Durability contract: every append rewrites the file via write-tmp + fsync +
 rename (+ directory fsync), so the ledger on disk is *always* a complete,
@@ -23,7 +18,7 @@ Record envelope::
      "ts": "<UTC ISO8601>", "env": {...fingerprint...}, ...kind fields...}
 
 (``chaos`` = an injected drill fault, ``checkpoint`` = a verified save
-commit, ``cache_error`` = a corrupt bench cache OR checkpoint rejected /
+commit, ``cache_error`` = a corrupt tier plane or checkpoint rejected /
 walked back — see ``ledger-report --failures`` for the timeline view.)
 
 ``python -m swiftsnails_tpu ledger-report`` (or ``tools/ledger_report.py``)
@@ -43,8 +38,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 SCHEMA_VERSION = 1
 
-# default ledger location: next to BENCH_LAST_GOOD.json at the repo root,
-# overridable per-call (config `ledger_path`) or via env for the bench
+# default ledger location: the repo root (listed in .gitignore — a run must
+# not dirty the tree), overridable per-call (config `ledger_path`) or via
+# env for the bench
 DEFAULT_LEDGER = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "RUN_LEDGER.jsonl",
@@ -75,11 +71,9 @@ def env_fingerprint(include_devices: bool = False) -> Dict:
     """Environment identity of a run: git sha, jax/jaxlib/libtpu versions,
     python, host — and device topology when ``include_devices`` is set.
 
-    ``include_devices`` intentionally defaults to False: querying devices
-    *initializes the backend*, and the bench must never touch the
-    accelerator before its pre-flight probe (the round-1 wedged-grant
-    lesson). Pass True only where jax is already live, or fill the
-    ``devices`` block from probe output instead.
+    ``include_devices`` defaults to False because querying devices
+    *initializes the backend* (and so takes the chip): pass True only where
+    jax is already live in this process.
     """
     fp: Dict = {
         "git_sha": _git_sha(),
@@ -229,10 +223,10 @@ class Ledger:
         return recs[-1] if recs else None
 
 
-# --------------------------------------------- bench cache (derived view) ---
+# ------------------------------------------------- bench payload schema ---
 
-# minimal self-consistency schema for a bench result payload: what the
-# outage-fallback path needs to emit a trustworthy headline
+# minimal self-consistency schema for a bench result payload (what
+# ``--baseline-file`` needs to pin a regression gate on)
 _BENCH_REQUIRED = {
     "metric": str,
     "value": (int, float),
@@ -242,7 +236,7 @@ _BENCH_REQUIRED = {
 
 
 def validate_bench_payload(payload) -> List[str]:
-    """Problems that make a bench payload unusable as a cached headline."""
+    """Problems that make a bench payload unusable as a pinned baseline."""
     if not isinstance(payload, dict):
         return [f"payload is {type(payload).__name__}, not an object"]
     problems = []
@@ -257,66 +251,6 @@ def validate_bench_payload(payload) -> List[str]:
     if isinstance(value, (int, float)) and not value > 0:
         problems.append(f"non-positive headline value {value!r}")
     return problems
-
-
-def load_bench_cache(path: str) -> Tuple[Optional[Dict], Optional[str]]:
-    """Read + schema-validate a BENCH_LAST_GOOD-style cache file.
-
-    Returns ``(payload, None)`` on success, ``(None, reason)`` on a missing,
-    partial, or unparseable cache — the caller records the reason as a
-    ledger event instead of crashing (or silently emitting garbage).
-    """
-    try:
-        with open(path, "r", encoding="utf-8") as f:
-            payload = json.load(f)
-    except OSError as e:
-        return None, f"cache unreadable: {e}"
-    except ValueError as e:
-        return None, f"cache unparseable (partial write?): {e}"
-    problems = validate_bench_payload(payload)
-    if problems:
-        return None, "cache failed schema validation: " + "; ".join(problems)
-    return payload, None
-
-
-def derive_last_good(
-    ledger: Ledger, out_path: str
-) -> Tuple[Optional[Dict], Optional[str]]:
-    """Regenerate the BENCH_LAST_GOOD.json **derived view** from the ledger.
-
-    The newest ``bench`` record flagged ``cacheable`` whose payload passes
-    schema validation wins. Returns ``(payload_written, None)`` or
-    ``(None, reason)`` when the ledger holds no cacheable record.
-    """
-    candidates = [
-        r for r in ledger.records("bench")
-        if r.get("cacheable") and isinstance(r.get("payload"), dict)
-    ]
-    for rec in reversed(candidates):
-        payload = rec["payload"]
-        if validate_bench_payload(payload):
-            continue
-        payload = dict(payload)
-        payload.setdefault("measured_at", rec.get("ts"))
-        atomic_write_json(out_path, payload)
-        return payload, None
-    return None, "no cacheable bench record in ledger"
-
-
-def outage_summary(ledger: Ledger) -> Optional[Dict]:
-    """Structured summary of the most recent outage: the line that used to be
-    hand-written into ``docs/OUTAGE_*.txt``."""
-    outages = ledger.records("outage")
-    if not outages:
-        return None
-    last = outages[-1]
-    return {
-        "at": last.get("ts"),
-        "probe_duration_s": last.get("probe_duration_s"),
-        "rc": last.get("rc"),
-        "error": last.get("error"),
-        "outages_recorded": len(outages),
-    }
 
 
 # -------------------------------------------------------------- reporting ---
@@ -350,19 +284,11 @@ def render_report(ledger: Ledger) -> str:
         for r in bench[-5:]:
             p = r.get("payload", {}) if isinstance(r.get("payload"), dict) else {}
             env = r.get("env", {}) or {}
-            flags = []
-            if r.get("cacheable"):
-                flags.append("cacheable")
-            if p.get("cached"):
-                flags.append("cached")
-            if p.get("reconstructed"):
-                flags.append("reconstructed")
             lines.append(
                 f"  {r.get('ts', '?')}  value={_fmt_num(p.get('value', 0))} "
                 f"{p.get('unit', '')}  path={p.get('path')}  "
                 f"platform={p.get('platform')}  git={str(env.get('git_sha'))[:9]}"
                 f"  config_hash={r.get('config_hash', '?')}"
-                + (f"  [{','.join(flags)}]" if flags else "")
             )
 
     runs = ledger.records("run")
@@ -2082,7 +2008,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     p.add_argument(
         "--baseline-file", default=None,
         help="JSON file whose 'value' field is the pinned baseline "
-             "(e.g. a preserved BENCH_LAST_GOOD.json)",
+             "(a saved bench result line)",
     )
     p.add_argument(
         "--failures", action="store_true",
@@ -2117,9 +2043,15 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     if args.check_regression is not None:
         baseline = args.baseline
         if baseline is None and args.baseline_file:
-            payload, err = load_bench_cache(args.baseline_file)
-            if err:
-                print(f"ledger_report: --baseline-file: {err}")
+            try:
+                with open(args.baseline_file, "r", encoding="utf-8") as f:
+                    payload = json.load(f)
+            except (OSError, ValueError) as e:
+                print(f"ledger_report: --baseline-file: {e}")
+                return 2
+            problems = validate_bench_payload(payload)
+            if problems:
+                print("ledger_report: --baseline-file: " + "; ".join(problems))
                 return 2
             baseline = float(payload["value"])
         rc, msg = check_regression(ledger, args.check_regression, baseline)

@@ -9,14 +9,11 @@ pjit/shard_map code path (SURVEY §4).
 Env vars must be set before jax initializes its backends, hence this conftest.
 """
 
-from swiftsnails_tpu.utils.platform_pin import pin_cpu, repin_after_import
+from swiftsnails_tpu.utils.platform_pin import pin_cpu
 
-pin_cpu(8)  # the shell pins a TPU platform; tests run on the virtual CPU mesh
+pin_cpu(8)  # tests run on the virtual CPU mesh, whatever the host holds
 
 import jax  # noqa: E402
-
-repin_after_import(8)
-
 import pytest  # noqa: E402
 
 

@@ -1,10 +1,10 @@
-"""bench.py <-> ledger integration: structured outage events from the probe,
-corrupt-cache rejection with regeneration from the ledger, and the derived
-last-good view written through the ledger on save."""
+"""bench.py's device rule and its ledger record: no chip -> non-zero exit and
+no result line; an explicit JAX_PLATFORMS=cpu run says ``platform: cpu`` on
+every line; the deadline watchdog fails the run; a completed run appends one
+bench record and nothing ever reads a result back out to print it again."""
 
 import json
 import os
-import subprocess
 import sys
 
 import pytest
@@ -17,140 +17,89 @@ from swiftsnails_tpu.telemetry.ledger import Ledger
 
 @pytest.fixture()
 def isolated_bench(tmp_path, monkeypatch):
-    """Point bench's module-level artifact paths at a tmp dir and reset the
-    one-shot emit latch + error list."""
+    """Point bench's ledger at a tmp dir and reset the one-shot emit latch,
+    the error list and the device fields."""
     monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last_good.json"))
     monkeypatch.setattr(bench, "_emitted", False)
     monkeypatch.setitem(bench._state, "errors", [])
+    for key in ("platform", "device_kind", "device_count"):
+        monkeypatch.setitem(bench._state, key, None)
+    # the cache rule is tested in test_chip_smoke; keep this process's jax
+    # config untouched here
+    from swiftsnails_tpu.utils import compile_cache
+
+    monkeypatch.setattr(compile_cache, "configure_compile_cache", lambda: "")
     return tmp_path
 
 
-def current_payload(value=123456.0):
-    """A payload whose config matches this build (the fallback config gate)."""
-    p = json.loads(bench._result_json())
-    p.update({"value": value, "path": "dense", "platform": "tpu",
-              "paths": {"dense": value}, "errors": []})
-    return p
-
-
-def test_probe_timeout_kills_group_and_writes_structured_outage_event(
-        isolated_bench, monkeypatch):
-    killed = []
-
-    class HungChild:
-        pid = 424242
-        returncode = None
-        _calls = 0
-
-        def communicate(self, timeout=None):
-            # first call hangs past the deadline; the post-kill reap returns
-            # the buffered stderr with the child now dead
-            HungChild._calls += 1
-            if HungChild._calls == 1:
-                raise subprocess.TimeoutExpired(cmd="probe", timeout=timeout)
-            self.returncode = -9
-            return "", "pjrt init stuck\n"
-
-    monkeypatch.setattr(bench.subprocess, "Popen",
-                        lambda *a, **kw: HungChild())
-    monkeypatch.setattr(bench.os, "killpg",
-                        lambda pgid, sig: killed.append((pgid, sig)))
-    assert bench.probe_accelerator() is None
-    assert killed == [(424242, bench.signal.SIGKILL)]  # group, not just pid
-    ev = Ledger(bench.LEDGER_PATH).latest("outage")
-    assert ev is not None
-    assert ev["killed"] is True and ev["rc"] == -9
-    assert ev["stderr_tail"] == ["pjrt init stuck"]
-    assert isinstance(ev["probe_duration_s"], (int, float))
-    assert "grant unavailable" in ev["error"]
-    assert any("grant unavailable" in e for e in bench._state["errors"])
-
-
-def test_probe_rc_failure_writes_outage_event(isolated_bench, monkeypatch):
-    class DeadChild:
-        returncode = 17
-
-        def communicate(self, timeout=None):
-            return "", "boom: no TPU platform"
-
-    monkeypatch.setattr(bench.subprocess, "Popen",
-                        lambda *a, **kw: DeadChild())
-    assert bench.probe_accelerator() is None
-    ev = Ledger(bench.LEDGER_PATH).latest("outage")
-    assert ev["rc"] == 17 and "rc=17" in ev["error"]
-    assert ev["killed"] is False
-    # the tail is a structured field now, not free text inside the error
-    assert ev["stderr_tail"] == ["boom: no TPU platform"]
-    assert "boom" not in ev["error"]
-
-
-def test_cached_fallback_rejects_corrupt_cache_and_regenerates(
+def test_no_chip_and_no_explicit_cpu_fails_without_a_result(
         isolated_bench, monkeypatch, capsys):
-    # a torn cache file on disk + a healthy cacheable record in the ledger
-    with open(bench.LAST_GOOD_PATH, "w") as f:
-        f.write('{"metric": "word2vec_words_per_sec_per_chip", "valu')
-    Ledger(bench.LEDGER_PATH).append(
-        "bench", {"payload": current_payload(), "cacheable": True})
-    assert bench._emit_cached_fallback() is True
-    out = capsys.readouterr().out.strip().splitlines()[-1]
-    emitted = json.loads(out)  # driver contract: one strict-JSON line
-    assert emitted["cached"] is True
-    assert emitted["value"] == 123456.0
-    errs = " | ".join(emitted["errors"])
-    assert "cache rejected" in errs and "regenerated from the run ledger" in errs
-    # the rejection is a ledger event, and the view was rewritten valid
-    led = Ledger(bench.LEDGER_PATH)
-    assert led.latest("cache_error") is not None
-    assert json.load(open(bench.LAST_GOOD_PATH))["value"] == 123456.0
+    # the test process runs on CPU devices; with JAX_PLATFORMS unset that is
+    # exactly "no chip found", which must not fall through to a CPU number
+    monkeypatch.delenv("JAX_PLATFORMS")
+    rc = bench.main(["--lane", "serve"])
+    out, err = capsys.readouterr()
+    assert rc != 0
+    assert out.strip() == ""  # no result line, so no "value"
+    assert "value" not in out
+    assert "platform is 'cpu'" in err and "refusing to measure" in err
+    # nothing was recorded as a run either
+    assert Ledger(bench.LEDGER_PATH).latest("bench") is None
 
 
-def test_cached_fallback_attaches_last_outage_summary(isolated_bench, capsys):
-    led = Ledger(bench.LEDGER_PATH)
-    for _ in range(3):
-        led.append("outage", {"probe_duration_s": 300.0, "rc": None,
-                              "error": "grant unavailable"})
-    payload = current_payload()
-    from swiftsnails_tpu.telemetry.ledger import atomic_write_json
-
-    atomic_write_json(bench.LAST_GOOD_PATH, payload)
-    assert bench._emit_cached_fallback() is True
-    emitted = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    # the structured summary replaces the hand-typed OUTAGE_*.txt line
-    assert emitted["last_outage"]["outages_recorded"] == 3
-    assert emitted["last_outage"]["probe_duration_s"] == 300.0
-    assert any("3 outages recorded" in e for e in emitted["errors"])
+def test_require_device_accepts_explicit_cpu_and_names_it(
+        isolated_bench, monkeypatch, capsys):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    bench.require_device()
+    assert bench._state["platform"] == "cpu"
+    assert bench._state["device_count"] >= 8
+    bench._say("hello")
+    assert "platform: cpu" in capsys.readouterr().err
+    payload = json.loads(bench._result_json())
+    assert payload["platform"] == "cpu"
+    assert payload["device"] == {
+        "platform": "cpu", "kind": bench._state["device_kind"],
+        "count": bench._state["device_count"]}
 
 
-def test_cached_fallback_missing_cache_and_empty_ledger_is_quiet(isolated_bench):
-    assert bench._emit_cached_fallback() is False
-    # a merely-missing cache is not a corruption event
-    assert Ledger(bench.LEDGER_PATH).latest("cache_error") is None
+def test_no_replay_path_left():
+    # the probe child, the cached emission and the reconstructed file are
+    # gone: nothing in bench.py can print a number it did not just measure
+    for name in ("probe_accelerator", "_emit_cached_fallback",
+                 "_save_last_good", "LAST_GOOD_PATH", "PROBE_DEADLINE_S"):
+        assert not hasattr(bench, name), name
+    src = open(bench.__file__).read()
+    assert "cached" not in src and "reconstructed" not in src
+    assert "subprocess" not in src  # one process per chip: no children
+    root = os.path.dirname(bench.__file__)
+    assert not os.path.exists(os.path.join(root, "BENCH_LAST_GOOD.json"))
 
 
-def test_save_last_good_routes_through_ledger(isolated_bench, monkeypatch):
-    # make this run look like a valid full headline run
+def test_deadline_watchdog_fails_the_run(isolated_bench, monkeypatch, capsys):
+    exits = []
+    monkeypatch.setattr(bench.os, "_exit", lambda rc: exits.append(rc))
+    monkeypatch.setitem(bench._state, "paths", {"dense": 1234.5})
+    monkeypatch.setitem(bench._state, "best", 1234.5)
+    bench._deadline()
+    out, err = capsys.readouterr()
+    assert exits == [1]  # even with a best-so-far in hand
+    assert out.strip() == ""
+    assert "deadline" in err and "dense" in err
+
+
+def test_record_run_appends_one_bench_record(isolated_bench, monkeypatch):
     monkeypatch.setitem(bench._state, "best", 999999.0)
     monkeypatch.setitem(bench._state, "best_path", "dense")
-    monkeypatch.setitem(bench._state, "platform", "tpu")
-    monkeypatch.setitem(bench._state, "attempted", {
-        "dense", "packed+pool", "fused-hogwild", "fused-grouped",
-        "fused-resident", "fused-dedup"})
-    monkeypatch.setattr(bench, "_SMALL", False)
-    bench._save_last_good()
-    led = Ledger(bench.LEDGER_PATH)
-    rec = led.latest("bench")
-    assert rec["cacheable"] is True
-    assert rec["payload"]["value"] == 999999.0
-    assert rec["payload"]["reconstructed"] is False
-    assert "env" in rec and len(rec["config_hash"]) == 16
-    # the derived view is regenerated from the ledger, atomically
-    view = json.load(open(bench.LAST_GOOD_PATH))
-    assert view["value"] == 999999.0
-    # an invalid (cpu / truncated) run is recorded but NOT cacheable, and
-    # must not overwrite the view
     monkeypatch.setitem(bench._state, "platform", "cpu")
-    monkeypatch.setitem(bench._state, "best", 1.0)
-    bench._save_last_good()
-    assert led.latest("bench")["cacheable"] is False
-    assert json.load(open(bench.LAST_GOOD_PATH))["value"] == 999999.0
+    monkeypatch.setitem(bench._state, "device_kind", "cpu")
+    bench._record_run()
+    led = Ledger(bench.LEDGER_PATH)
+    recs = led.records("bench")
+    assert len(recs) == 1
+    rec = recs[0]
+    assert rec["payload"]["value"] == 999999.0
+    assert rec["payload"]["platform"] == "cpu"
+    assert "measured_at" in rec["payload"]
+    assert "cacheable" not in rec
+    assert "env" in rec and len(rec["config_hash"]) == 16
+    assert rec["env"]["devices"]["platform"] == "cpu"

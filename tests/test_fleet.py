@@ -308,8 +308,6 @@ def test_affinity_beats_random_on_zipf_traffic():
 @pytest.fixture()
 def isolated_bench(tmp_path, monkeypatch):
     monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH",
-                        str(tmp_path / "last_good.json"))
     monkeypatch.setattr(bench, "_SMALL", True)
     monkeypatch.setitem(bench._state, "errors", [])
     monkeypatch.setitem(bench._state, "fleet", None)

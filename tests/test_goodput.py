@@ -112,18 +112,17 @@ def test_audit_without_cost_still_reports():
     assert rep["flops_per_step"] is None
 
 
-def test_peaks_from_config_overrides():
-    from swiftsnails_tpu.telemetry.goodput import peaks_from_config
-    from swiftsnails_tpu.utils.config import Config
+def test_unknown_tpu_kind_is_an_error_not_a_neighbours_peak():
+    from swiftsnails_tpu.telemetry.goodput import UnknownDeviceError
 
-    cfg = Config({"peak_flops": "5e12", "peak_hbm_gbps": "100"})
-    p = peaks_from_config(cfg, None)
-    assert p["flops_per_s"] == pytest.approx(5e12)
-    assert p["hbm_bytes_per_s"] == pytest.approx(100e9)
-    assert p["source"] == "config"
-    # no override: table lookup passes through
-    assert peaks_from_config(Config({}), "TPU v4")["flops_per_s"] == \
-        pytest.approx(275e12)
+    # exact keys only: the old substring table gave any "...v5..." kind
+    # v5p's 459 TFLOP/s
+    for kind in ("TPU v5", "TPU v5e", "TPU v7x", "tpu v5 lite"):
+        with pytest.raises(UnknownDeviceError, match="PEAKS"):
+            peaks_for(kind, "tpu")
+    # off the chip there is no denominator, and no error
+    assert peaks_for("cpu", "cpu")["flops_per_s"] is None
+    assert peaks_for("TPU v5", None)["flops_per_s"] is None
 
 
 # ---------------------------------------------- TrainLoop end-to-end (CPU)
@@ -147,9 +146,6 @@ def test_trainloop_emits_goodput_and_ledger_record(tmp_path):
         telemetry="1",
         ledger_path=ledger_path,
         blackbox_dir=str(tmp_path / "bb"),
-        # CPU has no table peak: exercise the config override path so MFU
-        # comes out numeric in the acceptance run
-        peak_flops="1e12",
     )
     loop = TrainLoop(trainer, metrics=MetricsLogger(path=metrics_path),
                      log_every=2)
@@ -168,7 +164,7 @@ def test_trainloop_emits_goodput_and_ledger_record(tmp_path):
     assert "jax" in rec["env"]
     g = rec["goodput"]
     assert "mfu" in g
-    assert g["mfu"] is not None and g["mfu"] > 0  # peak_flops override
+    assert g["mfu"] is None  # CPU has no peak, and no key invents one
     assert g["decomposition"]["steps"] == 5
     assert g["flops_per_step"] > 0  # the compile-only audit ran
     assert 0 < g["goodput"] <= 1

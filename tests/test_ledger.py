@@ -1,5 +1,5 @@
-"""Run ledger: atomic append/replay, schema validation, the derived
-BENCH_LAST_GOOD view, the outage summary, and the regression gate."""
+"""Run ledger: atomic append/replay, schema validation, and the regression
+gate."""
 
 import json
 import os
@@ -11,10 +11,7 @@ from swiftsnails_tpu.telemetry.ledger import (
     atomic_write_json,
     check_regression,
     config_hash,
-    derive_last_good,
     env_fingerprint,
-    load_bench_cache,
-    outage_summary,
     render_report,
     validate_bench_payload,
 )
@@ -96,7 +93,7 @@ def test_config_hash_stable_and_order_independent():
     assert len(h1) == 16
 
 
-# ----------------------------------------------------- cache schema + view
+# ------------------------------------------------------- payload schema
 
 
 def test_validate_bench_payload():
@@ -107,47 +104,6 @@ def test_validate_bench_payload():
     assert validate_bench_payload(bench_payload(value="fast")) != []
 
 
-def test_load_bench_cache_rejects_partial_and_missing(tmp_path):
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps(bench_payload()))
-    payload, err = load_bench_cache(str(good))
-    assert err is None and payload["value"] == 100.0
-
-    partial = tmp_path / "partial.json"
-    partial.write_text('{"metric": "m", "valu')  # torn write
-    payload, err = load_bench_cache(str(partial))
-    assert payload is None and "unparseable" in err
-
-    incomplete = tmp_path / "incomplete.json"
-    incomplete.write_text(json.dumps({"metric": "m"}))
-    payload, err = load_bench_cache(str(incomplete))
-    assert payload is None and "schema" in err
-
-    payload, err = load_bench_cache(str(tmp_path / "missing.json"))
-    assert payload is None and "unreadable" in err
-
-
-def test_derive_last_good_picks_newest_valid_cacheable(tmp_path):
-    led = Ledger(str(tmp_path / "ledger.jsonl"))
-    out = str(tmp_path / "BENCH_LAST_GOOD.json")
-    # nothing cacheable yet
-    payload, reason = derive_last_good(led, out)
-    assert payload is None and "no cacheable" in reason
-
-    led.append("bench", {"payload": bench_payload(value=50.0), "cacheable": True})
-    led.append("bench", {"payload": bench_payload(value=75.0), "cacheable": False})
-    led.append("bench", {"payload": {"metric": "m"}, "cacheable": True})  # invalid
-    payload, reason = derive_last_good(led, out)
-    # newest VALID cacheable wins: the 50.0 record (75 not cacheable,
-    # newest cacheable fails schema)
-    assert reason is None and payload["value"] == 50.0
-    on_disk = json.load(open(out))
-    assert on_disk["value"] == 50.0 and "measured_at" in on_disk
-    # round-trips through the validated loader
-    loaded, err = load_bench_cache(out)
-    assert err is None and loaded["value"] == 50.0
-
-
 def test_atomic_write_json_replaces_not_appends(tmp_path):
     p = str(tmp_path / "f.json")
     atomic_write_json(p, {"v": 1})
@@ -156,16 +112,6 @@ def test_atomic_write_json_replaces_not_appends(tmp_path):
 
 
 # ------------------------------------------------------- outage + report
-
-
-def test_outage_summary_structured(tmp_path):
-    led = Ledger(str(tmp_path / "ledger.jsonl"))
-    assert outage_summary(led) is None
-    led.append("outage", {"probe_duration_s": 300.0, "rc": None, "error": "a"})
-    led.append("outage", {"probe_duration_s": 280.0, "rc": 1, "error": "b"})
-    s = outage_summary(led)
-    assert s["outages_recorded"] == 2
-    assert s["probe_duration_s"] == 280.0 and s["rc"] == 1 and s["error"] == "b"
 
 
 def test_render_report_covers_all_kinds(tmp_path):
@@ -241,3 +187,13 @@ def test_ledger_report_cli_roundtrip(tmp_path, capsys):
     bad.write_text("{")
     assert main([path, "--check-regression", "10",
                  "--baseline-file", str(bad)]) == 2
+    # parseable but not a bench payload: rejected by the schema, with the
+    # missing keys named
+    incomplete = tmp_path / "incomplete.json"
+    incomplete.write_text(json.dumps({"metric": "m"}))
+    capsys.readouterr()
+    assert main([path, "--check-regression", "10",
+                 "--baseline-file", str(incomplete)]) == 2
+    assert "missing required key 'value'" in capsys.readouterr().out
+    assert main([path, "--check-regression", "10", "--baseline-file",
+                 str(tmp_path / "missing.json")]) == 2

@@ -27,7 +27,6 @@ from swiftsnails_tpu.telemetry.ledger import Ledger, check_regression
 @pytest.fixture()
 def isolated_bench(tmp_path, monkeypatch):
     monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last_good.json"))
     monkeypatch.setitem(bench._state, "errors", [])
     monkeypatch.setitem(bench._state, "scaling", None)
     return tmp_path
@@ -43,7 +42,7 @@ def test_scaling_lane_smoke(isolated_bench):
     counts, ids = _small_workload()
     bench.measure_scaling(
         counts, ids, n_devices=8, dim=16, batch_per_shard=64,
-        steps_per_call=2, measure_steps=2, calib_steps=1,
+        steps_per_call=2, measure_steps=2,
     )
     block = bench._state["scaling"]
     assert block and "skipped" not in block

@@ -255,7 +255,6 @@ def test_backpressure_sheds_typed_error_and_ledger_event(tmp_path, capsys):
 @pytest.fixture()
 def isolated_bench(tmp_path, monkeypatch):
     monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last_good.json"))
     monkeypatch.setattr(bench, "_SMALL", True)
     monkeypatch.setitem(bench._state, "errors", [])
     monkeypatch.setitem(bench._state, "serving", None)

@@ -157,6 +157,23 @@ def test_reduce_scatter_bills_full_operand():
     st = collective_stats(hlo)
     assert st["ops"]["reduce-scatter"] == {"count": 1, "bytes": 64 * 8 * 4}
     assert st["by_scope"] == {"ssn_zero_head_push": 64 * 8 * 4}
+    # the text jax 0.9.0 prints names the operand without its shape: the
+    # operand's own defining line in the same computation supplies it, and
+    # a same-named instruction of ANOTHER computation does not
+    named = (
+        "%fused (p: f32[4096,8]) -> f32[4096,8] {\n"
+        "  %bitcast = f32[4096,8]{1,0} bitcast(%p)\n"
+        "}\n"
+        "ENTRY %main (x: f32[64,8]) -> f32[16,8] {\n"
+        "  %bitcast = f32[64,8]{1,0} bitcast(%x)\n"
+        "  ROOT %rs.7 = f32[16,8]{1,0} reduce-scatter(%bitcast), "
+        "channel_id=1, replica_groups={{0,2,4,6},{1,3,5,7}}, dimensions={0}, "
+        "to_apply=%region_0.0, metadata={op_name=\"jit(step)/shard_map/"
+        "ssn_zero_head_push/reduce_scatter\"}\n"
+        "}\n")
+    st = collective_stats(named)
+    assert st["ops"]["reduce-scatter"] == {"count": 1, "bytes": 64 * 8 * 4}
+    assert st["by_scope"] == {"ssn_zero_head_push": 64 * 8 * 4}
 
 
 def test_reduce_scatter_sub_byte_operand():
@@ -250,7 +267,6 @@ def test_compiled_collective_bytes_kernel_lab_contract():
 def test_audit_compiled_reduce_scatter_full_operand():
     """End to end on real compiled HLO: an f32 reduce_scatter_quantized
     step bills the full operand under its ssn_zero scope label."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from swiftsnails_tpu.parallel.comm import reduce_scatter_quantized
@@ -263,8 +279,8 @@ def test_audit_compiled_reduce_scatter_full_operand():
             with jax.named_scope("ssn_zero_head_push"):
                 return reduce_scatter_quantized(xs[0], DATA_AXIS, "float32", 4)
 
-        return shard_map(body, mesh=mesh, in_specs=(P(DATA_AXIS),),
-                         out_specs=P(DATA_AXIS), check_rep=False)(x)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(DATA_AXIS),),
+                             out_specs=P(DATA_AXIS), check_vma=False)(x)
 
     report = audit_step(step, jnp.ones((4, rows, dim), jnp.float32))
     assert report["ops"]["reduce-scatter"]["bytes"] == rows * dim * 4

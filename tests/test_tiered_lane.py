@@ -23,7 +23,6 @@ from swiftsnails_tpu.telemetry.ledger import Ledger, check_regression
 @pytest.fixture()
 def isolated_bench(tmp_path, monkeypatch):
     monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "LAST_GOOD_PATH", str(tmp_path / "last_good.json"))
     monkeypatch.setattr(bench, "_SMALL", True)  # CI-sized corpora + vocab
     monkeypatch.setitem(bench._state, "errors", [])
     monkeypatch.setitem(bench._state, "tiered", None)

@@ -29,7 +29,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from swiftsnails_tpu.data.vocab import Vocab
 from swiftsnails_tpu.models.word2vec import Word2VecTrainer
@@ -77,14 +76,14 @@ def test_reduce_scatter_matches_owned_slice(mesh, wire, stochastic):
 
     # xs keeps the global (DATA, rows, dim) buffer: in_spec P(DATA_AXIS)
     # hands each shard one identical full local gradient via xs[0];
-    # check_rep off — the quantized paths move bytes with gather/all-to-all
+    # check_vma off — the quantized paths move bytes with gather/all-to-all
     # and sum by hand, which the replication checker can't see through
-    summed = jax.jit(shard_map(
+    summed = jax.jit(jax.shard_map(
         full, mesh=mesh, in_specs=(P(DATA_AXIS),), out_specs=P(),
-        check_rep=False))(x)
-    scattered = jax.jit(shard_map(
+        check_vma=False))(x)
+    scattered = jax.jit(jax.shard_map(
         scat, mesh=mesh, in_specs=(P(DATA_AXIS),),
-        out_specs=P(DATA_AXIS), check_rep=False))(x)
+        out_specs=P(DATA_AXIS), check_vma=False))(x)
     np.testing.assert_array_equal(np.asarray(scattered), np.asarray(summed))
 
 
@@ -95,7 +94,7 @@ def test_reduce_scatter_rejects_misaligned_leading_dim(mesh):
         return reduce_scatter_quantized(xs[0], DATA_AXIS, "float32", DATA)
 
     with pytest.raises(ValueError, match="not\\s+divisible"):
-        jax.jit(shard_map(scat, mesh=mesh, in_specs=(P(DATA_AXIS),),
+        jax.jit(jax.shard_map(scat, mesh=mesh, in_specs=(P(DATA_AXIS),),
                           out_specs=P(DATA_AXIS)))(x)
 
 
@@ -300,8 +299,13 @@ def test_resume_auto_under_sharding_bit_identical(mesh, tmp_path):
 
 
 def test_overlap_depths_agree_on_first_macro(mesh):
-    """overlap 0/1/2 run the same updates on one macro batch (staleness
-    only reorders *which* substep a push lands in, not its math)."""
+    """overlap 0/1/2 train the same macro batch to the same loss within f32
+    tolerance — not bit for bit: substep i of depth d reads rows that miss
+    the last d pushes, so from the second substep on its loss is computed on
+    (slightly) different row values. One substep's update is ~1e-3 of a
+    row, which moves the mean loss by 1-2 ulp here (measured per substep on
+    jax 0.9.0: substep 0 identical, substeps 1-3 differ in the last bits) —
+    a real staleness difference, not a reduction reordering."""
     losses = {}
     batch = None
     for depth in (0, 1, 2):
@@ -309,8 +313,8 @@ def test_overlap_depths_agree_on_first_macro(mesh):
                   placement="uniform")
         _, loss, batch = _w2v_step(tr, mesh, batch=batch)
         losses[depth] = loss
-    assert losses[1] == losses[0]
-    assert losses[2] == losses[0]
+    np.testing.assert_allclose(losses[1], losses[0], rtol=1e-5)
+    np.testing.assert_allclose(losses[2], losses[0], rtol=1e-5)
 
 
 def test_overlap_validation():

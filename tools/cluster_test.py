@@ -9,6 +9,10 @@ word2vec job on CPU devices, hit the end-of-training barrier, and exit 0.
 
     python tools/cluster_test.py --nproc 2
 
+This is a CPU test: every child is started with ``JAX_PLATFORMS=cpu`` (set
+below), so it never asks for a chip — a chip belongs to one process, and
+this launcher imports no jax backend itself. It says nothing about TPUs.
+
 Each process logs to ``/tmp/snails_cluster_test/proc<i>.log`` (the master.log
 analog).
 """
@@ -24,8 +28,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _CHILD = r"""
 import os, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
+import jax  # JAX_PLATFORMS=cpu comes from the launcher's env
 import numpy as np
 
 pid = int(sys.argv[1]); nproc = int(sys.argv[2]); port = sys.argv[3]

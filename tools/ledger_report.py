@@ -1,10 +1,10 @@
 #!/usr/bin/env python
 """Render the durable run ledger (RUN_LEDGER.jsonl) as a terminal report.
 
-The ledger is the append-only source of truth every bench run, training run,
-outage/probe failure, and black-box dump writes into
-(``swiftsnails_tpu/telemetry/ledger.py``); ``BENCH_LAST_GOOD.json`` is a
-derived view of it. This tool renders the history — and gates CI:
+The ledger is the append-only record every bench run, training run, outage
+event, and black-box dump writes into
+(``swiftsnails_tpu/telemetry/ledger.py``). This tool renders the history —
+and gates CI:
 
     python tools/ledger_report.py                      # full history
     python tools/ledger_report.py RUN_LEDGER.jsonl     # explicit path
