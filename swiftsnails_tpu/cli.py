@@ -53,25 +53,30 @@ from swiftsnails_tpu.utils.metrics import MetricsLogger
 def _build_trainer(cfg: Config):
     from swiftsnails_tpu.models.registry import get_model
     from swiftsnails_tpu.parallel.mesh import DATA_AXIS, MODEL_AXIS, make_mesh
+    from swiftsnails_tpu.telemetry.tracer import span_fn, tracer_from_config
 
     import jax
 
     from swiftsnails_tpu.data import native
 
-    # a run that wants the native producers gets them or stops here, with
-    # the compiler's output, before any corpus is read
-    native.use_native(cfg)
-    model_name = cfg.get_str("model", "word2vec")
-    trainer_cls = get_model(model_name)
-    n = len(jax.devices())
-    if cfg.get_bool("local_train", False) or n == 1:
-        mesh = None  # reference local_train parity (SwiftWorker.h:114-123)
-    else:
-        model_axis = cfg.get_int("model_axis", 0)
-        if model_axis <= 0:
-            model_axis = next((c for c in (4, 2, 1) if n % c == 0 and n > c), 1)
-        mesh = make_mesh({DATA_AXIS: n // model_axis, MODEL_AXIS: model_axis})
-    return trainer_cls(cfg, mesh=mesh)
+    # the run's tracer starts here, where the config is first in hand, so
+    # that set-up has spans too; the trainer holds it and TrainLoop adopts it
+    tracer = tracer_from_config(cfg)
+    with span_fn(tracer)("build-trainer"):
+        # a run that wants the native producers gets them or stops here, with
+        # the compiler's output, before any corpus is read
+        native.use_native(cfg)
+        model_name = cfg.get_str("model", "word2vec")
+        trainer_cls = get_model(model_name)
+        n = len(jax.devices())
+        if cfg.get_bool("local_train", False) or n == 1:
+            mesh = None  # reference local_train parity (SwiftWorker.h:114-123)
+        else:
+            model_axis = cfg.get_int("model_axis", 0)
+            if model_axis <= 0:
+                model_axis = next((c for c in (4, 2, 1) if n % c == 0 and n > c), 1)
+            mesh = make_mesh({DATA_AXIS: n // model_axis, MODEL_AXIS: model_axis})
+        return trainer_cls(cfg, mesh=mesh, tracer=tracer)
 
 
 def cmd_train(argv: List[str]) -> int:

@@ -40,6 +40,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from swiftsnails_tpu.utils.config import Config
 from swiftsnails_tpu.utils.metrics import MetricsLogger
 from swiftsnails_tpu.utils.profiling import StepProfiler, step_annotation
+from swiftsnails_tpu.telemetry.tracer import span_fn, tracer_from_config
 from swiftsnails_tpu.parallel.mesh import DATA_AXIS, batch_sharding
 
 
@@ -59,11 +60,16 @@ class Trainer:
 
     name: str = "trainer"
 
-    def __init__(self, config: Config, mesh: Optional[Mesh] = None):
+    def __init__(self, config: Config, mesh: Optional[Mesh] = None,
+                 tracer=None):
         from swiftsnails_tpu.parallel.zero import resolve_optimizer_sharding
 
         self.config = config
         self.mesh = mesh
+        # the run's span tracer (cli._build_trainer makes it, TrainLoop adopts
+        # it) or None; `self.span` is a no-op without one
+        self.tracer = tracer
+        self.span = span_fn(tracer)
         # optimizer_sharding: zero -> ZeRO-style update sharding of every
         # replicated optimizer plane across the data axis (parallel/zero.py)
         self.optimizer_sharding = resolve_optimizer_sharding(
@@ -167,22 +173,33 @@ class _Prefetcher:
 
     _DONE = object()
 
-    def __init__(self, it: Iterator, depth: int = 2):
+    def __init__(self, it: Iterator, depth: int = 2, span=span_fn(None)):
         self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
         self._err: Optional[BaseException] = None
         self._stop = threading.Event()
         self._exhausted = False
         self.last_wait_ns = 0  # consumer block on the last __next__
+        it = iter(it)
 
         def produce():
+            # `produce` spans are the producer's busy time (the source's own
+            # parsing/sampling), `queue-full` its wait for the consumer
             try:
-                for item in it:
-                    while not self._stop.is_set():
-                        try:
-                            self._q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
+                while True:
+                    with span("produce"):
+                        item = next(it, self._DONE)
+                    if item is self._DONE:
+                        break
+                    try:
+                        self._q.put_nowait(item)
+                    except queue.Full:
+                        with span("queue-full"):
+                            while not self._stop.is_set():
+                                try:
+                                    self._q.put(item, timeout=0.1)
+                                    break
+                                except queue.Full:
+                                    continue
                     if self._stop.is_set():
                         return
             except BaseException as e:  # surfaced in __next__
@@ -348,15 +365,16 @@ class TrainLoop:
         self.preempted = False
         self._prev_sigterm = None
         # telemetry is opt-in (`telemetry: 1` or a `trace_path`); when off,
-        # tracer/registry/black-box stay None and run() takes the
-        # uninstrumented branch
-        self.trace_path = cfg.get_str("trace_path", "")
-        if cfg.get_bool("telemetry", False) or self.trace_path:
+        # tracer/registry/black-box stay None and run()'s spans are the
+        # shared no-op. A trainer from cli._build_trainer brings the run's
+        # tracer, with the set-up spans already in it
+        self.tracer = (trainer.tracer if trainer.tracer is not None
+                       else tracer_from_config(cfg))
+        if self.tracer is not None:
             from swiftsnails_tpu.telemetry import (
-                BlackBox, MetricRegistry, StdoutSummarySink, Tracer,
+                BlackBox, MetricRegistry, StdoutSummarySink,
             )
 
-            self.tracer = Tracer(path=self.trace_path or None)
             sinks = [self.metrics]
             if cfg.get_bool("telemetry_stdout", False):
                 sinks.append(StdoutSummarySink())
@@ -407,7 +425,6 @@ class TrainLoop:
                 self.drift = None
             self.incident_dir = cfg.get_str("incident_dir", "incidents")
         else:
-            self.tracer = None
             self.registry = None
             self.blackbox = None
             self._want_audit = False
@@ -417,6 +434,7 @@ class TrainLoop:
             self.incident_dir = ""
         self.incidents: List[str] = []
         self._incident_reasons: set = set()
+        self._run_event_idx = 0  # the tracer's length when run() began
         self._profile_event_idx = 0
         self._profile_pending_loss = None
         self._audit_report = None
@@ -586,10 +604,13 @@ class TrainLoop:
             src = tier.stage_stream(src, root_rng)
             if not tier.all_transparent:
                 depth = tier.prefetch_depth
-        batches = _Prefetcher(src, depth=depth) if depth else src
+        tel = self.tracer
+        span = span_fn(tel)
+        if tel is not None:
+            self._run_event_idx = self._profile_event_idx = tel.n_events()
+        batches = _Prefetcher(src, depth=depth, span=span) if depth else src
         if tier is not None and isinstance(batches, _Prefetcher):
             tier.attach_prefetcher(batches)  # tier_prefetch_depth: auto
-        tel = self.tracer
         reg = self.registry
         bb = self.blackbox
         guard = self.guardrail
@@ -617,73 +638,22 @@ class TrainLoop:
                     break
         preempted = self._preempt.is_set
         try:
-            # hot-path contract: with telemetry and resilience off each step
-            # pays exactly the flag checks below — the instrumented bodies
-            # never run and allocate nothing
-            if tel is None:
-                for batch in it:
-                    if preempted():
-                        break
-                    n_items = trainer.items_per_batch(batch)
-                    self.profiler.on_step(step)
-                    if fresh is not None:
-                        # record touched rows BEFORE tier.prepare remaps the
-                        # batch ids to slot space (resident/transparent path)
-                        fresh.on_batch(batch, root_rng, step)
-                    if chaos is not None:
-                        # slow_step stalls the HOST before dispatch (outside
-                        # the step), mimicking a real host-blocked regression
-                        chaos.maybe_slow_step(step)
-                    with step_annotation(trainer.name, step):
-                        if tier is not None:
-                            # fault the rows this step touches into the cache
-                            # and remap batch ids to slot space; runs BEFORE
-                            # any snapshot/injection so rollback targets a
-                            # slot-map-consistent state
-                            state, batch = tier.prepare(
-                                state, batch, root_rng, step)
-                        dev_batch = self._device_batch(batch)
-                        # fold_in happens inside the jitted step; the numpy
-                        # scalar is an array operand (no per-value retrace)
-                        if resilient:
-                            state, last_metrics = self._resilient_step(
-                                state, dev_batch, root_rng, step)
-                        else:
-                            state, last_metrics = self._step_fn(
-                                state, dev_batch, root_rng, np.uint32(step))
-                    step += 1
-                    self._items_seen += n_items
-                    if cl is not None:
-                        # commit the applied batch + renew the membership
-                        # lease + adopt any reassigned spans — BEFORE a
-                        # checkpoint below, so the cursor sees this commit
-                        cl.on_step(step)
-                    self.metrics.count(n_items)
-                    if self.log_every and step % self.log_every == 0:
-                        host = {k: float(v) for k, v in last_metrics.items()}
-                        self.metrics.flush_window(step=step, **host)
-                    if self.backup_period and self.checkpoint_fn and step % self.backup_period == 0:
-                        self.checkpoint_fn(state, step)
-                    if fresh is not None:
-                        fresh.maybe_publish(state, step)
-                    if max_steps is not None and step >= max_steps:
-                        break
-            else:
-                while True:
-                    if preempted():
-                        break
+            # ONE loop body, traced or not: with telemetry off every `span`
+            # is the shared no-op (nothing recorded, nothing allocated) and
+            # registry / black box / time series sit behind `is not None`,
+            # so the jitted step is dispatched from the same source line
+            # either way and a traced run loads what a plain run compiled
+            try:
+                while not preempted():
                     t_step0 = time.monotonic()
-                    with tel.span("prefetch-wait"):
-                        try:
-                            batch = next(it)
-                        except StopIteration:
-                            break
+                    with span("prefetch-wait", step=step):
+                        batch = next(it, _STREAM_END)
+                    if batch is _STREAM_END:
+                        break
                     n_items = trainer.items_per_batch(batch)
                     self.profiler.on_step(step)
-                    if isinstance(batches, _Prefetcher):
-                        q_depth = batches.qsize()
-                        reg.gauge("prefetch_queue_depth").set(q_depth)
-                        tel.counter("prefetch_queue_depth", q_depth)
+                    if reg is not None and isinstance(batches, _Prefetcher):
+                        reg.gauge("prefetch_queue_depth").set(batches.qsize())
                     if fresh is not None:
                         # record touched rows BEFORE tier.prepare remaps the
                         # batch ids to slot space (resident/transparent path)
@@ -692,17 +662,22 @@ class TrainLoop:
                         # the injected host stall runs OUTSIDE the step span,
                         # inside its own bucketed span, so the decomposition
                         # attributes it to host_blocked_s like a real stall
-                        with tel.span("chaos-slow", step=step):
+                        with span("chaos-slow", step=step):
                             chaos.maybe_slow_step(step)
-                    # step_span bridges to jax.profiler.StepTraceAnnotation,
-                    # so a concurrent profile_dir capture lines device work
-                    # up with these host spans by step number
-                    with tel.step_span(trainer.name, step):
+                    # the annotation carries the step number onto a
+                    # concurrent profile_dir capture, so device work lines
+                    # up with these host spans
+                    with step_annotation(trainer.name, step), \
+                            span(trainer.name, step=step):
                         if tier is not None:
-                            with tel.span("tier-fault", step=step):
+                            # fault the rows this step touches into the cache
+                            # and remap batch ids to slot space; runs BEFORE
+                            # any snapshot/injection so rollback targets a
+                            # slot-map-consistent state
+                            with span("tier-fault", step=step):
                                 state, batch = tier.prepare(
                                     state, batch, root_rng, step)
-                        with tel.span("h2d"):
+                        with span("h2d", step=step):
                             dev_batch = self._device_batch(batch)
                         if self._want_audit and self._audit_report is None:
                             # compile-only HLO audit of this exact step fn
@@ -710,7 +685,9 @@ class TrainLoop:
                             # feeds the goodput block's FLOP/byte numerators
                             self._audit_report = self._audit_step_fn(
                                 state, dev_batch, root_rng, np.uint32(step))
-                        with tel.span("step", step=step):
+                        # fold_in happens inside the jitted step; the numpy
+                        # scalar is an array operand (no per-value retrace)
+                        with span("step", step=step):
                             if resilient:
                                 state, last_metrics = self._resilient_step(
                                     state, dev_batch, root_rng, step)
@@ -721,55 +698,76 @@ class TrainLoop:
                     total_items += n_items
                     self._items_seen += n_items
                     if cl is not None:
+                        # commit the applied batch + renew the membership
+                        # lease + adopt any reassigned spans — BEFORE a
+                        # checkpoint below, so the cursor sees this commit
                         cl.on_step(step)
-                    reg.counter("steps").inc()
-                    reg.counter("items").inc(n_items)
-                    step_ms = (time.monotonic() - t_step0) * 1e3
-                    reg.histogram("step_ms").observe(step_ms)
-                    if bb is not None:
-                        bb.record_step(step, step_ms=step_ms, items=n_items)
-                    if (self.timeseries is not None
-                            and step % self.profile_cadence == 0):
-                        self._profile_sample(step, step_ms, last_metrics)
+                    if reg is not None:
+                        reg.counter("steps").inc()
+                        reg.counter("items").inc(n_items)
+                        step_ms = (time.monotonic() - t_step0) * 1e3
+                        reg.histogram("step_ms").observe(step_ms)
+                        if bb is not None:
+                            bb.record_step(step, step_ms=step_ms, items=n_items)
+                        if (self.timeseries is not None
+                                and step % self.profile_cadence == 0):
+                            self._profile_sample(step, step_ms, last_metrics)
                     self.metrics.count(n_items)
                     if self.log_every and step % self.log_every == 0:
-                        with tel.span("metrics-flush"):
+                        with span("metrics-flush", step=step):
                             host = {k: float(v) for k, v in last_metrics.items()}
                             self.metrics.flush_window(step=step, **host)
-                            reg.flush(step=step)
+                            if reg is not None:
+                                reg.flush(step=step)
                             if bb is not None:
                                 bb.record_metrics(step, host)
                                 if bb.nonfinite(host):
                                     bb.dump("nan-loss", tracer=tel)
                                     self._incident("nan-loss")
                     if self.backup_period and self.checkpoint_fn and step % self.backup_period == 0:
-                        with tel.span("checkpoint", step=step):
+                        with span("checkpoint", step=step):
                             self.checkpoint_fn(state, step)
                     if fresh is not None:
                         fresh.maybe_publish(state, step)
                     if max_steps is not None and step >= max_steps:
                         break
-        except BaseException as e:
-            # the flight-recorder moment: a failing run must leave a
-            # post-mortem artifact (ring of recent steps + spans) behind
-            if bb is not None:
-                bb.dump("exception", exc=e, tracer=tel)
-            raise
+            except BaseException as e:
+                # the flight-recorder moment: a failing run must leave a
+                # post-mortem artifact (ring of recent steps + spans) behind
+                if bb is not None:
+                    bb.dump("exception", exc=e, tracer=tel)
+                raise
+            finally:
+                # `finalize` is everything run() does after its last step
+                # but wait for the device (`drain`): this teardown, which
+                # also runs on error/interrupt, and the end-of-run work below
+                with span("finalize"):
+                    self.profiler.close()  # an open capture must be finalized
+                    if isinstance(batches, _Prefetcher):
+                        batches.close()
+                    self._uninstall_sigterm()
+                    # join outstanding background checkpoint writes HERE, not
+                    # only on the happy path: an async save must never be
+                    # orphaned by an exception, and its write errors become
+                    # ledger events, not lost
+                    if self.checkpoint_fn is not None:
+                        self._join_checkpoints()
+            # block so throughput/final metrics are real, then final flush
+            with span("drain"):
+                jax.block_until_ready(jax.tree_util.tree_leaves(state))
+            with span("finalize"):
+                state = self._finalize(state, step, total_items, last_metrics)
         finally:
-            # an open trace must be finalized even on error/interrupt
-            self.profiler.close()
-            if isinstance(batches, _Prefetcher):
-                batches.close()
+            # after the last span, so that the written trace holds them all
             if tel is not None:
                 tel.close()
-            self._uninstall_sigterm()
-            # join outstanding background checkpoint writes HERE, not only on
-            # the happy path: an async save must never be orphaned by an
-            # exception, and its write errors become ledger events, not lost
-            if self.checkpoint_fn is not None:
-                self._join_checkpoints()
-        # block so throughput/final metrics are real, then final flush
-        jax.block_until_ready(jax.tree_util.tree_leaves(state))
+        return state
+
+    def _finalize(self, state, step: int, total_items: int, last_metrics):
+        """End of run(), the device drained: the preemption save, the
+        managers' hand-back to the master layout, final flushes and the run
+        record. Returns the state the caller gets."""
+        tier, reg, bb, tel = self.tier, self.registry, self.blackbox, self.tracer
         if self._preempt.is_set():
             # preemption drain: final save + durable outage record, THEN exit
             # — the next run's `resume: auto` continues from this state
@@ -1104,7 +1102,7 @@ class TrainLoop:
             if audit is not None and "error" in audit:
                 audit = None
             report = goodput_report(
-                events=self.tracer.events(),
+                events=self.tracer.events(self._run_event_idx),
                 audit=audit,
                 steps=steps,
                 items=items,

@@ -24,9 +24,9 @@ from swiftsnails_tpu.utils.config import Config
 class FMTrainer(SparseCTRTrainer):
     name = "fm"
 
-    def __init__(self, config: Config, mesh=None, data=None):
+    def __init__(self, config: Config, mesh=None, data=None, tracer=None):
         self.k = config.get_int("factor_dim", 8)
-        super().__init__(config, mesh=mesh, data=data)
+        super().__init__(config, mesh=mesh, data=data, tracer=tracer)
 
     @property
     def table_dim(self) -> int:
@@ -48,10 +48,10 @@ class FMTrainer(SparseCTRTrainer):
 class FFMTrainer(SparseCTRTrainer):
     name = "ffm"
 
-    def __init__(self, config: Config, mesh=None, data=None):
+    def __init__(self, config: Config, mesh=None, data=None, tracer=None):
         self.k = config.get_int("factor_dim", 4)
         self._num_fields = config.get_int("num_fields")
-        super().__init__(config, mesh=mesh, data=data)
+        super().__init__(config, mesh=mesh, data=data, tracer=tracer)
 
     @property
     def table_dim(self) -> int:

@@ -49,8 +49,9 @@ def _norm(x):
 class SeqLMTrainer(Trainer):
     name = "seqlm"
 
-    def __init__(self, config: Config, mesh=None, corpus_ids=None, vocab_size=None):
-        super().__init__(config, mesh)
+    def __init__(self, config: Config, mesh=None, corpus_ids=None, vocab_size=None,
+                 tracer=None):
+        super().__init__(config, mesh, tracer)
         cfg = config
         self.seq_len = cfg.get_int("seq_len", 256)
         self.n_layers = cfg.get_int("n_layers", 2)

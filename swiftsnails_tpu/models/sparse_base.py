@@ -31,6 +31,7 @@ from swiftsnails_tpu.ops.hashing import hash_row
 from swiftsnails_tpu.parallel.access import AdaGradAccess, SgdAccess
 from swiftsnails_tpu.parallel.store import TableState, create_table, pull, push
 from swiftsnails_tpu.utils.config import Config
+from swiftsnails_tpu.utils.profiling import phase_scope
 
 
 class CTRState(NamedTuple):
@@ -66,8 +67,9 @@ class SparseCTRTrainer(Trainer):
         config: Config,
         mesh=None,
         data: Optional[Tuple[np.ndarray, np.ndarray]] = None,
+        tracer=None,
     ):
-        super().__init__(config, mesh)
+        super().__init__(config, mesh, tracer)
         cfg = config
         self.num_fields = cfg.get_int("num_fields")
         self.capacity = cfg.get_int("capacity", 1 << 20)
@@ -160,14 +162,15 @@ class SparseCTRTrainer(Trainer):
         else:
             from swiftsnails_tpu.data import native
 
-            if native.use_native(cfg):
-                self.labels, self.feats = native.read_ctr(
-                    cfg.get_str("data"), self.num_fields
-                )
-            else:
-                self.labels, self.feats = read_ctr_file(
-                    cfg.get_str("data"), self.num_fields
-                )
+            with self.span("load-data"):  # the text parse
+                if native.use_native(cfg):
+                    self.labels, self.feats = native.read_ctr(
+                        cfg.get_str("data"), self.num_fields
+                    )
+                else:
+                    self.labels, self.feats = read_ctr_file(
+                        cfg.get_str("data"), self.num_fields
+                    )
             # Multi-host: each process trains its round-robin record subset
             # (stdin-split parity, run_worker.sh; record i -> process
             # i % count like iter_line_records). shard_data: 0 disables.
@@ -444,29 +447,35 @@ class SparseCTRTrainer(Trainer):
     def train_step(self, state: CTRState, batch, rng):
         feats, labels = batch["feats"], batch["labels"]
         b, f = feats.shape
-        mask = feats >= 0
-        # tier mode: rows were hashed host-side and remapped to cache slots
-        # (padding fields hash to hash_row(0) on both paths and push only
-        # mask-zeroed gradients, so parity holds bit-for-bit)
-        if self.tiered:
-            rows = batch["rows"].reshape(-1)
-        else:
-            rows = self._rows(feats).reshape(-1)
-        pulled = self._pull_rows(state.table, rows).reshape(b, f, self.table_dim)
+        with phase_scope("prep"):  # ids to rows
+            mask = feats >= 0
+            # tier mode: rows were hashed host-side and remapped to cache
+            # slots (padding fields hash to hash_row(0) on both paths and push
+            # only mask-zeroed gradients, so parity holds bit-for-bit)
+            if self.tiered:
+                rows = batch["rows"].reshape(-1)
+            else:
+                rows = self._rows(feats).reshape(-1)
+        with phase_scope("pull"):
+            pulled = self._pull_rows(state.table, rows).reshape(
+                b, f, self.table_dim)
 
         def loss_of(pulled, dense):
             logits = self.forward(pulled, dense, mask)
             loss = bce_with_logits(logits, labels).mean()
             return loss, logits
 
-        (loss, logits), (dp, dd) = jax.value_and_grad(
-            loss_of, argnums=(0, 1), has_aux=True
-        )(pulled, state.dense)
-        dp = jnp.where(mask[..., None], dp, 0)  # no pushes from padding
-        table = self._push_rows(
-            state.table, rows, dp.reshape(-1, self.table_dim), self.lr)
+        with phase_scope("dense"):  # forward and backward
+            (loss, logits), (dp, dd) = jax.value_and_grad(
+                loss_of, argnums=(0, 1), has_aux=True
+            )(pulled, state.dense)
+            dp = jnp.where(mask[..., None], dp, 0)  # no pushes from padding
+            acc = ((logits > 0) == (labels > 0.5)).mean()
+        with phase_scope("push"):
+            table = self._push_rows(
+                state.table, rows, dp.reshape(-1, self.table_dim), self.lr)
         if state.dense:
-            with self._zero_scope():
+            with phase_scope("dense"), self._zero_scope():  # the dense update
                 updates, opt = self.dense_opt.update(
                     dd, state.opt, state.dense)
                 dense = optax.apply_updates(state.dense, updates)
@@ -479,7 +488,6 @@ class SparseCTRTrainer(Trainer):
                     opt = self._zero_constrain(opt)
         else:
             dense, opt = state.dense, state.opt
-        acc = ((logits > 0) == (labels > 0.5)).mean()
         return CTRState(table, dense, opt), {"loss": loss, "accuracy": acc}
 
     # -- tiered parameter store (table_tier: host; see tiered/) -------------

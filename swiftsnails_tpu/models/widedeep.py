@@ -27,11 +27,11 @@ from swiftsnails_tpu.utils.config import Config
 class WideDeepTrainer(SparseCTRTrainer):
     name = "widedeep"
 
-    def __init__(self, config: Config, mesh=None, data=None):
+    def __init__(self, config: Config, mesh=None, data=None, tracer=None):
         self.k = config.get_int("embed_dim", 16)
         hidden = config.get_str("hidden_dims", "128,64")
         self.hidden_dims: List[int] = [int(x) for x in hidden.replace(";", ",").split(",") if x]
-        super().__init__(config, mesh=mesh, data=data)
+        super().__init__(config, mesh=mesh, data=data, tracer=tracer)
 
     @property
     def table_dim(self) -> int:

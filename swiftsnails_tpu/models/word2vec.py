@@ -57,6 +57,7 @@ from swiftsnails_tpu.parallel.store import (
 )
 from swiftsnails_tpu.framework.trainer import Trainer
 from swiftsnails_tpu.utils.config import Config
+from swiftsnails_tpu.utils.profiling import phase_scope
 
 
 class W2VState(NamedTuple):
@@ -100,8 +101,9 @@ class Word2VecTrainer(Trainer):
         mesh=None,
         corpus_ids: Optional[np.ndarray] = None,
         vocab: Optional[Vocab] = None,
+        tracer=None,
     ):
-        super().__init__(config, mesh)
+        super().__init__(config, mesh, tracer)
         cfg = config
         self.dim = cfg.get_int("dim", 100)
         self.window = cfg.get_int("window", 5)
@@ -267,39 +269,40 @@ class Word2VecTrainer(Trainer):
         self._chunk_factory = None
         self._local_total = None  # approx local tokens/epoch (progress denom)
         if corpus_ids is None:
-            data_path = cfg.get_str("data")
-            if self.stream:
-                from swiftsnails_tpu.data.text import encode_corpus_stream
-                from swiftsnails_tpu.parallel.cluster import byte_span, process_info
+            with self.span("load-data"):  # vocabulary scan + corpus encode
+                data_path = cfg.get_str("data")
+                if self.stream:
+                    from swiftsnails_tpu.data.text import encode_corpus_stream
+                    from swiftsnails_tpu.parallel.cluster import byte_span, process_info
 
-                span = (0, 0)
-                n_proc = 1
-                if cfg.get_bool("shard_data", True):
-                    span = byte_span(data_path)
-                    n_proc = process_info()[1]
-                vocab, self._chunk_factory = encode_corpus_stream(
-                    data_path,
-                    self.chunk_tokens,
-                    min_count=cfg.get_int("min_count", 5),
-                    max_vocab=cfg.get_int("max_vocab", 0) or None,
-                    byte_start=span[0],
-                    byte_end=span[1],
-                )
-                # even byte spans => ~even token spans (progress denominator)
-                self._local_total = max(int(vocab.counts.sum()) // n_proc, 1)
-            else:
-                corpus_ids, vocab = encode_corpus(
-                    data_path,
-                    min_count=cfg.get_int("min_count", 5),
-                    max_vocab=cfg.get_int("max_vocab", 0) or None,
-                )
-                # Multi-host: train on this process's contiguous corpus span
-                # (stdin-split parity; vocab stays global so ids/placement
-                # agree across hosts). shard_data: 0 = every host trains all.
-                if cfg.get_bool("shard_data", True):
-                    from swiftsnails_tpu.parallel.cluster import shard_token_stream
+                    span = (0, 0)
+                    n_proc = 1
+                    if cfg.get_bool("shard_data", True):
+                        span = byte_span(data_path)
+                        n_proc = process_info()[1]
+                    vocab, self._chunk_factory = encode_corpus_stream(
+                        data_path,
+                        self.chunk_tokens,
+                        min_count=cfg.get_int("min_count", 5),
+                        max_vocab=cfg.get_int("max_vocab", 0) or None,
+                        byte_start=span[0],
+                        byte_end=span[1],
+                    )
+                    # even byte spans => ~even token spans (progress denominator)
+                    self._local_total = max(int(vocab.counts.sum()) // n_proc, 1)
+                else:
+                    corpus_ids, vocab = encode_corpus(
+                        data_path,
+                        min_count=cfg.get_int("min_count", 5),
+                        max_vocab=cfg.get_int("max_vocab", 0) or None,
+                    )
+                    # Multi-host: train on this process's contiguous corpus span
+                    # (stdin-split parity; vocab stays global so ids/placement
+                    # agree across hosts). shard_data: 0 = every host trains all.
+                    if cfg.get_bool("shard_data", True):
+                        from swiftsnails_tpu.parallel.cluster import shard_token_stream
 
-                    corpus_ids = shard_token_stream(corpus_ids)
+                        corpus_ids = shard_token_stream(corpus_ids)
         assert vocab is not None, "vocab required when corpus_ids is given"
         if corpus_ids is not None:
             self.corpus_ids = np.asarray(corpus_ids, dtype=np.int32)
@@ -314,7 +317,8 @@ class Word2VecTrainer(Trainer):
                 f"vocab {len(vocab)} exceeds capacity {cap}; set hash_keys: 1"
             )
         self.access = SgdAccess()
-        self.neg_alias = build_unigram_alias(vocab.counts)
+        with self.span("alias-table"):
+            self.neg_alias = build_unigram_alias(vocab.counts)
         # placement: uniform|hybrid|auto — hybrid head/tail split of both
         # tables: the zipf head replicated (dense grad reduce over `data`),
         # the tail model-sharded through the collective twins in tail slot
@@ -924,10 +928,13 @@ class Word2VecTrainer(Trainer):
         pc = self._effective_pc(n)
         nb = n // pc
         pn = self.pool_size
-        pools = alias_sample(self.neg_alias, rng, (nb, pn))
-        ctx_rows = jnp.where(
-            ctxs >= 0, self._rows(jnp.maximum(ctxs, 0)), -1
-        )  # hash real ids only; pads stay -1
+        with phase_scope("prep"):  # the negatives' draw, ids to rows
+            pools = alias_sample(self.neg_alias, rng, (nb, pn))
+            ctx_rows = jnp.where(
+                ctxs >= 0, self._rows(jnp.maximum(ctxs, 0)), -1
+            )  # hash real ids only; pads stay -1
+            center_rows = self._rows(centers)
+            pool_rows = self._rows(pools.reshape(-1))
         # resident needs >= 8 hot rows after clipping to capacity
         hot_n = min(self.hot_rows, self.capacity)
         if self.dedup and self.resident and hot_n >= 8:
@@ -960,9 +967,9 @@ class Word2VecTrainer(Trainer):
         in_t, out_t, loss = step_fn(
             state.in_table.table,
             state.out_table.table,
-            self._rows(centers),
+            center_rows,
             ctx_rows,
-            self._rows(pools.reshape(-1)),
+            pool_rows,
             lr=lr,
             lam=self.negatives / pn,
             window=self.window,

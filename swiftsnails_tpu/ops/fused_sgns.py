@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from swiftsnails_tpu.utils.profiling import phase_scope
+
 
 _WAIT_CHUNK = 64
 
@@ -377,23 +379,24 @@ def fused_sgns_grouped_step(
     if in_table.shape[1:] != out_table.shape[1:] or in_table.dtype != out_table.dtype:
         raise ValueError("in/out tables must share row shape and dtype")
 
-    # [CW, PC] orientation throughout (PC = lanes): flat slot k = c*PC + p
-    flat = (
-        ctxs.reshape(nblocks, pc, cw).transpose(0, 2, 1).reshape(nblocks, cap)
-    ).astype(jnp.int32)
-    valid = flat >= 0
-    # compact real context slots to the front of each block's copy list,
-    # with last-occurrence write flags (under last-write-wins only the
-    # LAST write of a duplicated row within a block survives, so all
-    # others are skipped in the writeback — bit-identical result, fewer
-    # copies); one shared single-sort pass does both
-    ctx_rows, ctx_slot, nctx, nwrite_u = _cold_compact(flat, valid)
-    mask = valid.reshape(nblocks, cw, pc).astype(jnp.float32)
+    with phase_scope("prep"):  # the copy lists, in XLA
+        # [CW, PC] orientation throughout (PC = lanes): flat slot k = c*PC + p
+        flat = (
+            ctxs.reshape(nblocks, pc, cw).transpose(0, 2, 1).reshape(nblocks, cap)
+        ).astype(jnp.int32)
+        valid = flat >= 0
+        # compact real context slots to the front of each block's copy list,
+        # with last-occurrence write flags (under last-write-wins only the
+        # LAST write of a duplicated row within a block survives, so all
+        # others are skipped in the writeback — bit-identical result, fewer
+        # copies); one shared single-sort pass does both
+        ctx_rows, ctx_slot, nctx, nwrite_u = _cold_compact(flat, valid)
+        mask = valid.reshape(nblocks, cw, pc).astype(jnp.float32)
 
-    c_blocks = centers.astype(jnp.int32).reshape(nblocks, pc)
-    c_last = _last_occurrence(c_blocks, jnp.ones_like(c_blocks, bool))
-    nwrite_c = c_last.sum(axis=1).astype(jnp.int32)
-    c_packed = (c_blocks | jnp.where(c_last, 1 << 30, 0)).reshape(-1)
+        c_blocks = centers.astype(jnp.int32).reshape(nblocks, pc)
+        c_last = _last_occurrence(c_blocks, jnp.ones_like(c_blocks, bool))
+        nwrite_c = c_last.sum(axis=1).astype(jnp.int32)
+        c_packed = (c_blocks | jnp.where(c_last, 1 << 30, 0)).reshape(-1)
 
     kern = functools.partial(
         _grouped_kernel, lam=lam, inv_b=inv_b, pc=pc, cw=cw, pool=pn
@@ -419,31 +422,35 @@ def fused_sgns_grouped_step(
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )
-    new_in, new_out, loss_parts = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct(in_table.shape, in_table.dtype),
-            jax.ShapeDtypeStruct(out_table.shape, out_table.dtype),
-            jax.ShapeDtypeStruct((nblocks, 8, 128), jnp.float32),
-        ),
-        input_output_aliases={9: 0, 10: 1},
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
-        interpret=interpret,
-    )(
-        c_packed,
-        ctx_rows.reshape(-1),
-        ctx_slot.reshape(-1),
-        nctx,
-        nwrite_c,
-        nwrite_u,
-        pool_rows.astype(jnp.int32),
-        jnp.asarray(lr, jnp.float32).reshape(1),
-        mask,
-        in_table,
-        out_table,
-    )
-    return new_in, new_out, loss_parts[:, 0, 0].sum()
+    # the inner scope keeps the kernel's instruction named after this function
+    # on the device timeline (XLA names a custom call by its innermost scope)
+    with phase_scope("fused"), jax.named_scope("fused_sgns_grouped_step"):
+        new_in, new_out, loss_parts = pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=(
+                jax.ShapeDtypeStruct(in_table.shape, in_table.dtype),
+                jax.ShapeDtypeStruct(out_table.shape, out_table.dtype),
+                jax.ShapeDtypeStruct((nblocks, 8, 128), jnp.float32),
+            ),
+            input_output_aliases={9: 0, 10: 1},
+            compiler_params=pltpu.CompilerParams(has_side_effects=True),
+            interpret=interpret,
+        )(
+            c_packed,
+            ctx_rows.reshape(-1),
+            ctx_slot.reshape(-1),
+            nctx,
+            nwrite_c,
+            nwrite_u,
+            pool_rows.astype(jnp.int32),
+            jnp.asarray(lr, jnp.float32).reshape(1),
+            mask,
+            in_table,
+            out_table,
+        )
+        loss = loss_parts[:, 0, 0].sum()
+    return new_in, new_out, loss
 
 
 def _resident_kernel(ccold_rows_ref, ccold_slot_ref, ncc_ref, nwc_ref,

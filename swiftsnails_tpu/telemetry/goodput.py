@@ -73,6 +73,7 @@ def peaks_for(device_kind: Optional[str],
 # spans the TrainLoop emits, bucketed for the decomposition
 _SPAN_BUCKETS = {
     "step": "compute_s",          # jitted dispatch + device sync
+    "drain": "compute_s",         # the device finishing what was dispatched ahead
     "h2d": "h2d_s",
     "prefetch-wait": "host_blocked_s",
     "tier-fault": "host_blocked_s",       # tiered residency work on the step
@@ -80,6 +81,7 @@ _SPAN_BUCKETS = {
     "chaos-slow": "host_blocked_s",       # injected slow_step host sleep
     "metrics-flush": "other_s",
     "checkpoint": "other_s",
+    "finalize": "other_s",        # end-of-run teardown, flushes, joins
 }
 
 

@@ -7,14 +7,18 @@ recording, and export to the Chrome/Perfetto trace-event JSON format, so a
 ``trace_path`` file drops straight into ``chrome://tracing`` / ui.perfetto.dev
 — or into ``tools/trace_summary.py`` for a terminal breakdown.
 
-Device-side alignment: :meth:`Tracer.step_span` opens the host span inside a
-``jax.profiler.StepTraceAnnotation``, so when a ``profile_dir`` capture runs
-concurrently (utils/profiling.py), the host spans and the XLA device timeline
-carry the same step numbers and line up in the combined view.
+Device-side alignment: the TrainLoop opens each step's span inside
+``utils.profiling.step_annotation`` (a ``jax.profiler.StepTraceAnnotation``),
+so when a ``profile_dir`` capture runs concurrently the host spans and the
+XLA device timeline carry the same step numbers and line up in the combined
+view.
 
-Cost contract: a Tracer only exists when telemetry is enabled (the TrainLoop
-holds ``None`` otherwise and branches once per step). Recording one span is
-one ``perf_counter_ns`` pair, one small tuple, and one lock-guarded append.
+Cost contract: a Tracer only exists when telemetry is enabled
+(:func:`tracer_from_config` gives ``None`` otherwise). Code that is
+instrumented either way takes its spans from :func:`span_fn`: with no tracer
+every ``with span(...)`` is the one shared :data:`NO_SPAN`, which does nothing
+and allocates nothing. Recording one span is one ``perf_counter_ns`` pair, one
+small tuple, and one lock-guarded append.
 """
 
 from __future__ import annotations
@@ -25,8 +29,7 @@ import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
-# event tuples: (name, ts_ns, dur_ns, tid, depth, args_or_None) for "X"
-# spans; counters are recorded separately as (name, ts_ns, value, tid).
+# event tuples: (name, ts_ns, dur_ns, tid, depth, args_or_None), "X" spans
 _Event = Tuple[str, int, int, int, int, Optional[Dict]]
 
 
@@ -56,25 +59,38 @@ class _SpanCtx:
         )
 
 
-class _StepSpanCtx:
-    """Host span + ``jax.profiler.StepTraceAnnotation`` for device alignment."""
+class _NoSpan:
+    """The span of a run without telemetry: entering and leaving do nothing."""
 
-    __slots__ = ("_span", "_ann")
+    __slots__ = ()
 
-    def __init__(self, tracer: "Tracer", name: str, step: int):
-        self._span = _SpanCtx(tracer, name, {"step": step})
-        import jax
-
-        self._ann = jax.profiler.StepTraceAnnotation(name, step_num=step)
-
-    def __enter__(self) -> "_StepSpanCtx":
-        self._ann.__enter__()
-        self._span.__enter__()
-        return self
+    def __enter__(self) -> None:
+        return None
 
     def __exit__(self, *exc) -> None:
-        self._span.__exit__(*exc)
-        self._ann.__exit__(*exc)
+        return None
+
+
+NO_SPAN = _NoSpan()
+
+
+def _no_span(name: str, **args) -> _NoSpan:
+    return NO_SPAN
+
+
+def span_fn(tracer: Optional["Tracer"]):
+    """``tracer.span``, or with no tracer a function that hands out
+    :data:`NO_SPAN` - so one body of code runs traced and untraced."""
+    return tracer.span if tracer is not None else _no_span
+
+
+def tracer_from_config(cfg) -> Optional["Tracer"]:
+    """A Tracer when the config turns telemetry on (``telemetry: 1`` or a
+    ``trace_path``, where ``close`` writes the trace), else ``None``."""
+    path = cfg.get_str("trace_path", "")
+    if cfg.get_bool("telemetry", False) or path:
+        return Tracer(path=path or None)
+    return None
 
 
 class Tracer:
@@ -89,11 +105,9 @@ class Tracer:
         self.path = path
         self.process_name = process_name
         self._events: List[_Event] = []
-        self._counters: List[Tuple[str, int, float, int]] = []
         self._lock = threading.Lock()
         self._tls = threading.local()
         self._epoch_ns = time.perf_counter_ns()
-        self._closed = False
 
     # -- recording ---------------------------------------------------------
 
@@ -101,20 +115,14 @@ class Tracer:
         """Open a nestable span: ``with tracer.span("h2d"): ...``"""
         return _SpanCtx(self, name, args or None)
 
-    def step_span(self, name: str, step: int) -> _StepSpanCtx:
-        """A span that also labels the device timeline with the step number."""
-        return _StepSpanCtx(self, name, step)
-
-    def counter(self, name: str, value: float) -> None:
-        """Record an instantaneous counter sample (Chrome "C" event)."""
-        with self._lock:
-            self._counters.append(
-                (name, time.perf_counter_ns(), float(value), threading.get_ident())
-            )
-
     def _record(self, event: _Event) -> None:
         with self._lock:
             self._events.append(event)
+
+    def n_events(self) -> int:
+        """Spans recorded so far: an index for :meth:`events`. (Not
+        ``__len__``: a tracer with nothing recorded yet must not be falsy.)"""
+        return len(self._events)
 
     # -- export ------------------------------------------------------------
 
@@ -142,7 +150,6 @@ class Tracer:
         pid = os.getpid()
         with self._lock:
             spans = list(self._events)
-            counters = list(self._counters)
         events: List[Dict] = [
             {
                 "ph": "M",
@@ -164,17 +171,6 @@ class Tracer:
             if args:
                 ev["args"] = args
             events.append(ev)
-        for name, t0, value, tid in counters:
-            events.append(
-                {
-                    "ph": "C",
-                    "pid": pid,
-                    "tid": tid,
-                    "name": name,
-                    "ts": (t0 - self._epoch_ns) / 1e3,
-                    "args": {"value": value},
-                }
-            )
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def export(self, path: str) -> None:
@@ -182,9 +178,7 @@ class Tracer:
             json.dump(self.chrome_trace(), f)
 
     def close(self) -> None:
-        """Finalize: write the trace to ``path`` (idempotent, keeps events)."""
-        if self._closed:
-            return
+        """Write the trace as it stands to ``path``. Keeps the events, so a
+        later close (a second run on one trainer's tracer) writes them all."""
         if self.path:
             self.export(self.path)
-        self._closed = True

@@ -32,6 +32,7 @@ import numpy as np
 
 import jax
 
+from swiftsnails_tpu.telemetry.tracer import span_fn
 from swiftsnails_tpu.tiered.store import (
     HostMaster, TieredTable, TierStats, _FlushQueue, resolve_master_dtype,
 )
@@ -276,10 +277,7 @@ class TierManager:
         decomposition)."""
         if self.flusher is None:
             return
-        if self.tracer is not None:
-            with self.tracer.span("tier-flush-wait"):
-                self.flusher.drain()
-        else:
+        with span_fn(self.tracer)("tier-flush-wait"):
             self.flusher.drain()
 
     def flush_dirty(self, state) -> None:

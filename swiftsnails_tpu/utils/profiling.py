@@ -8,12 +8,13 @@ keys:
 * ``profile_dir``   — where to write the trace (enables profiling);
 * ``profile_steps`` — "start,stop" step numbers for the capture window
   (default "10,20": skips compile, captures 10 steady-state steps).
+
+And the names the device timeline carries: :func:`step_annotation` puts the
+step number on the host side of a capture, :func:`phase_scope` names what a
+part of a jitted step is for, in every model alike.
 """
 
 from __future__ import annotations
-
-import contextlib
-from typing import Iterator, Optional
 
 import jax
 
@@ -62,8 +63,25 @@ class StepProfiler:
             self._active = False
 
 
-@contextlib.contextmanager
-def step_annotation(name: str, step: int) -> Iterator[None]:
+def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
     """Label host-side work for the profiler timeline."""
-    with jax.profiler.StepTraceAnnotation(name, step_num=step):
-        yield
+    return jax.profiler.StepTraceAnnotation(name, step_num=step)
+
+
+# What the operations of a jitted train step are for. A name means the same
+# in every model: ``prep`` turns ids into rows, negatives, copy lists and
+# merge plans; ``fused`` is Word2Vec's grouped SGNS kernel; ``pull`` and
+# ``push`` read and update table rows; ``dense`` is a CTR model's forward,
+# backward and dense update. Scopes are metadata on the operations (no
+# operation, no flag): a ``profile_dir`` capture shows them as the name
+# scope of each device operation, and ``benchmark/lib/scopes.py`` sums
+# device time by the innermost one. The prefix stays clear of the ``ssn_*``
+# labels that ``telemetry/audit.py`` groups collective bytes by.
+PHASES = ("prep", "fused", "pull", "push", "dense")
+
+
+def phase_scope(phase: str):
+    """``jax.named_scope`` of one of :data:`PHASES`: ``phase_<name>``."""
+    if phase not in PHASES:
+        raise ValueError(f"unknown phase {phase!r}; one of {PHASES}")
+    return jax.named_scope("phase_" + phase)

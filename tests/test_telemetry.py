@@ -32,9 +32,8 @@ def test_tracer_nested_spans_and_export(tmp_path):
             pass
     with tr.span("outer", step=1):
         pass
-    tr.counter("queue_depth", 2)
     tr.close()
-    tr.close()  # idempotent
+    tr.close()  # idempotent: the same events again
 
     doc = json.load(open(path))
     assert "traceEvents" in doc  # chrome-loadable shape
@@ -47,8 +46,6 @@ def test_tracer_nested_spans_and_export(tmp_path):
     o = outers[0]
     assert o["ts"] <= inner["ts"]
     assert inner["ts"] + inner["dur"] <= o["ts"] + o["dur"] + 1e-3
-    counters = [e for e in doc["traceEvents"] if e.get("ph") == "C"]
-    assert counters and counters[0]["args"]["value"] == 2.0
     # depth bookkeeping survives exceptions
     with pytest.raises(RuntimeError):
         with tr.span("erring"):
@@ -79,17 +76,6 @@ def test_tracer_threads_record_independently():
     assert sum(e["name"] == "worker" for e in evs) == 100
     assert sum(e["name"] == "main" for e in evs) == 50
     assert len({e["tid"] for e in evs}) >= 2
-
-
-def test_step_span_bridges_profiler():
-    tr = Tracer()
-    with tr.step_span("train", 7):
-        with tr.span("h2d"):
-            pass
-    evs = tr.events()
-    outer = next(e for e in evs if e["name"] == "train")
-    assert outer["args"] == {"step": 7}
-    assert any(e["name"] == "h2d" and e["depth"] == 1 for e in evs)
 
 
 # ----------------------------------------------- HLO audit: text parsing ---

@@ -49,7 +49,7 @@ def test_traced_run_produces_chrome_trace(traced_run):
     names = {e["name"] for e in evs}
     assert {"prefetch-wait", "h2d", "step"} <= names, names
     assert sum(e["name"] == "step" for e in evs) == 5
-    # nesting: every dispatch span sits inside its step_span (trainer name)
+    # nesting: every dispatch span sits inside its step's span (trainer name)
     outers = [e for e in evs if e["name"] == "word2vec"]
     assert outers
     for s in (e for e in evs if e["name"] == "step"):
@@ -57,11 +57,8 @@ def test_traced_run_produces_chrome_trace(traced_run):
             o["ts"] <= s["ts"] and s["ts"] + s["dur"] <= o["ts"] + o["dur"] + 1e-3
             for o in outers
         )
-    # the prefetcher queue-depth gauge also lands in the trace as counters
-    assert any(
-        e.get("ph") == "C" and e["name"] == "prefetch_queue_depth"
-        for e in doc["traceEvents"]
-    )
+    # the producer thread's side of the queue is in the trace too
+    assert "produce" in names
 
 
 def test_trace_summary_renders_breakdown(traced_run):
