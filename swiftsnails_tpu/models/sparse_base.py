@@ -375,6 +375,8 @@ class SparseCTRTrainer(Trainer):
         return pull(table_state, rows)
 
     def _push_rows(self, table_state, rows, grads, lr):
+        """The pushed table and, where one chip's small-row plane did the
+        push, its count of distinct tiles (``store.live_count``), else None."""
         from swiftsnails_tpu.parallel.hybrid import is_hybrid
 
         if self.packed:
@@ -389,7 +391,7 @@ class SparseCTRTrainer(Trainer):
                             self.mesh, table_state, rows, grads, self.access,
                             lr, self.table_dim, comm_dtype=self.comm_dtype,
                             zero=self.zero,
-                        )
+                        ), None
                     from swiftsnails_tpu.parallel.transfer import (
                         push_collective_packed_small,
                     )
@@ -397,7 +399,7 @@ class SparseCTRTrainer(Trainer):
                     return push_collective_packed_small(
                         self.mesh, table_state, rows, grads, self.access, lr,
                         self.table_dim, comm_dtype=self.comm_dtype,
-                    )
+                    ), None
             from swiftsnails_tpu.parallel.store import push_packed_small
 
             return push_packed_small(
@@ -409,8 +411,8 @@ class SparseCTRTrainer(Trainer):
             with self._tbl_scope():
                 return push_hybrid(self.mesh, table_state, rows, grads,
                                    self.access, lr, comm_dtype=self.comm_dtype,
-                                   zero=self.zero)
-        return push(table_state, rows, grads, self.access, lr)
+                                   zero=self.zero), None
+        return push(table_state, rows, grads, self.access, lr), None
 
     def _row_chunks(self, rows_per_chunk: int = 1 << 20):
         """Streamed (labels, feats) chunks of this process's byte span."""
@@ -472,7 +474,7 @@ class SparseCTRTrainer(Trainer):
             dp = jnp.where(mask[..., None], dp, 0)  # no pushes from padding
             acc = ((logits > 0) == (labels > 0.5)).mean()
         with phase_scope("push"):
-            table = self._push_rows(
+            table, live = self._push_rows(
                 state.table, rows, dp.reshape(-1, self.table_dim), self.lr)
         if state.dense:
             with phase_scope("dense"), self._zero_scope():  # the dense update
@@ -488,7 +490,12 @@ class SparseCTRTrainer(Trainer):
                     opt = self._zero_constrain(opt)
         else:
             dense, opt = state.dense, state.opt
-        return CTRState(table, dense, opt), {"loss": loss, "accuracy": acc}
+        metrics = {"loss": loss, "accuracy": acc}
+        if live is not None:
+            # the share of the push's slots that held a distinct tile: what
+            # the fused scatter did per-slot work for
+            metrics["push_live_share"] = live / rows.shape[0]
+        return CTRState(table, dense, opt), metrics
 
     # -- tiered parameter store (table_tier: host; see tiered/) -------------
 

@@ -142,6 +142,9 @@ def _check_rowdma(name: str, capacity: int, dim: int, n: int,
         return {"compile_s": secs, "max_err": err, "rel_err": rel,
                 "agrees": rel <= REL_TOL}
     if name == "scatter_adagrad_fused_rows":
+        # the kernel is told how many leading rows are live: everything
+        # (the padding slots fall past the count) and a quarter, where the
+        # slots past the count hold valid ids that must stay as they were
         fshape = (capacity, 2, rowdma.ROW_LANES)  # sublane 0 param, 1 accum
         g = deltas[:, :1, :]
 
@@ -150,17 +153,21 @@ def _check_rowdma(name: str, capacity: int, dim: int, n: int,
             return t.at[:, 1, :].set(jnp.abs(t[:, 1, :]) + 0.01)
 
         fn, secs = _compile(rowdma.scatter_adagrad_fused_rows, fresh(), rows,
-                            g, lr, eps=eps, **kw)
-        got = fn(fresh(), rows, g, lr)
+                            g, lr, n, eps=eps, **kw)
         before = fresh()
         cur = before[jnp.minimum(rows, capacity - 1)]
         acc = cur[:, 1:2, :] + g * g
         par = cur[:, 0:1, :] - lr * g * jax.lax.rsqrt(acc + eps)
-        want = before.at[rows].set(
-            jnp.concatenate([par, acc], axis=1), mode="drop")
-        err, rel = _rel_err([got], [want], [before])
+        new = jnp.concatenate([par, acc], axis=1)
+        err = rel = 0.0
+        for count in (n - 7, n // 4):
+            got = fn(fresh(), rows, g, lr, count)
+            live = jnp.where(jnp.arange(n) < count, rows, capacity)
+            want = before.at[live].set(new, mode="drop")
+            e, r = _rel_err([got], [want], [before])
+            err, rel = max(err, e), max(rel, r)
         return {"compile_s": secs, "max_err": err, "rel_err": rel,
-                "agrees": rel <= REL_TOL}
+                "agrees": rel <= REL_TOL, "live_counts": [n - 7, n // 4]}
     raise KeyError(name)
 
 

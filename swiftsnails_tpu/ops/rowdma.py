@@ -35,7 +35,10 @@ an option; equal-sized copies make shared byte-accounting exact):
   HBM cache plane from one fused host staging buffer, one DMA per row.
 * :func:`scatter_adagrad_rows` / :func:`scatter_adagrad_fused_rows` —
   fused AdaGrad RMW (split param/accum buffers, or both packed into one
-  stored tile so a single DMA pair moves them).
+  stored tile so a single DMA pair moves them). The slot-fused kernel takes
+  the live count as an operand: ``rows[:count]`` are unique and in range
+  (``merge_duplicate_rows`` sorts them first), every slot after them is
+  ignored and costs the scalar core nothing.
 
 Which of these runs is decided in one place, :func:`on_tpu`: on a TPU
 backend the callers in ``parallel/store.py`` always take these kernels; on
@@ -51,6 +54,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from swiftsnails_tpu.ops.fused_sgns import _WAIT_CHUNK, _wait_rows
 
 ROW_LANES = 128
 
@@ -479,67 +484,94 @@ def scatter_adagrad_rows(
 
 # -------------------------------------------- slot-fused AdaGrad (1 tile) ---
 
+# DMA starts per iteration of the issue loops: 2.99 -> 2.25 ms for the
+# Wide&Deep cell's 55,893 live rows on a v5e (16: 2.17; PERF.md, PR 27)
+_START_UNROLL = 8
 
-def _adagrad_fused_kernel(rows_ref, lr_ref, table_in, deltas_ref, table_ref,
-                          scratch, read_sems, write_sems, *, eps):
+
+def _adagrad_fused_kernel(rows_ref, lr_ref, count_ref, table_in, deltas_ref,
+                          table_ref, scratch, read_sems, write_sems,
+                          *, eps):
     """AdaGrad RMW where param AND accum live in ONE stored tile
     (``table[r] = [param_row, accum_row]`` along the sublane axis): one read
     DMA + one write DMA per row moves both, halving the issue-bound DMA
-    count of the split-buffer kernel. Rows must be unique; ``>= capacity``
-    skipped."""
+    count of the split-buffer kernel.
+
+    Only ``rows[:count]`` are live (unique, in range); the scalar core does
+    no per-slot work beyond them: a block past the count is an empty grid
+    step, the last live block loops over its live rows only, and no slot is
+    tested against the capacity."""
     del table_in
     lr = lr_ref[0]
+    count = count_ref[0]
     R = scratch.shape[1]
-    C = table_ref.shape[0]
     i = pl.program_id(0)
-    nblocks = pl.num_programs(0)
+    nlive = pl.cdiv(count, R)  # live blocks; the last one may be partial
 
-    def dma(b, slot, j, read):
-        pair = (table_ref.at[rows_ref[b * R + j]], scratch.at[slot, j])
-        src, dst = pair if read else pair[::-1]
-        sems = read_sems if read else write_sems
-        return pltpu.make_async_copy(src, dst, sems.at[slot])
+    def live_rows(b):
+        return jnp.minimum(R, count - b * R)
 
-    def for_valid(b, fn):
-        def body(j, _):
-            @pl.when(rows_ref[b * R + j] < C)
-            def _():
-                fn(j)
+    def start(b, slot, read):
+        def one(j):
+            pair = (table_ref.at[rows_ref[b * R + j]], scratch.at[slot, j])
+            src, dst = pair if read else pair[::-1]
+            sems = read_sems if read else write_sems
+            pltpu.make_async_copy(src, dst, sems.at[slot]).start()
+
+        def group(k, _):
+            for u in range(_START_UNROLL):
+                one(k * _START_UNROLL + u)
             return 0
-        jax.lax.fori_loop(0, R, body, 0)
 
-    @pl.when(i == 0)
+        n = live_rows(b)
+        whole = n // _START_UNROLL
+        jax.lax.fori_loop(0, whole, group, 0)
+        jax.lax.fori_loop(
+            whole * _START_UNROLL, n, lambda j, _: (one(j), 0)[1], 0)
+
+    def wait(b, slot, read):
+        # equal-sized copies on a shared semaphore: retired by descriptor
+        # size, a chunk of rows at a time
+        sems = read_sems if read else write_sems
+        _wait_rows(scratch.at[slot, 0], scratch.at[slot, :min(_WAIT_CHUNK, R)],
+                   sems.at[slot], live_rows(b))
+
+    @pl.when(i < nlive)
     def _():
-        for_valid(0, lambda j: dma(0, 0, j, True).start())
-
-    @pl.when(i + 1 < nblocks)
-    def _():
-        slot_next = (i + 1) % 2
-
-        @pl.when(i >= 1)
+        @pl.when(i == 0)
         def _():
-            for_valid(i - 1, lambda j: dma(i - 1, slot_next, j, False).wait())
+            start(0, 0, True)
 
-        for_valid(i + 1, lambda j: dma(i + 1, slot_next, j, True).start())
-
-    slot = i % 2
-    for_valid(i, lambda j: dma(i, slot, j, True).wait())
-
-    g = deltas_ref[...].astype(jnp.float32)  # [R, 1, 128]
-    tile = scratch[slot].astype(jnp.float32)  # [R, 2, 128]
-    accum = tile[:, 1:2, :] + g * g
-    param = tile[:, 0:1, :] - lr * g * jax.lax.rsqrt(accum + eps)
-    scratch[slot] = jnp.concatenate([param, accum], axis=1).astype(scratch.dtype)
-
-    for_valid(i, lambda j: dma(i, slot, j, False).start())
-
-    @pl.when(i == nblocks - 1)
-    def _():
-        for_valid(i, lambda j: dma(i, slot, j, False).wait())
-
-        @pl.when(nblocks >= 2)
+        @pl.when(i + 1 < nlive)
         def _():
-            for_valid(i - 1, lambda j: dma(i - 1, (i - 1) % 2, j, False).wait())
+            slot_next = (i + 1) % 2
+
+            # block i-1 used slot_next; its writebacks must land before we
+            # overwrite the slot's scratch with new reads.
+            @pl.when(i >= 1)
+            def _():
+                wait(i - 1, slot_next, False)
+
+            start(i + 1, slot_next, True)
+
+        slot = i % 2
+        wait(i, slot, True)
+
+        g = deltas_ref[...].astype(jnp.float32)  # [R, 1, 128]
+        tile = scratch[slot].astype(jnp.float32)  # [R, 2, 128]
+        accum = tile[:, 1:2, :] + g * g
+        param = tile[:, 0:1, :] - lr * g * jax.lax.rsqrt(accum + eps)
+        scratch[slot] = jnp.concatenate([param, accum], axis=1).astype(scratch.dtype)
+
+        start(i, slot, False)
+
+        @pl.when(i == nlive - 1)
+        def _():
+            wait(i, slot, False)
+
+            @pl.when(i >= 1)
+            def _():
+                wait(i - 1, (i - 1) % 2, False)
 
 
 @functools.partial(
@@ -552,23 +584,35 @@ def scatter_adagrad_fused_rows(
     rows: jax.Array,
     grads: jax.Array,  # [N, 1, 128]
     lr,
+    count,
     eps: float = 1e-8,
     block_rows: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
-    """Slot-fused AdaGrad RMW for UNIQUE rows; see ``_adagrad_fused_kernel``."""
+    """Slot-fused AdaGrad RMW; see ``_adagrad_fused_kernel``.
+
+    ``rows[:count]`` must be unique and in ``[0, capacity)`` (what
+    ``store.merge_duplicate_rows`` puts first, counted by
+    ``store.live_count``); every slot from ``count`` on is ignored, whatever
+    id and gradient it holds."""
     n = rows.shape[0]
     c, s, lanes = table.shape
     if s != 2:
         raise ValueError(f"slot-fused table must be [C, 2, 128], got {table.shape}")
     if n % block_rows:
         raise ValueError(f"N={n} not a multiple of block_rows={block_rows}")
+
+    def deltas_block(i, rows_ref, lr_ref, count_ref):
+        # dead blocks re-use the last live block's index: nothing is fetched
+        last = jnp.maximum(pl.cdiv(count_ref[0], block_rows) - 1, 0)
+        return (jnp.minimum(i, last), 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((block_rows, 1, lanes), lambda i, *_: (i, 0, 0)),
+            pl.BlockSpec((block_rows, 1, lanes), deltas_block),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
@@ -581,12 +625,13 @@ def scatter_adagrad_fused_rows(
         functools.partial(_adagrad_fused_kernel, eps=eps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(table.shape, table.dtype),
-        input_output_aliases={2: 0},
+        input_output_aliases={3: 0},
         compiler_params=pltpu.CompilerParams(has_side_effects=True),
         interpret=interpret,
     )(
         rows.astype(jnp.int32),
         jnp.asarray(lr, jnp.float32).reshape(1),
+        jnp.asarray(count, jnp.int32).reshape(1),
         table,
         grads.astype(table.dtype),
     )

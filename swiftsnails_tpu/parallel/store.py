@@ -196,6 +196,17 @@ def merge_duplicate_rows(
     return uniq, merged
 
 
+def live_count(uniq: jax.Array, invalid_row: int) -> jax.Array:
+    """How many leading slots of a merged ``uniq`` hold a row to update.
+
+    :func:`merge_duplicate_rows` sorts, so the distinct rows below
+    ``invalid_row`` fill slots ``0..count-1`` and everything else (padding
+    ids ``>= invalid_row``, the fill) comes after them: the count is all a
+    kernel needs to know where the work ends
+    (``ops/rowdma.scatter_adagrad_fused_rows``)."""
+    return jnp.sum(uniq < invalid_row, dtype=jnp.int32)
+
+
 def apply_rows(
     table: jax.Array,
     slots: "Slots",
@@ -412,19 +423,19 @@ def push_packed_small(
     lr,
     dim: int,
     block_rows: int = 512,
-) -> PackedTableState:
-    """Merge-by-tile -> one fused RMW kernel (SGD add / in-kernel AdaGrad)."""
-    from swiftsnails_tpu.ops import rowdma
-    from swiftsnails_tpu.ops.rowdma import ROW_LANES, scatter_adagrad_rows
-    from swiftsnails_tpu.parallel.access import AdaGradAccess, SgdAccess
+) -> Tuple[PackedTableState, jax.Array]:
+    """Merge-by-tile -> one fused RMW kernel (SGD add / in-kernel AdaGrad).
 
-    from swiftsnails_tpu.ops.rowdma import scatter_adagrad_fused_rows
+    Returns the new state and the number of distinct tiles the push touched
+    (:func:`live_count`): the fused AdaGrad kernel does per-slot work for
+    that many slots only, and ``train_step`` reports it over the slots as
+    ``push_live_share``."""
+    from swiftsnails_tpu.ops.rowdma import ROW_LANES
 
     g = small_group(dim)
     stride = ROW_LANES // g
     n = rows.shape[0]
     t = state.table.shape[0]
-    fused_slots = state.table.shape[1] == 2 and not state.slots
 
     pad_w = stride - dim
     grads_s = jnp.pad(grads, ((0, 0), (0, pad_w))) if pad_w else grads
@@ -433,7 +444,30 @@ def push_packed_small(
     tiles = rows // g
     # lane groups are disjoint, so tile-level merge == per-row merge
     uniq, merged = merge_duplicate_rows(tiles, tile_grads, invalid_row=t)
-    merged3 = merged.reshape(n, 1, ROW_LANES)
+    live = live_count(uniq, t)
+    new = _apply_merged_small(
+        state, uniq, merged.reshape(n, 1, ROW_LANES), live, access, lr, block_rows)
+    return new, live
+
+
+def _apply_merged_small(
+    state: PackedTableState,
+    uniq: jax.Array,  # [N] merged tile ids: ``live`` distinct ones, then ``t``
+    merged3: jax.Array,  # [N, 1, 128]
+    live: jax.Array,
+    access: AccessMethod,
+    lr,
+    block_rows: int,
+) -> PackedTableState:
+    """The update half of :func:`push_packed_small`: one RMW kernel on the
+    chip, its XLA twin off it."""
+    from swiftsnails_tpu.ops import rowdma
+    from swiftsnails_tpu.ops.rowdma import (
+        ROW_LANES, scatter_adagrad_fused_rows, scatter_adagrad_rows)
+    from swiftsnails_tpu.parallel.access import AdaGradAccess, SgdAccess
+
+    t = state.table.shape[0]
+    fused_slots = state.table.shape[1] == 2 and not state.slots
 
     if fused_slots:
         if not _fuse_small_slots(access, state.table.dtype):
@@ -457,7 +491,7 @@ def push_packed_small(
                 [merged3, jnp.zeros((pad, 1, ROW_LANES), merged3.dtype)]
             )
         table = scatter_adagrad_fused_rows(
-            state.table, uniq, merged3, lr, eps=eps, block_rows=block_rows
+            state.table, uniq, merged3, lr, live, eps=eps, block_rows=block_rows
         )
         return PackedTableState(table=table, slots={})
 

@@ -362,7 +362,7 @@ def push_collective_packed_small(
         local_ids = jnp.where(owned, local_ids, per_rows)
         grads_all = jnp.where(owned[:, None], grads_all, 0)
         shard_state = PackedTableState(table=table_shard, slots=slot_shards)
-        new = push_packed_small(shard_state, local_ids, grads_all, access, lr, dim)
+        new, _ = push_packed_small(shard_state, local_ids, grads_all, access, lr, dim)
         return new.table, dict(new.slots)
 
     shard_spec = P(MODEL_AXIS, None, None)

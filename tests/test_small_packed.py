@@ -76,7 +76,7 @@ def test_push_sgd_matches_2d_plane(dim):
 
     rows = jnp.asarray(rng.integers(0, cap, 96).astype(np.int32))
     grads = jnp.asarray(rng.normal(size=(96, dim)).astype(np.float32))
-    new_small = push_packed_small(small, rows, grads, access, 0.1, dim)
+    new_small, _ = push_packed_small(small, rows, grads, access, 0.1, dim)
     new_ref = push(ref, rows, grads, access, 0.1)
     got = pull_packed_small(new_small, ids, dim)
     np.testing.assert_allclose(
@@ -96,9 +96,10 @@ def test_push_adagrad_merged_semantics():
 
     rows_np = np.array([3, 7, 3, 11, 7, 3], dtype=np.int32)
     grads_np = rng.normal(size=(6, dim)).astype(np.float32)
-    new_small = push_packed_small(
+    new_small, live = push_packed_small(
         small, jnp.asarray(rows_np), jnp.asarray(grads_np), access, 0.5, dim
     )
+    assert int(live) == 3  # rows 3, 7, 11 sit in tiles 0, 1, 2
     got = pull_packed_small(new_small, ids, dim)
 
     want = np.asarray(logical).copy()
@@ -153,35 +154,102 @@ def test_scatter_adagrad_kernel_interpret():
     np.testing.assert_allclose(np.asarray(got_t2), want_t, rtol=1e-5, atol=1e-6)
 
 
-def test_scatter_adagrad_fused_kernel_interpret():
-    """Slot-fused RMW kernel (param+accum in one tile) == the split-buffer
-    reference math, padding rows skipped."""
+def _fused_case(count, n=32, tiles=64, seed=5):
+    """A slot-fused table, ``count`` sorted distinct tile ids followed by
+    ``tail`` (default: the invalid row), and a non-zero gradient in EVERY
+    slot, as ``merge_duplicate_rows`` never leaves them."""
+    rng = np.random.default_rng(seed)
+    param = rng.normal(size=(tiles, 1, 128)).astype(np.float32)
+    accum = (rng.random((tiles, 1, 128)) * 0.1).astype(np.float32)
+    table = jnp.asarray(np.concatenate([param, accum], axis=1))  # [T, 2, 128]
+    ids = rng.permutation(tiles)[:n].astype(np.int32)
+    live, dead = np.sort(ids[:count]), ids[count:]
+    grads = jnp.asarray(rng.normal(size=(n, 1, 128)).astype(np.float32))
+    return table, live, dead, grads
+
+
+@jax.jit
+def _fused_twin(table, uniq, grads, lr, count):
+    """The XLA twin of the kernel inside ``push_packed_small``, jitted as
+    in every CPU run of the trainers (run op by op it rounds otherwise)."""
+    from swiftsnails_tpu.parallel.store import (
+        PackedTableState, _apply_merged_small)
+
+    state = PackedTableState(table=table, slots={})
+    return _apply_merged_small(
+        state, uniq, grads, count, AdaGradAccess(), lr, 8).table
+
+
+# (count, slots, block_rows): nothing live, one row, one short of a block, a
+# block, a block and one, a partial last block, every slot; then blocks wide
+# enough for the 64-row chunked waits and the unrolled starts with their
+# remainders
+@pytest.mark.parametrize("count,n,block", [
+    (0, 32, 8), (1, 32, 8), (7, 32, 8), (8, 32, 8), (9, 32, 8), (21, 32, 8),
+    (32, 32, 8), (203, 256, 128), (129, 256, 128), (256, 256, 128),
+])
+def test_scatter_adagrad_fused_kernel_interpret(count, n, block):
+    """Slot-fused RMW kernel (param+accum in one tile), told how many
+    leading rows are live: bit-equal to the XLA twin at every count."""
     from swiftsnails_tpu.ops.rowdma import scatter_adagrad_fused_rows
 
-    rng = np.random.default_rng(5)
-    C, L, N = 64, 128, 16
-    eps = 1e-8
-    param = rng.normal(size=(C, 1, L)).astype(np.float32)
-    accum = (rng.random((C, 1, L)) * 0.1).astype(np.float32)
-    table = np.concatenate([param, accum], axis=1)  # [C, 2, 128]
-    rows = np.concatenate([
-        rng.permutation(C)[: N - 4].astype(np.int32),
-        np.full(4, C, np.int32),
-    ])
-    grads = rng.normal(size=(N, 1, L)).astype(np.float32)
-
+    table, live, dead, grads = _fused_case(count, n=n, tiles=2 * n)
+    tiles = table.shape[0]
+    uniq = jnp.asarray(np.concatenate(
+        [live, np.full(dead.shape, tiles, np.int32)]))
+    want = _fused_twin(table, uniq, grads, 0.3, count)
     got = scatter_adagrad_fused_rows(
-        jnp.asarray(table), jnp.asarray(rows), jnp.asarray(grads), 0.3,
-        eps=eps, block_rows=8, interpret=True,
+        table + 0, uniq, grads, 0.3, count, eps=AdaGradAccess().eps,
+        block_rows=block, interpret=True,
     )
-    want = table.copy()
-    for j, r in enumerate(rows):
-        if r >= C:
-            continue
-        g = grads[j, 0]
-        want[r, 1] = want[r, 1] + g * g
-        want[r, 0] = want[r, 0] - 0.3 * g / np.sqrt(want[r, 1] + eps)
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    touched = np.any(np.asarray(got) != np.asarray(table), axis=(1, 2))
+    assert sorted(np.flatnonzero(touched)) == sorted(live)
+
+
+@pytest.mark.parametrize("count", [0, 5, 8, 19])
+def test_scatter_adagrad_fused_kernel_ignores_rows_past_count(count):
+    """The contract: slots from ``count`` on are not looked at, even where
+    they hold valid, distinct ids with non-zero gradients. (Were a dead
+    block to run, these rows would move.)"""
+    from swiftsnails_tpu.ops.rowdma import scatter_adagrad_fused_rows
+
+    table, live, dead, grads = _fused_case(count, seed=6)
+    tiles = table.shape[0]
+    got = scatter_adagrad_fused_rows(
+        table + 0, jnp.asarray(np.concatenate([live, dead])), grads, 0.3,
+        count, block_rows=8, interpret=True,
+    )
+    want = _fused_twin(
+        table,
+        jnp.asarray(np.concatenate([live, np.full(dead.shape, tiles, np.int32)])),
+        grads, 0.3, count)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(got)[dead], np.asarray(table)[dead])
+
+
+@pytest.mark.parametrize("rows,invalid,want_uniq", [
+    ([5, 2, 5, 9, 2, 5], 16, [2, 5, 9]),          # duplicates
+    ([7, 3, 11, 0], 16, [0, 3, 7, 11]),          # none
+    ([16, 4, 16, 1, 4, 40], 16, [1, 4]),         # ids >= invalid_row mixed in
+    ([16, 16, 99], 16, []),                      # nothing to update
+])
+def test_merge_duplicate_rows_live_count(rows, invalid, want_uniq):
+    """``live_count`` is the number of leading slots of ``uniq`` that hold a
+    row below ``invalid_row``: sorted, distinct, everything else after."""
+    from swiftsnails_tpu.parallel.store import live_count
+
+    rows = jnp.asarray(rows, jnp.int32)
+    grads = jnp.ones((rows.shape[0], 4), jnp.float32)
+    uniq, merged = jax.jit(
+        lambda r, g: merge_duplicate_rows(r, g, invalid_row=invalid))(rows, grads)
+    count = int(live_count(uniq, invalid))
+    assert count == len(want_uniq)
+    assert list(np.asarray(uniq[:count])) == want_uniq
+    assert np.all(np.asarray(uniq[count:]) >= invalid)
+    for j, r in enumerate(want_uniq):  # the merged gradients meet their rows
+        assert float(merged[j, 0]) == float(np.sum(np.asarray(rows) == r))
 
 
 def test_fused_slot_layout_selected_for_adagrad():
@@ -206,7 +274,7 @@ def test_non_multiple_capacity_rounds_up():
     rows = jnp.asarray([0, 999], jnp.int32)
     vals = pull_packed_small(state, rows, 1)
     assert vals.shape == (2, 1)
-    new = push_packed_small(
+    new, _ = push_packed_small(
         state, rows, jnp.ones((2, 1), jnp.float32), access, 0.5, 1)
     got = pull_packed_small(new, rows, 1)
     np.testing.assert_allclose(np.asarray(got), np.asarray(vals) - 0.5,
@@ -238,6 +306,39 @@ def test_ctr_trainer_packed_plane_end_to_end():
     assert np.mean(losses[-8:]) < np.mean(losses[:8])
     auc = tr.eval_auc(state)
     assert auc > 0.6, f"AUC {auc}"
+
+
+def test_train_step_reports_push_live_share():
+    """``train_step``'s metrics carry ``push_live_share`` = (distinct tiles
+    of the batch) / (slots pushed), the share of the fused scatter's slots
+    that get any per-slot work; a plane with no such count reports none."""
+    from swiftsnails_tpu.data.ctr import synth_ctr
+    from swiftsnails_tpu.models.registry import get_model
+    from swiftsnails_tpu.utils.config import Config
+
+    labels, feats, _ = synth_ctr(512, 4, 50, seed=1)
+    cfg = {
+        "num_fields": "4", "capacity": "1024", "batch_size": "256",
+        "learning_rate": "0.1", "num_iters": "1", "seed": "0",
+        "hidden_dims": "16,8", "embed_dim": "4", "optimizer": "adagrad",
+    }
+    tr = get_model("widedeep")(Config(dict(cfg)), mesh=None, data=(labels, feats))
+    batch = next(iter(tr.batches()))
+    batch["feats"] = batch["feats"].copy()
+    batch["feats"][64:] = batch["feats"][0]  # three quarters: one example
+    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+    tiles = np.asarray(tr._rows(batch["feats"])) // small_group(tr.table_dim)
+    want = np.unique(tiles).size / tiles.size
+    assert 0 < want < 0.3
+    _, m = jax.jit(tr.train_step)(tr.init_state(), batch, jax.random.PRNGKey(0))
+    assert set(m) == {"loss", "accuracy", "push_live_share"}
+    assert float(m["push_live_share"]) == pytest.approx(want, rel=1e-6)
+
+    dense = get_model("widedeep")(
+        Config({**cfg, "packed": "0"}), mesh=None, data=(labels, feats))
+    _, m = jax.jit(dense.train_step)(
+        dense.init_state(), batch, jax.random.PRNGKey(0))
+    assert set(m) == {"loss", "accuracy"}
 
 
 def test_ctr_trainer_packed_vs_dense_agree_sgd():

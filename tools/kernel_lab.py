@@ -329,7 +329,7 @@ def ctr_lab(argv=None):
 
     def small_step(state):
         vals = pull_packed_small(state, rows, dim)
-        state = push_packed_small(
+        state, _ = push_packed_small(
             state, rows, grads + vals * 1e-6, access, 0.01, dim)
         return state, state.table[0, 0, 0]
 
