@@ -4,6 +4,7 @@ from swiftsnails_tpu.models.fm import FMTrainer, FFMTrainer
 from swiftsnails_tpu.models.widedeep import WideDeepTrainer
 from swiftsnails_tpu.models.sparse_base import CTRState, SparseCTRTrainer
 from swiftsnails_tpu.models.seqlm import SeqLMTrainer
+from swiftsnails_tpu.models.moelm import MoELMTrainer
 
 __all__ = [
     "Word2VecTrainer",
@@ -16,4 +17,5 @@ __all__ = [
     "CTRState",
     "SparseCTRTrainer",
     "SeqLMTrainer",
+    "MoELMTrainer",
 ]

@@ -15,7 +15,15 @@ tables' job). bf16-friendly; losses/softmax statistics in f32.
 Config keys: ``seq_len``, ``n_layers``, ``n_heads``, ``d_model``,
 ``attention`` (``ring`` | ``ulysses`` | ``dense``), ``optimizer``
 (``sgd`` | ``momentum`` | ``adam`` | ``adamw``), plus the usual
-``learning_rate``, ``batch_size``, ``num_iters``, ``data``.
+``learning_rate``, ``batch_size``, ``num_iters``, ``data`` (a text corpus,
+or a ``.npy`` file of token ids with ``vocab_size`` stated).
+
+What every sequence trainer shares lives here once: the corpus and its
+windows (:meth:`SeqLMTrainer.batches`), the optimizer wiring, the one
+``train_step`` (gradient step, then :meth:`SeqLMTrainer.after_update` for
+what a step changes that is no gradient) and the loss
+(:func:`next_token_loss`). ``models/moelm.py`` puts another block stack
+under them.
 """
 
 from __future__ import annotations
@@ -37,12 +45,60 @@ from swiftsnails_tpu.parallel.sequence import (
     ulysses_attention,
 )
 from swiftsnails_tpu.utils.config import Config
+from swiftsnails_tpu.utils.profiling import phase_scope
 
 
 def _norm(x):
     x32 = x.astype(jnp.float32)
     scale = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + 1e-6)
     return (x32 * scale).astype(x.dtype)
+
+
+def next_token_loss(hidden, head, targets, chunks: int = 1, matmul=jnp.dot):
+    """Mean cross entropy of ``hidden [T, d] @ head [d, V]`` against
+    ``targets [T]``, float32. With ``chunks`` > 1 the tokens go by in that
+    many chunks, each chunk's logits recomputed in the backward pass, so that
+    only ``[T / chunks, V]`` logits (and as much gradient) exist at once."""
+    t = hidden.shape[0]
+    if t % chunks:
+        raise ValueError(f"{t} tokens do not split into {chunks} chunks")
+
+    def chunk_loss(h, y):
+        logits = matmul(h, head).astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        return jnp.sum(lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
+
+    if chunks == 1:
+        return chunk_loss(hidden, targets) / t
+    chunk_loss = jax.checkpoint(chunk_loss)
+
+    def body(total, xs):
+        return total + chunk_loss(*xs), ()
+
+    total, _ = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32),
+        (hidden.reshape(chunks, t // chunks, -1), targets.reshape(chunks, t // chunks)))
+    return total / t
+
+
+def make_optimizer(cfg: Config, lr: float):
+    """The sequence trainers' optimizer choice, same contract as the CTR
+    families ("sgd" default = bare SGD; the state carries the optax slots so
+    adam/momentum checkpoint-resume exactly). ``adam_b1``, ``adam_b2``,
+    ``adam_eps`` and ``weight_decay`` default to optax's own."""
+    adam = {"b1": cfg.get_float("adam_b1", 0.9), "b2": cfg.get_float("adam_b2", 0.999),
+            "eps": cfg.get_float("adam_eps", 1e-8)}
+    opts = {
+        "sgd": lambda: optax.sgd(lr),
+        "momentum": lambda: optax.sgd(lr, momentum=0.9),
+        "adam": lambda: optax.adam(lr, **adam),
+        "adamw": lambda: optax.adamw(
+            lr, weight_decay=cfg.get_float("weight_decay", 1e-4), **adam),
+    }
+    name = cfg.get_str("optimizer", "sgd")
+    if name not in opts:
+        raise ValueError(f"optimizer must be one of {sorted(opts)}, got {name}")
+    return opts[name]()
 
 
 @register_model("seqlm")
@@ -54,46 +110,52 @@ class SeqLMTrainer(Trainer):
         super().__init__(config, mesh, tracer)
         cfg = config
         self.seq_len = cfg.get_int("seq_len", 256)
-        self.n_layers = cfg.get_int("n_layers", 2)
-        self.n_heads = cfg.get_int("n_heads", 4)
-        self.d_model = cfg.get_int("d_model", 128)
         self.attention = cfg.get_str("attention", "ring" if self._has_seq_axis() else "dense")
         self.lr = cfg.get_float("learning_rate", 3e-3)
         self.batch_size = cfg.get_int("batch_size", 8)
         self.epochs = cfg.get_int("num_iters", 1)
         self.seed = cfg.get_int("seed", 0)
-        # optimizer choice, same contract as the CTR families ("sgd" default
-        # = the bare SGD this trainer always ran; state carries the optax
-        # slots so adam/momentum checkpoint-resume exactly)
-        opt_name = cfg.get_str("optimizer", "sgd")
-        opts = {
-            "sgd": lambda: optax.sgd(self.lr),
-            "momentum": lambda: optax.sgd(self.lr, momentum=0.9),
-            "adam": lambda: optax.adam(self.lr),
-            "adamw": lambda: optax.adamw(self.lr),
-        }
-        if opt_name not in opts:
-            raise ValueError(
-                f"optimizer must be one of {sorted(opts)}, got {opt_name}")
-        self.opt = opts[opt_name]()
+        self.opt = make_optimizer(cfg, self.lr)
         if corpus_ids is None:
+            with self.span("load-data"):
+                corpus_ids, vocab_size = self._load_corpus(cfg)
+        self.corpus_ids = np.asarray(corpus_ids, dtype=np.int32)
+        self.vocab_size = int(vocab_size)
+        self._read_shape(cfg)
+
+    def _read_shape(self, cfg: Config) -> None:
+        self.n_layers = cfg.get_int("n_layers", 2)
+        self.n_heads = cfg.get_int("n_heads", 4)
+        self.d_model = cfg.get_int("d_model", 128)
+        if self.d_model % self.n_heads:
+            raise ValueError("d_model must divide by n_heads")
+
+    @staticmethod
+    def _load_corpus(cfg: Config):
+        """(token ids, vocabulary size): a ``.npy`` file holds the ids of a
+        stated ``vocab_size``; anything else is text, whose words are the
+        vocabulary."""
+        path = cfg.get_str("data")
+        if path.endswith(".npy"):
+            ids = np.load(path)
+            vocab_size = cfg.get_int("vocab_size")
+            if ids.ndim != 1 or ids.min() < 0 or ids.max() >= vocab_size:
+                raise ValueError(f"{path}: not a row of token ids under {vocab_size}")
+        else:
             from swiftsnails_tpu.data.text import encode_corpus
 
-            corpus_ids, vocab = encode_corpus(
-                cfg.get_str("data"), min_count=cfg.get_int("min_count", 1),
+            ids, vocab = encode_corpus(
+                path, min_count=cfg.get_int("min_count", 1),
                 max_vocab=cfg.get_int("max_vocab", 0) or None,
             )
             vocab_size = len(vocab)
-            # multi-host contiguous corpus span (stdin-split parity); the
-            # global vocab keeps token ids consistent across hosts
-            if cfg.get_bool("shard_data", True):
-                from swiftsnails_tpu.parallel.cluster import shard_token_stream
+        # multi-host contiguous corpus span (stdin-split parity); the
+        # global vocab keeps token ids consistent across hosts
+        if cfg.get_bool("shard_data", True):
+            from swiftsnails_tpu.parallel.cluster import shard_token_stream
 
-                corpus_ids = shard_token_stream(corpus_ids)
-        self.corpus_ids = np.asarray(corpus_ids, dtype=np.int32)
-        self.vocab_size = int(vocab_size)
-        if self.d_model % self.n_heads:
-            raise ValueError("d_model must divide by n_heads")
+            ids = shard_token_stream(ids)
+        return ids, vocab_size
 
     def _has_seq_axis(self) -> bool:
         return self.mesh is not None and SEQ_AXIS in self.mesh.shape
@@ -139,6 +201,10 @@ class SeqLMTrainer(Trainer):
         return ring_attention(self.mesh, q, k, v, causal=True)
 
     def forward(self, params, tokens):
+        return self.hidden(params, tokens) @ params["embed"].T
+
+    def hidden(self, params, tokens):
+        """The stack's output after the last norm, [B, L, d]."""
         b, l = tokens.shape
         h = self.n_heads
         d = self.d_model
@@ -153,15 +219,20 @@ class SeqLMTrainer(Trainer):
             x = x + attn @ blk["wo"]
             y = _norm(x)
             x = x + jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
-        logits = _norm(x) @ params["embed"].T
-        return logits
+        return _norm(x)
 
-    def loss_fn(self, params, tokens):
-        logits = self.forward(params, tokens[:, :-1])
-        targets = tokens[:, 1:]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return -ll.mean()
+    def loss_fn(self, params, tokens, state):
+        """(loss, aux): ``aux`` is handed to :meth:`after_update`."""
+        del state
+        b, l = tokens.shape[0], tokens.shape[1] - 1
+        x = self.hidden(params, tokens[:, :-1]).reshape(b * l, -1)
+        return next_token_loss(x, params["embed"].T, tokens[:, 1:].reshape(-1)), {}
+
+    def after_update(self, state, aux):
+        """(state, metrics) once the gradient step is in ``state``: whatever a
+        step changes besides (a mixture's selection bias, its counters)."""
+        del aux
+        return state, {}
 
     # -- trainer contract --------------------------------------------------
 
@@ -180,11 +251,13 @@ class SeqLMTrainer(Trainer):
 
     def train_step(self, state, batch, rng):
         del rng
-        loss, grads = jax.value_and_grad(self.loss_fn)(
-            state["params"], batch["tokens"])
-        updates, opt = self.opt.update(grads, state["opt"], state["params"])
-        params = optax.apply_updates(state["params"], updates)
-        return {"params": params, "opt": opt}, {"loss": loss}
+        (loss, aux), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
+            state["params"], batch["tokens"], state)
+        with phase_scope("opt"):
+            updates, opt = self.opt.update(grads, state["opt"], state["params"])
+            params = optax.apply_updates(state["params"], updates)
+        state, metrics = self.after_update({**state, "params": params, "opt": opt}, aux)
+        return state, {"loss": loss, **metrics}
 
     def items_per_batch(self, batch) -> int:
         return int(batch["tokens"].shape[0] * (batch["tokens"].shape[1] - 1))

@@ -38,7 +38,7 @@ _NEG_INF = -1e30
 def reference_attention(q, k, v, causal: bool = False) -> jax.Array:
     """Dense softmax attention (the single-device ground truth).
 
-    Shapes: q [B, Lq, H, D], k/v [B, Lk, H, D] -> [B, Lq, H, D].
+    Shapes: q [B, Lq, H, Dk], k [B, Lk, H, Dk], v [B, Lk, H, Dv] -> [B, Lq, H, Dv].
     """
     scale = 1.0 / jnp.sqrt(q.shape[-1]).astype(q.dtype)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -53,7 +53,8 @@ def reference_attention(q, k, v, causal: bool = False) -> jax.Array:
 def _block_update(q, k, v, o, l, m, block_mask):
     """One online-softmax accumulation step (flash-attention recurrence).
 
-    q [B, Lq, H, D]; k/v [B, Lk, H, D]; o running output; l running
+    q [B, Lq, H, Dk]; k [B, Lk, H, Dk]; v [B, Lk, H, Dv]; o running output
+    [B, Lq, H, Dv]; l running
     denominator [B, H, Lq]; m running max [B, H, Lq]; block_mask [Lq, Lk]
     boolean or None.
     """
@@ -73,12 +74,14 @@ def _block_update(q, k, v, o, l, m, block_mask):
 
 
 def _ring_attention_local(q, k, v, axis_name: str, causal: bool):
-    """shard_map body: q/k/v are the local sequence shards [B, Lb, H, D]."""
+    """shard_map body: q/k are the local sequence shards [B, Lb, H, Dk], v
+    [B, Lb, H, Dv]; the running output takes the values' width (latent
+    attention has 192-wide keys and 128-wide values)."""
     axis_size = lax.psum(1, axis_name)
     my_idx = lax.axis_index(axis_name)
-    b, lb, h, d = q.shape
+    b, lb, h, _ = q.shape
 
-    o0 = jnp.zeros_like(q, dtype=jnp.float32)
+    o0 = jnp.zeros((b, lb, h, v.shape[-1]), jnp.float32)
     l0 = jnp.zeros((b, h, lb), dtype=jnp.float32)
     m0 = jnp.full((b, h, lb), _NEG_INF, dtype=jnp.float32)
     perm = [(j, (j + 1) % axis_size) for j in range(axis_size)]

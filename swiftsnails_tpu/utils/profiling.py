@@ -72,12 +72,19 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # in every model: ``prep`` turns ids into rows, negatives, copy lists and
 # merge plans; ``fused`` is Word2Vec's grouped SGNS kernel; ``pull`` and
 # ``push`` read and update table rows; ``dense`` is a CTR model's forward,
-# backward and dense update. Scopes are metadata on the operations (no
+# backward and dense update. A sequence model's step (``models/seqlm.py``,
+# ``models/moelm.py``): ``attn`` the attention block with its projections,
+# ``mlp`` a dense feed-forward (a mixture layer's shared experts too),
+# ``route`` the router, its top-k, the sort by expert and the counts,
+# ``experts`` the grouped products over the experts held and the combine,
+# ``head`` embedding, final norm, output head and loss, ``opt`` the optimizer
+# and whatever else a step changes that is no gradient. Scopes are metadata on the operations (no
 # operation, no flag): a ``profile_dir`` capture shows them as the name
 # scope of each device operation, and ``benchmark/lib/scopes.py`` sums
 # device time by the innermost one. The prefix stays clear of the ``ssn_*``
 # labels that ``telemetry/audit.py`` groups collective bytes by.
-PHASES = ("prep", "fused", "pull", "push", "dense")
+PHASES = ("prep", "fused", "pull", "push", "dense",
+          "attn", "mlp", "route", "experts", "head", "opt")
 
 
 def phase_scope(phase: str):

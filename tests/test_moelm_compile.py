@@ -1,0 +1,86 @@
+"""The kernels of the mixture-of-experts step compile for a TPU v5e that is
+described and not attached, at Moonlight-16B-A3B's published widths: what
+interpret mode cannot show (tile alignment, VMEM, transposed products in
+Mosaic). Compile-only: nothing runs, and no time or result comes of it. The
+topology is described inside a fixture, in this file alone (one process may
+load the TPU's library)."""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from swiftsnails_tpu.ops import flash_attention as fa
+from swiftsnails_tpu.ops import grouped_matmul as gm
+
+SEQ, HEADS, DK, DV = 8192, 16, 192, 128  # qk_nope 128 + qk_rope 64; v_head_dim 128
+HIDDEN, EXPERT_WIDTH, HELD, TOP_K = 2048, 1408, 8, 6
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_cache():
+    """A described-chip compile is written to the persistent cache and
+    cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compiled(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+def test_attention_kernels_compile_at_published_widths(one_chip, no_cache):
+    def loss(q, k, v, w):
+        return jnp.sum(fa.flash_attention(q, k, v, block=512, interpret=False) * w)
+
+    f32 = jnp.float32
+    compiled = _compiled(jax.grad(loss, (0, 1, 2)), one_chip,
+                         ((HEADS, SEQ, DK), f32), ((HEADS, SEQ, DK), f32),
+                         ((HEADS, SEQ, DV), f32), ((HEADS, SEQ, DV), f32))
+    text = compiled.as_text()
+    for name in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
+        assert name in text and "tpu_custom_call" in text, name
+    # scores never leave VMEM: nothing the size of [heads, L, L] is allocated
+    assert compiled.memory_analysis().temp_size_in_bytes < HEADS * SEQ * SEQ * 4 / 4
+
+
+def test_grouped_products_compile_at_published_widths(one_chip, no_cache):
+    assignments = SEQ * TOP_K  # every token could choose six of the eight held
+    rows = gm.rows_for(assignments, HELD)
+    assert rows == (assignments // gm.TILE + HELD) * gm.TILE
+
+    def loss(y, w_in, w_out, gates, owner):  # the moves between tokens and rows ride along
+        plan = gm.plan_rows(owner, HELD)
+        mm = functools.partial(gm.grouped_matmul, plan=plan, interpret=False)
+        out = mm(jax.nn.silu(mm(gm.rows_of_tokens(y, plan), w_in)), w_out)
+        return jnp.sum(gm.tokens_of_rows(out, gates, plan))
+
+    f32 = jnp.float32
+    compiled = _compiled(jax.grad(loss, (0, 1, 2, 3)), one_chip,
+                         ((SEQ, HIDDEN), f32), ((HELD, HIDDEN, EXPERT_WIDTH), f32),
+                         ((HELD, EXPERT_WIDTH, HIDDEN), f32), ((SEQ, TOP_K), f32),
+                         ((SEQ, TOP_K), jnp.int32))
+    text = compiled.as_text()
+    for name in ("grouped_matmul_", "grouped_matmul_dx", "grouped_matmul_dw"):
+        assert name in text, name
