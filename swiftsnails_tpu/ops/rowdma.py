@@ -23,6 +23,8 @@ an option; equal-sized copies make shared byte-accounting exact):
 * :func:`gather_rows` — pull: for each of N row ids, DMA ``table[r]`` HBM ->
   VMEM, emitting ``[N, S, 128]``. Block ``i+1``'s row DMAs are issued before
   block ``i`` is consumed, so issue latency overlaps the output pipeline.
+  Every slot is live, so a block's copies are started ``_START_UNROLL`` to a
+  loop iteration and retired ``_WAIT_CHUNK`` rows to a wait (PR 29).
 * :func:`scatter_add_rows` — push: read-modify-write ``table[r] += delta``
   per row, pipelined two blocks deep (reads of block ``i+1`` overlap writes
   of block ``i``). Rows MUST be unique (or >= capacity for padding slots,
@@ -82,6 +84,29 @@ def unpack_rows(rows3d: jax.Array, dim: int) -> jax.Array:
     return rows3d.reshape(n, -1)[:, :dim]
 
 
+# DMA starts per iteration of the issue loops, on a v5e at the Wide&Deep
+# cell's shapes. The fused scatter's 55,893 live rows: 2.99 -> 2.25 ms (16:
+# 2.17; PERF.md, PR 27). The gather's 212,992 slots, waits chunked: 5.20 ->
+# 3.37 ms (16: 3.22, 32: 3.15, 64: 3.11; PERF.md, PR 29): one constant for both
+_START_UNROLL = 8
+
+
+def _start_rows(n, start_one):
+    """``start_one(j)`` for ``j`` in ``[0, n)``, ``_START_UNROLL`` to a loop
+    iteration and the remainder one by one: the scalar core pays per
+    iteration, not per DMA. ``n`` is a Python int or a traced scalar."""
+
+    def group(k, _):
+        for u in range(_START_UNROLL):
+            start_one(k * _START_UNROLL + u)
+        return 0
+
+    whole = n // _START_UNROLL
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(
+        whole * _START_UNROLL, n, lambda j, _: (start_one(j), 0)[1], 0)
+
+
 # --------------------------------------------------------------- gather ---
 
 
@@ -90,13 +115,11 @@ def _gather_kernel(rows_ref, table_ref, out_ref, scratch, sems):
     i = pl.program_id(0)
     nblocks = pl.num_programs(0)
 
-    def row_dma(b, slot, j):
-        return pltpu.make_async_copy(
-            table_ref.at[rows_ref[b * R + j]], scratch.at[slot, j], sems.at[slot]
-        )
-
     def start_block(b, slot):
-        jax.lax.fori_loop(0, R, lambda j, _: (row_dma(b, slot, j).start(), 0)[1], 0)
+        # every slot of a gather is live: no count, no per-slot test
+        _start_rows(R, lambda j: pltpu.make_async_copy(
+            table_ref.at[rows_ref[b * R + j]], scratch.at[slot, j], sems.at[slot]
+        ).start())
 
     @pl.when(i == 0)
     def _():
@@ -107,7 +130,11 @@ def _gather_kernel(rows_ref, table_ref, out_ref, scratch, sems):
         start_block(i + 1, (i + 1) % 2)
 
     slot = i % 2
-    jax.lax.fori_loop(0, R, lambda j, _: (row_dma(i, slot, j).wait(), 0)[1], 0)
+    # equal-sized copies on the slot's semaphore: retired by descriptor
+    # size, a chunk of rows at a time (4.91 -> 3.37 ms at the cell's shapes;
+    # chunks of 128 or one wait on the whole block: 3.36, 3.35; PR 29)
+    _wait_rows(scratch.at[slot, 0], scratch.at[slot, :min(_WAIT_CHUNK, R)],
+               sems.at[slot], R)
     out_ref[...] = scratch[slot]
 
 
@@ -484,11 +511,6 @@ def scatter_adagrad_rows(
 
 # -------------------------------------------- slot-fused AdaGrad (1 tile) ---
 
-# DMA starts per iteration of the issue loops: 2.99 -> 2.25 ms for the
-# Wide&Deep cell's 55,893 live rows on a v5e (16: 2.17; PERF.md, PR 27)
-_START_UNROLL = 8
-
-
 def _adagrad_fused_kernel(rows_ref, lr_ref, count_ref, table_in, deltas_ref,
                           table_ref, scratch, read_sems, write_sems,
                           *, eps):
@@ -518,16 +540,7 @@ def _adagrad_fused_kernel(rows_ref, lr_ref, count_ref, table_in, deltas_ref,
             sems = read_sems if read else write_sems
             pltpu.make_async_copy(src, dst, sems.at[slot]).start()
 
-        def group(k, _):
-            for u in range(_START_UNROLL):
-                one(k * _START_UNROLL + u)
-            return 0
-
-        n = live_rows(b)
-        whole = n // _START_UNROLL
-        jax.lax.fori_loop(0, whole, group, 0)
-        jax.lax.fori_loop(
-            whole * _START_UNROLL, n, lambda j, _: (one(j), 0)[1], 0)
+        _start_rows(live_rows(b), one)
 
     def wait(b, slot, read):
         # equal-sized copies on a shared semaphore: retired by descriptor

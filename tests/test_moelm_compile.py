@@ -1,5 +1,6 @@
-"""The kernels of the mixture-of-experts step compile for a TPU v5e that is
-described and not attached, at Moonlight-16B-A3B's published widths: what
+"""The kernels of the mixture-of-experts step (at Moonlight-16B-A3B's
+published widths) and the table plane's gather (at the Wide&Deep cell's
+shapes) compile for a TPU v5e that is described and not attached: what
 interpret mode cannot show (tile alignment, VMEM, transposed products in
 Mosaic). Compile-only: nothing runs, and no time or result comes of it. The
 topology is described inside a fixture, in this file alone (one process may
@@ -84,3 +85,18 @@ def test_grouped_products_compile_at_published_widths(one_chip, no_cache):
     text = compiled.as_text()
     for name in ("grouped_matmul_", "grouped_matmul_dx", "grouped_matmul_dw"):
         assert name in text, name
+
+
+# the Wide&Deep cell's pull (212,992 ids into a 4 GiB table of [2, 128]
+# tiles, block 512), a block that is no multiple of the start unroll, and one
+# whose waits end in a chunk remainder
+@pytest.mark.parametrize("capacity,n,block_rows", [
+    (1 << 22, 212_992, 512), (4096, 36, 12), (4096, 384, 192)])
+def test_gather_rows_compiles_for_the_chip(one_chip, no_cache, capacity, n, block_rows):
+    from swiftsnails_tpu.ops import rowdma
+
+    fn = functools.partial(rowdma.gather_rows, block_rows=block_rows)
+    compiled = _compiled(fn, one_chip,
+                         ((capacity, 2, 128), jnp.float32), ((n,), jnp.int32))
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (n, 2, 128)

@@ -27,12 +27,48 @@ def _mk_table(c=64, s=2, seed=0):
     return jnp.asarray(rng.random((c, s, 128), dtype=np.float32))
 
 
-def test_gather_rows_interpret():
-    table = _mk_table()
-    rng = np.random.default_rng(1)
-    rows = rng.integers(0, 64, 32).astype(np.int32)
-    got = rowdma.gather_rows(table, jnp.asarray(rows), block_rows=8, interpret=True)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("s", [1, 2])
+@pytest.mark.parametrize("nblocks", [1, 2, 3])
+# 8: one unrolled group; 12: no multiple of the unroll; 64: one chunked wait;
+# 128: two; 192: three, and no multiple of 128
+@pytest.mark.parametrize("block_rows", [8, 12, 64, 128, 192])
+def test_gather_rows_interpret(block_rows, nblocks, s, dtype):
+    table = _mk_table(c=48, s=s, seed=block_rows + nblocks).astype(dtype)
+    n = block_rows * nblocks
+    rows = np.random.default_rng(n + s).integers(0, 48, n).astype(np.int32)
+    rows[1] = rows[0]  # an id twice in one block ...
+    rows[-1] = rows[0]  # ... and, with more than one block, across blocks
+    got = rowdma.gather_rows(
+        table, jnp.asarray(rows), block_rows=block_rows, interpret=True)
+    assert got.dtype == table.dtype and got.shape == (n, s, 128)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(table)[rows])
+
+
+@pytest.mark.parametrize("n_ids", [24, 40])  # under one block, and padded to two
+def test_pull_packed_small_through_the_gather_kernel(monkeypatch, n_ids):
+    """The small-row plane's pull with the kernel in the XLA gather's place
+    (interpret mode, as on the chip otherwise): same values, bit for bit."""
+    from swiftsnails_tpu.parallel.store import (
+        create_packed_small_table,
+        pull_packed_small,
+    )
+
+    state = create_packed_small_table(100, 16, AdaGradAccess(), seed=3)
+    assert state.table.shape[1:] == (2, 128)  # accumulator fused in
+    ids = jnp.asarray(np.random.default_rng(4).integers(0, 100, n_ids).astype(np.int32))
+    want = pull_packed_small(state, ids, 16, block_rows=32)  # the XLA twin
+    calls, gather = [], rowdma.gather_rows
+
+    def kernel(table, rows, block_rows):
+        calls.append(rows.shape[0])
+        return gather(table, rows, block_rows=block_rows, interpret=True)
+
+    monkeypatch.setattr(rowdma, "on_tpu", lambda: True)
+    monkeypatch.setattr(rowdma, "gather_rows", kernel)
+    got = pull_packed_small(state, ids, 16, block_rows=32)
+    assert calls == [-(-n_ids // 32) * 32]
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 def test_scatter_add_rows_interpret_unique_and_padding():

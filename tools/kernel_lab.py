@@ -71,18 +71,23 @@ def main(argv=None):
     # --- throughput sweep -------------------------------------------------
     probe = jnp.zeros((8, 128), jnp.float32)
 
-    def bench(name, fn, n=20):
+    def bench(name, fn, *operands, n=20):
+        # tables ride as operands: a closed-over 4 GiB table would be baked
+        # into the program as a constant, through the host's memory
         f = jax.jit(fn)
-        o = f(probe)
-        _ = float(o[0, 0])
-        t0 = time.perf_counter(); _ = float(o[0, 0])
-        fetch = time.perf_counter() - t0
-        o = probe
-        t0 = time.perf_counter()
-        for _ in range(n):
-            o = f(o)
-        _ = float(o[0, 0])
-        dt = (time.perf_counter() - t0 - fetch) / n * 1e3
+        _ = float(f(f(probe, *operands), *operands)[0, 0])  # compile, warm
+
+        def timed():
+            o = probe
+            t0 = time.perf_counter()
+            for _ in range(n):
+                o = f(o, *operands)
+            _ = float(o[0, 0])
+            return (time.perf_counter() - t0) / n * 1e3
+
+        # the faster of two passes: a process's first timed loop stalled on
+        # the host for 0.4 s, once (first dispatch, 4 GiB table, on a v5e)
+        dt = min(timed(), timed())
         print(f"{name}: {dt:.3f} ms  ({dt * 1e6 / args.rows:.1f} ns/row)")
         return dt
 
@@ -93,34 +98,39 @@ def main(argv=None):
         for br in blocks:
             bench(
                 f"gather {args.rows} rows dtype={dtype.__name__} R={br}",
-                lambda p, br=br, table=table: p
+                lambda p, table, br=br: p
                 + rowdma.gather_rows(
                     table, (rows + p[0, 0].astype(jnp.int32)) % args.vocab,
                     block_rows=br,
                 )[:8, 0, :].astype(jnp.float32),
+                table,
             )
-        # XLA reference
+        # XLA reference (all rows summed: a slice of the result would let XLA
+        # gather the eight rows it keeps and no others)
         bench(
             f"gather {args.rows} XLA dtype={dtype.__name__}",
-            lambda p, table=table: p
+            lambda p, table: p
             + table.at[(rows + p[0, 0].astype(jnp.int32)) % args.vocab]
-            .get(mode="promise_in_bounds")[:8, 0, :]
-            .astype(jnp.float32),
+            .get(mode="promise_in_bounds")
+            .astype(jnp.float32).sum(axis=(0, 1))[None, :] * 1e-9,
+            table,
         )
 
         deltas_big = jnp.asarray(
             rng.random((args.rows, S, 128), dtype=np.float32) * 1e-9, dtype=dtype
         )
         for br in blocks:
-            def scat(p, br=br, table=table):
+            def scat(p, table, deltas_big, br=br):
                 t = rowdma.scatter_add_rows(table + p[0, 0] * 0, uniq, deltas_big, block_rows=br)
                 return p + t[0, 0, :].astype(jnp.float32)[None, :]
-            bench(f"scatter {args.rows} unique dtype={dtype.__name__} R={br}", scat)
+            bench(f"scatter {args.rows} unique dtype={dtype.__name__} R={br}",
+                  scat, table, deltas_big)
 
-        def scat_xla(p, table=table):
+        def scat_xla(p, table, deltas_big):
             t = (table + p[0, 0] * 0).at[uniq].add(deltas_big, mode="drop")
             return p + t[0, 0, :].astype(jnp.float32)[None, :]
-        bench(f"scatter {args.rows} XLA dtype={dtype.__name__}", scat_xla)
+        bench(f"scatter {args.rows} XLA dtype={dtype.__name__}", scat_xla,
+              table, deltas_big)
 
 
 def resident_lab(argv=None):
