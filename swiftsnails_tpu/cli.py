@@ -23,7 +23,6 @@ Usage::
     python -m swiftsnails_tpu models
     python -m swiftsnails_tpu trace-summary TRACE_OR_JSONL   # telemetry breakdown
     python -m swiftsnails_tpu ledger-report [LEDGER.jsonl]   # run-ledger history
-    python -m swiftsnails_tpu ledger-report --check-regression 10   # bench gate
     python -m swiftsnails_tpu ledger-report --failures   # outage/chaos timeline
     python -m swiftsnails_tpu ledger-report --diff A B   # attribute a words/sec delta
     python -m swiftsnails_tpu supervisor-status [LEDGER.jsonl]   # membership view
@@ -34,8 +33,7 @@ Resilience (docs/RESILIENCE.md): ``resume: auto`` continues an interrupted
 run from the newest verified checkpoint (tables + data cursor); a real
 SIGTERM drains with a final save and a ledger ``outage`` record instead of
 dying mid-step; ``guardrail: 1`` arms the NaN/rollback step guardrail; the
-fault-injection drills live in ``tools/chaos_drill.py`` and
-``bench.py --lane chaos``.
+fault-injection drills live in ``tools/chaos_drill.py``.
 
 ``master`` / ``server`` are accepted for parity and explain the collapse.
 """

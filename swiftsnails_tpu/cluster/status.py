@@ -4,9 +4,7 @@ A live in-process :class:`Supervisor` answers :meth:`status` directly; a
 finished (or remote) run leaves its whole membership lifecycle in the run
 ledger as ``membership`` events. This module replays those events into the
 supervisor's-eye view — who joined, who was lost and why, where every
-reassigned range went, which workers were flagged stragglers — plus the
-newest ``chaos_cluster`` bench block's exactly-once verdict when one is
-present.
+reassigned range went, which workers were flagged stragglers.
 """
 
 from __future__ import annotations
@@ -49,14 +47,8 @@ def supervisor_status_view(ledger) -> Dict:
             _w(w)["straggler"] = False
         elif action in counts:
             counts[action] += 1
-    view = {"workers": workers, "counts": counts, "events": sum(
+    return {"workers": workers, "counts": counts, "events": sum(
         1 for _ in ledger.records("membership"))}
-    for r in ledger.records("bench"):
-        payload = r.get("payload")
-        if isinstance(payload, dict) and \
-                isinstance(payload.get("chaos_cluster"), dict):
-            view["chaos_cluster"] = payload["chaos_cluster"]
-    return view
 
 
 def render_supervisor_status(ledger) -> str:
@@ -83,13 +75,4 @@ def render_supervisor_status(ledger) -> str:
         f"reassigned, {c['straggler']} straggler flags, "
         f"{c['backup']} backup grants, {c['restore']} restores"
     )
-    cc = view.get("chaos_cluster")
-    if cc:
-        lines.append(
-            f"  accounting: {cc.get('committed')}/{cc.get('total_batches')} "
-            f"committed, lost={cc.get('lost_count')} "
-            f"dup={cc.get('duplicated_count')} "
-            f"dup_discarded={cc.get('dup_discarded')} "
-            f"exact={cc.get('accounting_exact')}"
-        )
     return "\n".join(lines)

@@ -1,7 +1,7 @@
 """Shared quality probe: does a trained word2vec state know its corpus?
 
 One implementation used by BOTH the CI gate (tests/test_path_quality.py) and
-the on-hardware bench gate (bench.py), so the bar and the corpus cannot
+the on-chip smoke (chip_smoke.py), so the bar and the corpus cannot
 drift apart. The probe corpus pairs word ``2i`` with ``2i+1`` exclusively;
 a trained state should rank the partner top-1 by in-out logit
 (``v_in[2i] . u_out[j]`` argmax over j). Catastrophic-regression detector:

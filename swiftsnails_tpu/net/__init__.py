@@ -26,9 +26,9 @@ puts that wire back under the roles we rebuilt in-process (docs/NETWORK.md):
   over TCP: a stream source replaces the file poll in front of
   ``DeltaSubscriber.apply_batch`` with the same seq/gap/fallback semantics.
 
-Drilled by ``bench.py --lane net`` and ``tools/chaos_drill.py --net`` with
-the process-level chaos kinds ``proc_kill`` / ``net_partition`` /
-``net_slow``; gated in ``ledger-report --check-regression``.
+Drilled by ``tools/chaos_drill.py --net`` (:mod:`~swiftsnails_tpu.net.drill`)
+with the process-level chaos kinds ``proc_kill`` / ``net_partition`` /
+``net_slow``.
 """
 
 from swiftsnails_tpu.net.wire import (  # noqa: F401

@@ -16,8 +16,7 @@ The observability stack (PRs 1-2: tracer, ledger, black box, goodput) is the
   the data-stream cursor so resumed loss curves continue instead of restart
   (``resume: auto``, with ``framework/checkpoint.py``);
 * :mod:`~swiftsnails_tpu.resilience.drill` — the canned chaos drill matrix
-  and the bench ``chaos`` lane's recovery-goodput measurement
-  (``bench.py --lane chaos``, ``tools/chaos_drill.py``);
+  (``tools/chaos_drill.py``);
 * :mod:`~swiftsnails_tpu.resilience.retry` — the unified deadline + retry
   policy (exponential backoff, decorrelated jitter, injectable clock) that
   every fallible host I/O path shares: the data stream, checkpoint

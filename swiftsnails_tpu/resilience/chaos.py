@@ -80,7 +80,7 @@ FAULT_KINDS = (
     # process-level transport kinds (PR 19): consulted by the net drills,
     # scheduled by storm tick. proc_kill SIGKILLs a replica process
     # mid-load; net_partition black-holes its socket for a window;
-    # net_slow injects RTT into every reply (see net/bench_lane.py)
+    # net_slow injects RTT into every reply (see net/drill.py)
     "proc_kill", "net_partition", "net_slow",
 )
 
@@ -371,7 +371,7 @@ class ChaosPlan:
         return name
 
     # -- serving-surface faults (consulted by the Servant's fault hook / the
-    # chaos-serve lane; "step" is the request index) -------------------------
+    # serve drill; "step" is the request index) -------------------------
 
     def serve_fault(self, index: int) -> Optional[str]:
         """The scheduled serving fault for request ``index`` (at most one:

@@ -1,9 +1,7 @@
-"""Canned chaos drills + the bench ``chaos`` lane's recovery measurement.
+"""Canned chaos drills: every fault the resilience stack claims to survive.
 
-One implementation used by ``tools/chaos_drill.py`` (the CI drill runner),
-``tests/test_chaos_drill.py`` (the tier-1 fast subset), and
-``bench.py --lane chaos`` (recovery-goodput numbers in the bench JSON line),
-so the drill matrix and the bench cannot drift apart.
+One implementation used by ``tools/chaos_drill.py`` (the drill runner) and
+``tests/test_chaos_drill.py`` (the tier-1 fast subset).
 
 Every drill is deterministic: fixed ``chaos_seed``, fixed data seed, fixed
 fault schedule — a failure reproduces bit-identically. A drill *passes* when
@@ -17,7 +15,6 @@ from __future__ import annotations
 
 import os
 import tempfile
-import time
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
@@ -228,12 +225,10 @@ def drill_preempt_resume(workdir: Optional[str] = None, steps: int = 24,
     # the final save rots on disk before the restart
     corrupt_checkpoint_dir(root, rng=np.random.default_rng(11), ledger=ledger)
 
-    # measure the restore (walk-back) cost on a throwaway template, then
-    # resume for real through the TrainLoop
-    t0 = time.monotonic()
+    # the restore (walk-back) on a throwaway template first, then the
+    # real resume through the TrainLoop
     probe = resume_state(root, make_trainer(workdir).init_state(),
                          mode="auto", ledger=ledger)
-    restore_s = time.monotonic() - t0
     tr2 = make_trainer(workdir, param_backup_period=period,
                        param_backup_root=root, resume="auto")
     loop2, resumed_state, _ = run_loop(tr2, max_steps=steps)
@@ -254,7 +249,6 @@ def drill_preempt_resume(workdir: Optional[str] = None, steps: int = 24,
         "restored_step": restored_step,
         "steps_lost": (final_step - restored_step)
         if restored_step is not None else None,
-        "time_to_recover_s": round(restore_s, 4),
         "loss_control": round(loss_control, 6),
         "loss_resumed": round(loss_resumed, 6),
         "loss_parity": round(parity, 6),
@@ -394,93 +388,9 @@ def run_drill_matrix(fast: bool = False, workdir: Optional[str] = None) -> Dict[
     for name in names:
         d = os.path.join(base, name)
         os.makedirs(d, exist_ok=True)
-        t0 = time.monotonic()
         try:
-            res = _DRILL_FNS[name](d)
+            results[name] = _DRILL_FNS[name](d)
         except Exception as e:
-            res = {"recovered": False,
-                   "error": f"{type(e).__name__}: {e}"}
-        res["elapsed_s"] = round(time.monotonic() - t0, 2)
-        results[name] = res
+            results[name] = {"recovered": False,
+                             "error": f"{type(e).__name__}: {e}"}
     return results
-
-
-# ------------------------------------------------- bench `chaos` lane -------
-
-
-def _bench_corpus(small: bool):
-    """Zipf corpus big enough that the guardrail's per-step cost is measured
-    against real step work (the paired probe corpus is too small for an
-    honest overhead number)."""
-    from swiftsnails_tpu.data.vocab import Vocab
-
-    vocab_n = 512 if small else 4096
-    n_tokens = 20_000 if small else 120_000
-    rng = np.random.default_rng(5)
-    ranks = np.arange(1, vocab_n + 1, dtype=np.float64)
-    w = 1.0 / ranks ** 1.05
-    cdf = np.cumsum(w) / w.sum()
-    ids = np.searchsorted(cdf, rng.random(n_tokens)).astype(np.int32)
-    counts = np.maximum(np.bincount(ids, minlength=vocab_n), 1).astype(np.int64)
-    return ids, Vocab([f"w{i}" for i in range(vocab_n)], counts)
-
-
-def chaos_bench(workdir: Optional[str] = None, small: bool = False) -> Dict:
-    """The bench ``chaos`` lane: guardrail overhead on the no-fault control
-    leg + the scripted fault drills' recovery numbers, as one JSON-ready
-    block (lands in the bench line, the run ledger, and the
-    ``ledger-report --check-regression`` gate)."""
-    t_lane0 = time.monotonic()
-    base = _workdir(workdir)
-    corpus = _bench_corpus(small)
-    over = {
-        "dim": 16 if small else 64,
-        "batch_size": 512 if small else 2048,
-        "window": 2,
-        "num_iters": 8,
-    }
-    warm, steps = (2, 12) if small else (3, 32)
-
-    def wps(extra: Dict) -> float:
-        """Steady-state pair rate of the control leg: one TrainLoop, a warm
-        run that pays the jit compile, then best-of-3 timed runs on the
-        already-compiled step fn (machine-load noise only ever slows a run,
-        so max is the robust estimator — the headline bench's lesson). A
-        rate for the overhead ratio, NOT comparable to words/sec/chip."""
-        from swiftsnails_tpu.framework.trainer import TrainLoop
-
-        d = tempfile.mkdtemp(dir=base)
-        tr = make_trainer(d, corpus=corpus, **{**over, **extra})
-        loop = TrainLoop(tr, log_every=0)
-        loop.run(max_steps=warm)
-        best = 0.0
-        for _ in range(3):
-            t0 = time.monotonic()
-            loop.run(max_steps=steps)
-            dt = max(time.monotonic() - t0, 1e-9)
-            best = max(best, steps * over["batch_size"] / dt)
-        return best
-
-    control = wps({})
-    guarded = wps({"guardrail": 1})
-    overhead_pct = (control - guarded) / control * 100.0 if control else None
-
-    drills = run_drill_matrix(fast=small, workdir=os.path.join(base, "drills"))
-    resume_drill = drills.get("preempt_resume") or drills.get("ckpt_walkback")
-    block = {
-        "control_words_per_sec": round(control, 1),
-        "guard_words_per_sec": round(guarded, 1),
-        "guard_overhead_pct": (
-            round(overhead_pct, 2) if overhead_pct is not None else None
-        ),
-        "nan_drill": drills.get("nan_burst"),
-        "resume_drill": resume_drill,
-        "drills": {k: {"recovered": v.get("recovered"),
-                       "elapsed_s": v.get("elapsed_s")}
-                   for k, v in drills.items()},
-        "recovered_all": all(v.get("recovered") for v in drills.values()),
-        "loss_parity": (resume_drill or {}).get("loss_parity"),
-        "small": small,
-        "elapsed_s": round(time.monotonic() - t_lane0, 1),
-    }
-    return block

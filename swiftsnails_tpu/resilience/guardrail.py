@@ -29,9 +29,8 @@ one flag check per step and this module is never imported. On-path the
 TrainLoop runs a NON-donating compile of the step (the input buffers are the
 rollback snapshot — 2x table memory, no copy), plus one fused reduction over
 the state and one host sync of its scalar result per step (the sync is what
-makes "roll back before the next step" possible at all). Measured in the
-bench ``chaos`` lane as ``guard_overhead_pct`` (~2-3% on the CPU control
-leg).
+makes "roll back before the next step" possible at all). Its cost on the
+chip is not measured.
 """
 
 from __future__ import annotations

@@ -396,7 +396,7 @@ class Servant:
         # availability layer: per-kernel breakers (threshold 0 disables) +
         # degraded-mode stale reads. `fault_hook` is the seeded chaos
         # injection point — fn(kernel, dispatch_index) may raise or stall,
-        # exactly as a sick device/storage read would (chaos-serve lane).
+        # exactly as a sick device/storage read would (the serve drill).
         self.degraded_enabled = bool(degraded)
         self.fault_hook = None
         # freshness: an attached DeltaSubscriber surfaces its watermark/lag

@@ -38,9 +38,8 @@ finish before closing it: connection draining, no mid-request kills. Both
 edges land in the run ledger as ``drain`` events.
 
 Per-replica injectable hooks (``Replica.request_hook`` at admission, the
-engine's ``Servant.fault_hook`` at dispatch) are the chaos/bench seam: the
-fleet lane models device service time with them, the chaos drill slows or
-kills exactly one replica through them.
+engine's ``Servant.fault_hook`` at dispatch) are the chaos seam: the fleet
+drill slows or kills exactly one replica through them.
 """
 
 from __future__ import annotations

@@ -80,7 +80,6 @@ from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
     config_hash,
     env_fingerprint,
-    validate_bench_payload,
 )
 from swiftsnails_tpu.telemetry.ops import render_ops, render_ops_from_ledger
 from swiftsnails_tpu.telemetry.request_trace import (
@@ -137,5 +136,4 @@ __all__ = [
     "peaks_for",
     "step_time_decomposition",
     "summarize_file",
-    "validate_bench_payload",
 ]

@@ -1,7 +1,7 @@
 """Online drift sentinel: EWMA/CUSUM detectors + atomic incident bundles.
 
-``ledger-report --check-regression`` catches a regression *between* bench
-snapshots; nothing watches a live run for the slow-burn kind — step time
+The benchmark catches a regression *between* commits; nothing else
+watches a live run for the slow-burn kind — step time
 creeping 10% over an hour, tier hit rate sagging as the zipf head drifts,
 exchange bytes growing after a placement change. This module is that
 watcher:

@@ -259,7 +259,7 @@ _ATTR_COMPONENTS = ("compute", "h2d", "host_blocked", "other", "unaccounted")
 
 
 def _per_step_components(rec: Dict) -> Dict[str, Optional[float]]:
-    """Per-step seconds for each decomposition component of one run/bench
+    """Per-step seconds for each decomposition component of one run
     record (``None`` when the record carries no decomposition)."""
     gp = rec.get("goodput") or rec
     dec = gp.get("decomposition") or {}
@@ -278,7 +278,7 @@ def _per_step_components(rec: Dict) -> Dict[str, Optional[float]]:
 
 
 def _record_rate(rec: Dict) -> Optional[float]:
-    """items/sec (words/sec) of a run/bench record, from whichever field
+    """items/sec (words/sec) of a run record, from whichever field
     the record carries.
 
     A record with a span decomposition is rated as items over traced
@@ -309,9 +309,9 @@ def _record_rate(rec: Dict) -> Optional[float]:
 
 
 def throughput_attribution(rec_a: Dict, rec_b: Dict) -> Dict:
-    """Decompose the words/sec delta between two run/bench records.
+    """Decompose the words/sec delta between two run records.
 
-    The core of ``ledger-report --diff A B`` / ``tools/perf_diff.py``:
+    The core of ``ledger-report --diff A B``:
     per-step seconds for each goodput component (compute / h2d /
     host-blocked / other / unaccounted) are differenced A→B, per-scope
     comm-audit bytes likewise, and the **dominant contributor** is the
@@ -343,7 +343,7 @@ def throughput_attribution(rec_a: Dict, rec_b: Dict) -> Dict:
     )
 
     # per-scope comm bytes (the audit's by_scope map, carried on run
-    # records as comm_by_scope and on bench payloads inside the audit)
+    # records as comm_by_scope, or inside a record's audit block)
     def _by_scope(rec: Dict) -> Dict[str, float]:
         scopes = rec.get("comm_by_scope")
         if not scopes:

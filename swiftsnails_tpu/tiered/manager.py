@@ -100,7 +100,7 @@ class TierManager:
 
     def adopt(self, state):
         """Device planes -> host masters + device cache planes (+ prewarm)."""
-        self._drain()  # re-adopt (bench lane re-run): no stragglers from the
+        self._drain()  # re-adopt (a second run on one loop): no stragglers from the
         # previous generation of tables may land after the masters rebuild
         tabs = self.trainer.tier_tables(state)
         budget_each = self.budget_mb / max(len(tabs), 1)

@@ -65,7 +65,7 @@ def _native():
 @dataclass
 class TierStats:
     """Shared counters for the telemetry surface (goodput block, ledger run
-    record, bench ``tiered`` lane). ``lookups``/``hits`` count unique units
+    record). ``lookups``/``hits`` count unique units
     per fault batch; ``faulted_rows``/``evictions`` count cache units (rows
     for the dense/packed layouts, tiles for packed-small).
 
@@ -100,7 +100,7 @@ class TierStats:
         return self.hits / self.lookups if self.lookups else 0.0
 
     def breakdown(self) -> Dict:
-        """The tiered step-time breakdown block (bench JSON + ledger)."""
+        """The tiered step-time breakdown block (the run record's)."""
         return {
             "plan_ns": self.plan_ns,
             "fault_ns": self.fault_ns,
@@ -384,8 +384,7 @@ class HostMaster:
     @property
     def host_unit_nbytes(self) -> int:
         """STORED bytes per unit in host RAM (codes + scale sidebands for a
-        quantized master) — the capacity-per-GB readout the tiered bench
-        reports. Equals :attr:`unit_nbytes` for f32 masters."""
+        quantized master) — the capacity-per-GB readout. Equals :attr:`unit_nbytes` for f32 masters."""
         per = int(np.prod(self.table.shape[1:], dtype=np.int64)) or 1
         n = per * self.table.dtype.itemsize
         for v in self.slots.values():

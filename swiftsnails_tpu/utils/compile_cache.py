@@ -1,6 +1,6 @@
 """Persistent XLA compile cache, placed from outside the program.
 
-Every entry point that compiles (``cli.main``, ``bench.main``,
+Every entry point that compiles (``cli.main``, ``benchmark/run.py``,
 ``chip_smoke.py``, the tools) calls :func:`configure_compile_cache` once,
 before its first jit:
 

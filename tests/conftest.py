@@ -32,3 +32,55 @@ def _fresh_global_config():
     global_config().clear()
     yield
     global_config().clear()
+
+
+@pytest.fixture(scope="session")
+def drill_once(tmp_path_factory):
+    """``drill_once(name, fn, limit_s)``: run ``fn(workdir)`` once for the
+    whole test run and hand every test its (JSON) result. Under xdist the
+    workers share one directory and a file lock on it: the first to hold the
+    lock runs the drill and the others read what it wrote, so a drill that
+    spawns processes never runs six at a time; a worker that dies holding
+    the lock lets go of it, and the next one runs the drill. ``limit_s`` is
+    the drill's own time limit: past it the result is an error, not a hang."""
+    import fcntl
+    import json
+    import os
+    import threading
+
+    def limited(fn, workdir, limit_s):
+        box = {}
+
+        def target():
+            try:
+                box["result"] = fn(workdir)
+            except BaseException as e:  # every worker reads the same error
+                box["result"] = {"drill_error": f"{type(e).__name__}: {e}"}
+
+        t = threading.Thread(target=target, daemon=True)
+        t.start()
+        t.join(limit_s)
+        return box.get(
+            "result", {"drill_error": f"still running after {limit_s} s"})
+
+    root = tmp_path_factory.getbasetemp()
+    if os.environ.get("PYTEST_XDIST_WORKER"):
+        root = root.parent  # the run's directory, above each worker's own
+    results = {}
+
+    def once(name, fn, limit_s):
+        if name not in results:
+            out = root / f"{name}.json"
+            with open(root / f"{name}.lock", "w") as lock:
+                fcntl.flock(lock, fcntl.LOCK_EX)
+                if not out.exists():
+                    tmp = root / f"{name}.json.tmp"
+                    tmp.write_text(json.dumps(
+                        limited(fn, str(root / name), limit_s)))
+                    os.replace(tmp, out)
+                results[name] = json.loads(out.read_text())
+        if "drill_error" in results[name]:
+            pytest.fail(f"drill {name}: {results[name]['drill_error']}")
+        return results[name]
+
+    return once

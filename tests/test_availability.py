@@ -1,6 +1,6 @@
 """Availability hardening (ISSUE 8): the unified retry/deadline layer, the
-serving circuit breakers + degraded-mode reads, tier integrity digests, and
-the chaos-serve lane gate.
+serving circuit breakers + degraded-mode reads, and tier integrity digests
+(the serve drill itself is in ``test_drills.py``).
 
 The bars: backoff draws stay inside the decorrelated-jitter envelope and a
 wall-clock deadline pre-empts the attempt budget (all under a fake clock —
@@ -9,8 +9,7 @@ ledger event, never a silent give-up; the breaker walks
 closed -> open -> half-open -> closed with probe capping, including under
 concurrent queries; a tripped pull breaker serves stale LRU rows counted
 apart from every fresh counter; a direct master-plane write (bit rot) is
-caught by ``HostMaster.verify()``; and the chaos-serve availability block
-is gated by ``ledger-report --check-regression`` on any platform.
+caught by ``HostMaster.verify()``.
 """
 
 import os
@@ -44,7 +43,6 @@ from swiftsnails_tpu.serving.breaker import (
 from swiftsnails_tpu.serving.engine import Servant
 from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
-    check_regression,
     render_failures,
 )
 from swiftsnails_tpu.utils.config import Config
@@ -448,67 +446,3 @@ def test_single_bit_flip_is_detected():
     m = _master()
     m.table.view(np.uint8).reshape(-1)[17] ^= 0x01  # the minimal corruption
     assert m.verify() == ["table"]
-
-
-# ------------------------------------------------------- chaos-serve lane --
-
-
-def test_chaos_serve_lane_smoke(tmp_path):
-    from swiftsnails_tpu.serving.chaos_lane import chaos_serve_bench
-
-    ledger_path = str(tmp_path / "l.jsonl")
-    block = chaos_serve_bench(small=True, workdir=str(tmp_path / "w"),
-                              ledger=Ledger(ledger_path),
-                              include_tier_drill=False)
-    assert block["availability_pct"] >= block["floor_pct"]
-    assert block["degraded_share_pct"] > 0  # stale reads actually carried it
-    assert block["recovered"] and block["breaker"]["trips"] >= 1
-    assert block["unprotected_hard_failure"]
-    assert "OSError" in block["control_first_error"]
-    assert block["control_availability_pct"] < block["availability_pct"]
-    assert block["reload_corrupt_rejected"]
-    led = Ledger(ledger_path)
-    assert led.latest("breaker") is not None
-    assert led.latest("degraded") is not None
-
-
-def _bench_record(value, chaos_serve=None, platform="tpu"):
-    payload = {
-        "metric": "word2vec_words_per_sec_per_chip", "value": value,
-        "unit": "words/sec/chip", "platform": platform, "config": {},
-    }
-    if chaos_serve is not None:
-        payload["chaos_serve"] = chaos_serve
-    return {"payload": payload}
-
-
-_GOOD_BLOCK = {
-    "floor_pct": 99.0, "availability_pct": 100.0,
-    "unprotected_hard_failure": True, "reload_corrupt_rejected": True,
-    "tier_bitflip": {"recovered": True},
-}
-
-
-def test_check_regression_gates_availability_floor(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(100_000.0, chaos_serve=_GOOD_BLOCK))
-    led.append("bench", _bench_record(
-        101_000.0, chaos_serve={**_GOOD_BLOCK, "availability_pct": 92.0}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc != 0 and "chaos-serve REGRESSION" in msg and "92.0%" in msg
-
-
-def test_check_regression_gates_control_and_drills(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(100_000.0, chaos_serve=_GOOD_BLOCK))
-    led.append("bench", _bench_record(101_000.0, chaos_serve={
-        **_GOOD_BLOCK, "unprotected_hard_failure": False}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc != 0 and "chaos-serve REGRESSION" in msg
-    led.append("bench", _bench_record(102_000.0, chaos_serve={
-        **_GOOD_BLOCK, "tier_bitflip": {"recovered": False}}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc != 0 and "chaos-serve REGRESSION" in msg
-    led.append("bench", _bench_record(103_000.0, chaos_serve=_GOOD_BLOCK))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "chaos-serve ok" in msg

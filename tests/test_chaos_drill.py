@@ -1,8 +1,8 @@
 """Tier-1 fast subset of the chaos drill matrix (tools/chaos_drill.py).
 
 Each drill is a deterministic end-to-end recovery scenario; the full matrix
-(plus the slower preemption-resume script) runs via ``tools/chaos_drill.py``
-and the bench ``chaos`` lane. A drill that does not *recover* here is a
+(plus the slower preemption-resume script) runs via ``tools/chaos_drill.py``.
+A drill that does not *recover* here is a
 regression in the resilience stack, not flake: every fault is seeded."""
 
 import pytest

@@ -2,8 +2,8 @@
 transition-edged ``drift`` ledger events, atomic incident bundles (and
 the drift + NaN same-window interplay — two distinct bundles, never one
 clobbered dir), the TrainLoop wiring under a ``slow_step`` chaos
-injection, ``--diff`` throughput attribution, and the two new CI gates
-(drift drill + profiler overhead)."""
+injection, and ``--diff`` throughput attribution (the drift drill itself is
+in ``test_drills.py``)."""
 
 import json
 import os
@@ -30,7 +30,6 @@ from swiftsnails_tpu.telemetry.goodput import (
 from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
     _resolve_diff_record,
-    check_regression,
     render_diff,
     render_failures,
 )
@@ -214,13 +213,17 @@ def _drift_loop(tmp_path, **trainer_kw):
         "profile_cadence": "1",
         "profile_window": "64",
         "drift_detect": "1",
-        "drift_warmup": "6",
+        # the detectors arm on the band's first sample (chaos step 16 is
+        # sample 17): a hiccup of a loaded host before the band cannot then
+        # confirm a drift and use up the one transition-edged event
+        "drift_warmup": "15",
         "blackbox_dir": str(tmp_path / "bb"),
         "incident_dir": str(tmp_path / "incidents"),
         "ledger_path": str(tmp_path / "ledger.jsonl"),
-        # a 25ms sleep against sub-ms toy steps: an unmissable shift
+        # an 80ms sleep against sub-ms toy steps: a shift no hiccup seen
+        # during warm-up hides
         "chaos_spec": "slow_step@16-40",
-        "chaos_slow_step_ms": "25",
+        "chaos_slow_step_ms": "80",
     })
     trainer = ToyTrainer(cfg, **trainer_kw)
     return TrainLoop(trainer, metrics=MetricsLogger(echo=False), log_every=1)
@@ -364,88 +367,3 @@ def test_resolve_diff_record_index_and_file(tmp_path):
     empty = Ledger(str(tmp_path / "empty.jsonl"))
     with pytest.raises(ValueError, match="no run records"):
         _resolve_diff_record(empty, "-1")
-
-
-# ----------------------------------------------------------- the CI gates ----
-
-
-def _drift_payload(detected=True, events=1, complete=True,
-                   dominant="host_blocked"):
-    return {
-        "detected": detected, "detect_step": 17, "inject_step": 16,
-        "drift_events": events, "bundle_complete": complete,
-        "attribution": {"dominant": dominant},
-    }
-
-
-def _gate_ledger(tmp_path, drift=None, profile_overhead=None):
-    led = Ledger(str(tmp_path / "gate.jsonl"))
-    payload = {
-        "metric": "word2vec_words_per_sec_per_chip", "value": 100_000.0,
-        "unit": "words/sec/chip", "platform": "tpu", "config": {},
-    }
-    led.append("bench", {"payload": dict(payload)})  # history to gate against
-    if drift is not None:
-        payload["drift"] = drift
-    if profile_overhead is not None:
-        payload["profile_overhead"] = profile_overhead
-    led.append("bench", {"payload": payload})
-    return led
-
-
-def test_drift_gate_passes_a_clean_drill(tmp_path):
-    led = _gate_ledger(tmp_path, drift=_drift_payload())
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0
-    assert "drift ok" in msg and "dominant=host_blocked" in msg
-
-
-@pytest.mark.parametrize("block,needle", [
-    (_drift_payload(detected=False), "NOT detected"),
-    (_drift_payload(events=3), "exactly one transition-edged"),
-    (_drift_payload(complete=False), "bundle incomplete"),
-    (_drift_payload(dominant="h2d"), "named 'h2d' dominant"),
-])
-def test_drift_gate_fails_each_broken_leg(tmp_path, block, needle):
-    led = _gate_ledger(tmp_path, drift=block)
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1
-    assert "drift REGRESSION" in msg and needle in msg
-
-
-def test_drift_gate_silent_without_history(tmp_path):
-    led = _gate_ledger(tmp_path)
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "drift" not in msg
-
-
-def _overhead_payload(pct, noise=0.5, ceil=3.0):
-    return {"overhead_pct": pct, "noise_pct": noise,
-            "overhead_ceil_pct": ceil, "cadence": 4,
-            "wps_off": 100_000.0, "wps_on": 100_000.0 * (1 - pct / 100)}
-
-
-def test_profiler_overhead_gate_passes_under_ceiling(tmp_path):
-    led = _gate_ledger(tmp_path, profile_overhead=_overhead_payload(1.2))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "profiler-overhead ok" in msg and "cadence 4" in msg
-
-
-def test_profiler_overhead_gate_trips_over_ceiling(tmp_path):
-    led = _gate_ledger(tmp_path, profile_overhead=_overhead_payload(6.0))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "profiler-overhead REGRESSION" in msg
-
-
-def test_profiler_overhead_gate_respects_measured_noise_floor(tmp_path):
-    # a +6% delta inside a 10% off-leg self-disagreement is jitter, not cost
-    led = _gate_ledger(
-        tmp_path, profile_overhead=_overhead_payload(6.0, noise=10.0))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "profiler-overhead ok" in msg
-    # an unmeasured block (no pct) must fail loudly, not pass silently
-    sub = tmp_path / "x2"
-    sub.mkdir()
-    led2 = _gate_ledger(sub, profile_overhead={"overhead_ceil_pct": 3.0})
-    rc2, msg2 = check_regression(led2, 10.0)
-    assert rc2 == 1 and "no overhead_pct" in msg2

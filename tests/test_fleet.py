@@ -1,7 +1,7 @@
 """Serving fleet: ring determinism + bounded spill, hedge first-writer-wins
 and budget cap, breaker-aware routing, typed-failure re-route, connection
-draining, affinity-vs-random cache economics, the fleet bench lane, and the
-fleet CI gate.
+draining, and affinity-vs-random cache economics (the fleet drill itself is
+in ``test_drills.py``).
 
 The router's correctness bars (ISSUE 13): consistent-hash ownership must be
 reproducible across construction orders and a removed node must only move
@@ -9,12 +9,9 @@ its own keys; a stalled primary must lose to its hedge (first writer wins)
 without the governor's budget ever being exceeded; an open breaker must
 demote its replica to last resort; ``drain`` must complete in-flight
 requests before teardown and land ``drain`` events in the ledger; affinity
-routing must beat random spray's aggregate cache hit rate on zipf traffic;
-and ``check_regression`` must trip on an SLO / scaling / affinity / hedge
-breach in the newest ``fleet`` block.
+routing must beat random spray's aggregate cache hit rate on zipf traffic.
 """
 
-import json
 import os
 import sys
 import threading
@@ -25,7 +22,6 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench
 from swiftsnails_tpu.serving import Overloaded, Servant
 from swiftsnails_tpu.serving.fleet import Fleet
 from swiftsnails_tpu.serving.loadgen import anchor_ids, zipf_weights
@@ -38,7 +34,6 @@ from swiftsnails_tpu.serving.router import (
 )
 from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
-    check_regression,
     render_failures,
 )
 from swiftsnails_tpu.telemetry.registry import Histogram
@@ -300,116 +295,6 @@ def test_affinity_beats_random_on_zipf_traffic():
     # same zipf trace, same per-replica LRU budget: keeping a key slice on
     # its owner must beat spraying the global head over every cache
     assert rates[True] > rates[False]
-
-
-# ------------------------------------------------------- fleet bench lane --
-
-
-@pytest.fixture()
-def isolated_bench(tmp_path, monkeypatch):
-    monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "_SMALL", True)
-    monkeypatch.setitem(bench._state, "errors", [])
-    monkeypatch.setitem(bench._state, "fleet", None)
-    return tmp_path
-
-
-def test_fleet_lane_smoke(isolated_bench):
-    bench.measure_fleet()
-    block = bench._state["fleet"]
-    assert block and block["replicas"] == 2
-    assert block["single"]["max_qps"] > 0
-    assert block["fleet"]["max_qps"] > 0
-    assert block["qps"] == block["fleet"]["max_qps"]
-    assert block["scaling_x"] > 0 and block["scaling_floor"] == 1.6
-    assert block["p99_ms"] > 0 and block["slo_p99_ms"] > 0
-    per = block["fleet"]["per_replica"]
-    assert len(per) == 2
-    assert all(rs["requests"] > 0 for rs in per.values())
-    aff = block["affinity"]
-    assert 0.0 <= aff["random_hit_rate"] <= 1.0
-    assert 0.0 <= aff["affinity_hit_rate"] <= 1.0
-    hedge = block["hedge"]
-    assert hedge["p99_ms"] > 0 and hedge["nohedge_p99_ms"] > 0
-    assert not bench._state["errors"]
-    # the block reaches the emitted JSON line (-> ledger payload)
-    payload = json.loads(bench._result_json())
-    assert payload["fleet"]["qps"] == block["qps"]
-
-
-# ------------------------------------------------------------ fleet gate ---
-
-
-def _fleet_block(qps=300.0, p99=30.0, slo=60.0, scaling=1.8, replicas=2,
-                 affinity=(0.44, 0.35), hedge=(40.0, 90.0)):
-    return {
-        "qps": qps, "p99_ms": p99, "slo_p99_ms": slo,
-        "scaling_x": scaling, "scaling_floor": 1.6, "replicas": replicas,
-        "affinity": {"affinity_hit_rate": affinity[0],
-                     "random_hit_rate": affinity[1]},
-        "hedge": {"p99_ms": hedge[0], "nohedge_p99_ms": hedge[1]},
-    }
-
-
-def _bench_record(value, fleet=None, platform="tpu"):
-    payload = {
-        "metric": "word2vec_words_per_sec_per_chip", "value": value,
-        "unit": "words/sec/chip", "platform": platform, "config": {},
-    }
-    if fleet is not None:
-        payload["fleet"] = fleet
-    return {"payload": payload}
-
-
-def test_fleet_gate_trips_on_slo_breach(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(
-        100_000.0, fleet=_fleet_block(p99=75.0, slo=60.0)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "fleet REGRESSION" in msg and "SLO" in msg
-
-
-def test_fleet_gate_trips_on_scaling_floor(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(
-        100_000.0, fleet=_fleet_block(scaling=1.3)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "fleet REGRESSION" in msg
-    assert "below the 1.6x floor" in msg
-
-
-def test_fleet_gate_trips_on_affinity_and_hedge(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(
-        100_000.0,
-        fleet=_fleet_block(affinity=(0.30, 0.35), hedge=(95.0, 90.0))))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "fleet REGRESSION" in msg
-    assert "affinity hit rate" in msg and "hedged p99" in msg
-
-
-def test_fleet_gate_qps_floor_and_recovery(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(100_000.0, fleet=_fleet_block(qps=300.0)))
-    led.append("bench", _bench_record(101_000.0, fleet=_fleet_block(qps=100.0)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "fleet REGRESSION" in msg and "fleet qps" in msg
-    assert msg.splitlines()[0].startswith("ok:")  # headline itself was fine
-    led.append("bench", _bench_record(102_000.0, fleet=_fleet_block(qps=310.0)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "fleet ok" in msg
-
-
-def test_fleet_gate_qps_is_platform_scoped(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    # a fast TPU history must not gate a CPU CI record on absolute qps,
-    # but the correctness checks (SLO/scaling/affinity/hedge) still apply
-    led.append("bench", _bench_record(
-        100_000.0, fleet=_fleet_block(qps=50_000.0)))
-    led.append("bench", _bench_record(
-        101_000.0, fleet=_fleet_block(qps=200.0), platform="cpu"))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "single cpu record" in msg
 
 
 # --------------------------------------------- histogram + failure lines ---

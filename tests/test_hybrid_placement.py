@@ -13,12 +13,11 @@ a statically smaller dedup capacity. These tests pin:
   composed with comm_dtype: int8;
 * non-composing configs (no mesh, table_tier: host) resolve to uniform
   with a recorded reason;
-* the comm audit's per-table attribution, the ledger's placement rendering
-  + skewed-lane exchange-bytes floor gate, and the bench skewed leg's
-  >= 2x audited exchange cut with loss parity.
+* the comm audit's per-table attribution, the ledger's placement
+  rendering, and auto's >= 2x audited exchange cut with loss parity on a
+  steep-zipf corpus.
 """
 
-import json
 import os
 import sys
 
@@ -327,31 +326,6 @@ def test_collective_stats_routes_table_scopes():
 # --------------------------------------------- ledger render + CI gate -----
 
 
-def _bench_record(value, skewed=None):
-    payload = {
-        "metric": "word2vec_words_per_sec_per_chip", "value": value,
-        "unit": "words/sec/chip", "platform": "tpu", "config": {},
-    }
-    if skewed is not None:
-        payload["scaling"] = {"aggregate_words_per_sec": 1e6,
-                              "skewed": skewed}
-    return {"payload": payload}
-
-
-def _skewed_block(reduction):
-    return {
-        "zipf_s": 1.4, "vocab": 4096,
-        "per_dtype": {"float32": {
-            "uniform_exchange_bytes": 1000, "hybrid_exchange_bytes": 100,
-            "exchange_reduction": reduction, "loss_delta": 0.0,
-        }},
-        "decision": {"mode": "hybrid", "cut": 512, "replicated_rows": 1024,
-                     "coverage": 0.96,
-                     "predicted_exchange_bytes": 120.0,
-                     "predicted_uniform_bytes": 1000.0},
-    }
-
-
 def test_ledger_renders_placement_decision(tmp_path):
     from swiftsnails_tpu.telemetry.ledger import Ledger, render_report
 
@@ -363,63 +337,87 @@ def test_ledger_renders_placement_decision(tmp_path):
                       "predicted_uniform_bytes": 9000.0,
                       "measured_exchange_bytes": 1300},
     })
-    led.append("bench", _bench_record(1.0, skewed=_skewed_block(8.05)))
     out = render_report(led)
     assert "hybrid placement (newest last):" in out
     assert "mode=hybrid" in out and "cut=512" in out
     assert "replicated_rows=1024" in out
     assert "measured=" in out and "predicted=" in out
-    assert "skewed[float32]" in out and "reduction=8.05x" in out
 
 
-def test_check_regression_gates_skewed_exchange_floor(tmp_path):
-    from swiftsnails_tpu.telemetry.ledger import Ledger, check_regression
-
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(100_000.0))
-    led.append("bench", _bench_record(101_000.0, skewed=_skewed_block(1.4)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1
-    assert "placement REGRESSION" in msg and "1.40x" in msg
-    led.append("bench", _bench_record(102_000.0, skewed=_skewed_block(2.6)))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0
-    assert "placement ok" in msg
+# ------------------------------------- auto's cut on a steep-zipf corpus ---
 
 
-def test_check_regression_without_skewed_history_gates_nothing(tmp_path):
-    from swiftsnails_tpu.telemetry.ledger import Ledger, check_regression
+def test_auto_cut_halves_audited_exchange_bytes_on_skewed_corpus():
+    """On a steep-zipf corpus (s=1.4, vocab id == frequency rank) the
+    auto-cut hybrid split, calibrated with the uniform layout's audited
+    exchange bytes, must move at most half of them per step at the same
+    wire format, with loss parity on identical batches and keys. The bytes
+    come from the compiled step's HLO shapes, so they are exact here."""
+    import itertools
 
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(100_000.0))
-    led.append("bench", _bench_record(99_000.0))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "placement" not in msg
+    from swiftsnails_tpu.data.sampler import batch_stream, skipgram_windows
+    from swiftsnails_tpu.parallel.mesh import batch_sharding
+    from swiftsnails_tpu.telemetry.audit import audit_step
 
+    vocab_n, dim, b_shard, spc, data, model = 1024, 16, 256, 2, 2, 4
+    macro_n = b_shard * data * spc
+    rng = np.random.default_rng(23)
+    w = 1.0 / np.arange(1, vocab_n + 1, dtype=np.float64) ** 1.4
+    ids = np.searchsorted(
+        np.cumsum(w) / w.sum(), rng.random(16_000)).astype(np.int32)
+    counts = np.bincount(ids, minlength=vocab_n).astype(np.int64)
+    # sampling noise can swap neighbours: re-rank so that id == rank exactly
+    order = np.argsort(-counts, kind="stable")
+    inv = np.empty_like(order)
+    inv[order] = np.arange(vocab_n)
+    ids = inv[ids].astype(np.int32)
+    vocab = Vocab([f"w{i}" for i in range(vocab_n)],
+                  np.maximum(counts[order], 1))
 
-# ------------------------------------------------- bench skewed leg --------
+    rng = np.random.default_rng(29)
+    g_c, g_x = skipgram_windows(ids, 5, rng)
+    batches = [
+        b for b in itertools.islice(batch_stream(g_c, g_x, macro_n, rng), 4)
+        if b["centers"].shape[0] == macro_n]
+    assert batches
+    mesh = make_mesh({DATA_AXIS: data, MODEL_AXIS: model},
+                     devices=jax.devices()[:8])
+    bs = batch_sharding(mesh)
+    dev_batches = [{k: jax.device_put(v, bs) for k, v in b.items()}
+                   for b in batches]
 
+    def layout(placement, calib_bytes=None):
+        conf = {
+            "dim": str(dim), "window": "5", "negatives": "5",
+            "learning_rate": "0.025", "batch_size": str(macro_n // spc),
+            "subsample": "0", "num_iters": "1", "steps_per_call": str(spc),
+            "table_dtype": "float32", "packed": "1", "neg_mode": "pool",
+            "pool_size": "64", "pool_block": "512", "fused": "1",
+            "grouped": "1", "comm_dtype": "float32", "placement": placement,
+        }
+        if calib_bytes:
+            conf["placement_calib_bytes"] = str(int(calib_bytes))
+        trainer = Word2VecTrainer(
+            Config(conf), mesh=mesh, corpus_ids=np.zeros(2, np.int32),
+            vocab=vocab)
+        state = trainer.init_state()
+        pm = PlacementManager(trainer, mesh)
+        if pm.active:
+            state = pm.adopt(state)
+        step = jax.jit(trainer.train_step, donate_argnums=(0,))
+        key = jax.random.PRNGKey(3)
+        for i in range(4):
+            state, m = step(state, dev_batches[i % len(dev_batches)],
+                            jax.random.fold_in(key, i))
+        report = audit_step(
+            step, state, dev_batches[0], jax.random.fold_in(key, 0))
+        return trainer, sum(report["by_scope"].values()), float(m["loss"]), report
 
-def test_bench_skewed_leg_cuts_exchange_bytes(monkeypatch):
-    import bench
-
-    monkeypatch.setitem(bench._state, "errors", [])
-    monkeypatch.setitem(bench._state, "scaling", {})
-    bench.measure_skewed_placement(
-        n_devices=8, comm_dtypes=("float32",), dim=16, batch_per_shard=256,
-        steps_per_call=2, vocab_size=1024)
-    assert not bench._state["errors"]
-    sk = bench._state["scaling"].get("skewed")
-    assert sk is not None
-    entry = sk["per_dtype"]["float32"]
-    # the acceptance bar: auto's cut removes >= 2x of the audited exchange
-    # bytes at the same wire format, with loss parity on identical batches
-    assert entry["exchange_reduction"] >= 2.0
-    assert entry["loss_delta"] <= 0.01
-    assert sk["decision"]["mode"] == "hybrid"
-    assert sk["decision"]["cut"] == entry["cut"] > 0
-    assert "by_table_bytes" in entry
-    # reaches the emitted JSON line (-> the ledger payload the gate reads)
-    payload = json.loads(bench._result_json())
-    assert payload["scaling"]["skewed"]["per_dtype"]["float32"][
-        "exchange_reduction"] >= 2.0
+    _, uniform_bytes, uniform_loss, _ = layout("uniform")
+    hybrid, hybrid_bytes, hybrid_loss, report = layout(
+        "auto", calib_bytes=uniform_bytes)
+    assert hybrid.placement_decision["mode"] == "hybrid"
+    assert hybrid.placement_decision["cut"] == hybrid.placement_cut > 0
+    assert uniform_bytes >= 2.0 * hybrid_bytes > 0
+    assert abs(hybrid_loss - uniform_loss) <= 0.01 * abs(uniform_loss)
+    assert report["by_table"]

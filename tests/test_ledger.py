@@ -1,5 +1,4 @@
-"""Run ledger: atomic append/replay, schema validation, and the regression
-gate."""
+"""Run ledger: atomic append/replay, the report, and the CLI way in."""
 
 import json
 import os
@@ -9,25 +8,12 @@ import pytest
 from swiftsnails_tpu.telemetry.ledger import (
     Ledger,
     atomic_write_json,
-    check_regression,
     config_hash,
     env_fingerprint,
     render_report,
-    validate_bench_payload,
 )
 
-
-def bench_payload(value=100.0, **over):
-    p = {
-        "metric": "word2vec_words_per_sec_per_chip",
-        "value": value,
-        "unit": "words/sec/chip",
-        "config": {"vocab": 1000, "dim": 8},
-        "path": "dense",
-        "platform": "tpu",
-    }
-    p.update(over)
-    return p
+RUN = {"model": "word2vec", "steps": 5, "items": 1280, "config_hash": "abcd"}
 
 
 # ------------------------------------------------------------ append/replay
@@ -35,15 +21,15 @@ def bench_payload(value=100.0, **over):
 
 def test_append_replay_roundtrip(tmp_path):
     led = Ledger(str(tmp_path / "ledger.jsonl"))
-    r1 = led.append("bench", {"payload": bench_payload()}, env={"jax": "x"})
-    r2 = led.append("outage", {"probe_duration_s": 12.5, "rc": 1, "error": "e"})
-    assert r1["schema"] == 1 and r1["kind"] == "bench" and "ts" in r1
+    r1 = led.append("run", dict(RUN), env={"jax": "x"})
+    led.append("outage", {"probe_duration_s": 12.5, "rc": 1, "error": "e"})
+    assert r1["schema"] == 1 and r1["kind"] == "run" and "ts" in r1
     records, bad = led.replay()
     assert bad == []
-    assert [r["kind"] for r in records] == ["bench", "outage"]
+    assert [r["kind"] for r in records] == ["run", "outage"]
     assert records[0]["env"] == {"jax": "x"}
     assert led.latest("outage")["probe_duration_s"] == 12.5
-    assert led.latest("run") is None
+    assert led.latest("blackbox") is None
     # every line on disk is independently parseable (atomic rewrite)
     for line in open(led.path):
         json.loads(line)
@@ -52,7 +38,7 @@ def test_append_replay_roundtrip(tmp_path):
 def test_replay_skips_corrupt_lines_and_heals_torn_tail(tmp_path):
     path = str(tmp_path / "ledger.jsonl")
     led = Ledger(path)
-    led.append("bench", {"payload": bench_payload()})
+    led.append("run", dict(RUN))
     # simulate a legacy torn write: garbage + a line without trailing newline
     with open(path, "a") as f:
         f.write('{"broken\n{"kind": "outage"')
@@ -61,7 +47,7 @@ def test_replay_skips_corrupt_lines_and_heals_torn_tail(tmp_path):
     # the next append heals the torn tail instead of concatenating onto it
     led.append("outage", {"error": "x"})
     records, bad = led.replay()
-    assert [r["kind"] for r in records] == ["bench", "outage"]
+    assert [r["kind"] for r in records] == ["run", "outage"]
 
 
 def test_append_is_atomic_no_tmp_litter(tmp_path):
@@ -93,15 +79,7 @@ def test_config_hash_stable_and_order_independent():
     assert len(h1) == 16
 
 
-# ------------------------------------------------------- payload schema
-
-
-def test_validate_bench_payload():
-    assert validate_bench_payload(bench_payload()) == []
-    assert validate_bench_payload([1, 2]) != []
-    assert any("metric" in p for p in validate_bench_payload({"value": 1.0}))
-    assert validate_bench_payload(bench_payload(value=0.0)) != []
-    assert validate_bench_payload(bench_payload(value="fast")) != []
+# ------------------------------------------------------- atomic writes
 
 
 def test_atomic_write_json_replaces_not_appends(tmp_path):
@@ -116,8 +94,6 @@ def test_atomic_write_json_replaces_not_appends(tmp_path):
 
 def test_render_report_covers_all_kinds(tmp_path):
     led = Ledger(str(tmp_path / "ledger.jsonl"))
-    led.append("bench", {"payload": bench_payload(), "cacheable": True,
-                         "config_hash": "abcd"})
     led.append("run", {"model": "word2vec", "steps": 5, "items": 1280,
                        "config_hash": "abcd",
                        "goodput": {"mfu": 0.41, "decomposition":
@@ -128,7 +104,7 @@ def test_render_report_covers_all_kinds(tmp_path):
     led.append("blackbox", {"reason": "nan-loss", "dump_path": "/tmp/bb.json",
                             "first_step": 3, "last_step": 7})
     out = render_report(led)
-    for needle in ("bench records", "training runs", "outages",
+    for needle in ("training runs", "outages",
                    "black-box dumps", "mfu=0.41", "nan-loss",
                    "config_hash=abcd", "compute_frac"):
         assert needle in out, f"missing {needle!r} in report:\n{out}"
@@ -136,64 +112,21 @@ def test_render_report_covers_all_kinds(tmp_path):
         "empty or missing ledger")
 
 
-# --------------------------------------------------------- regression gate
-
-
-def _measured(led, value, cached=False, reconstructed=False):
-    led.append("bench", {"payload": bench_payload(
-        value=value, cached=cached, reconstructed=reconstructed)})
-
-
-def test_check_regression_gate(tmp_path):
-    led = Ledger(str(tmp_path / "ledger.jsonl"))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 2  # nothing measured at all
-
-    _measured(led, 100.0)
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "single measured" in msg
-
-    _measured(led, 95.0)
-    assert check_regression(led, 10.0)[0] == 0  # -5% within tolerance
-    _measured(led, 80.0)
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "REGRESSION" in msg
-    # explicit pinned baseline overrides the ledger-derived one
-    assert check_regression(led, 10.0, baseline=85.0)[0] == 0
-    # cached/reconstructed emissions and CPU smoke runs never count
-    _measured(led, 200.0, cached=True)
-    _measured(led, 200.0, reconstructed=True)
-    led.append("bench", {"payload": bench_payload(value=1.0, platform="cpu")})
-    assert check_regression(led, 10.0)[0] == 1  # newest measured is still 80
+# -------------------------------------------------------------- the CLI
 
 
 def test_ledger_report_cli_roundtrip(tmp_path, capsys):
-    from swiftsnails_tpu.telemetry.ledger import main
+    from swiftsnails_tpu.cli import main
 
     path = str(tmp_path / "ledger.jsonl")
     led = Ledger(path)
-    _measured(led, 100.0)
-    _measured(led, 50.0)
-    assert main([path]) == 0
-    assert "bench records" in capsys.readouterr().out
-    assert main([path, "--check-regression", "10"]) == 1
-    assert main([path, "--check-regression", "60"]) == 0
-    # --baseline-file: pin via a preserved last-good payload
-    base = tmp_path / "pin.json"
-    base.write_text(json.dumps(bench_payload(value=55.0)))
-    assert main([path, "--check-regression", "10",
-                 "--baseline-file", str(base)]) == 0
-    bad = tmp_path / "bad.json"
-    bad.write_text("{")
-    assert main([path, "--check-regression", "10",
-                 "--baseline-file", str(bad)]) == 2
-    # parseable but not a bench payload: rejected by the schema, with the
-    # missing keys named
-    incomplete = tmp_path / "incomplete.json"
-    incomplete.write_text(json.dumps({"metric": "m"}))
-    capsys.readouterr()
-    assert main([path, "--check-regression", "10",
-                 "--baseline-file", str(incomplete)]) == 2
-    assert "missing required key 'value'" in capsys.readouterr().out
-    assert main([path, "--check-regression", "10", "--baseline-file",
-                 str(tmp_path / "missing.json")]) == 2
+    led.append("run", dict(RUN))
+    led.append("outage", {"probe_duration_s": 12.5, "rc": 1, "error": "e"})
+    assert main(["ledger-report", path]) == 0
+    out = capsys.readouterr().out
+    assert "training runs" in out and "outages (1 recorded" in out
+    assert main(["ledger-report", path, "--failures"]) == 0
+    assert "failure timeline" in capsys.readouterr().out
+    # the gates went with the records they judged: their flags are refused
+    with pytest.raises(SystemExit):
+        main(["ledger-report", path, "--baseline-file", "x.json"])

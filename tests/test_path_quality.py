@@ -7,7 +7,7 @@ behavior) — so throughput alone could hide a quality regression. This gate
 trains every path on the SAME structured corpus from the SAME init and
 asserts the learned co-occurrence structure clears the shared bar
 (:mod:`swiftsnails_tpu.framework.quality`, also run on real hardware by
-bench.py so a fast-but-wrong path can't ship a headline number).
+``chip_smoke.py`` so a fast-but-wrong path can't ship a headline number).
 Semantics being approximated: ``merge_push_value``
 (``src/core/parameter/sparsetable.h:176-179``) + per-pair negative draws.
 """
@@ -45,7 +45,7 @@ PATHS = {
 def test_fast_paths_match_reference_quality(name):
     """Every fast path must learn the pair structure about as well as the
     reference-faithful dense per-pair path; the absolute bar (shared with
-    bench.py's on-chip gate) means a collapse cannot hide behind a weak
+    ``chip_smoke.py``'s on-chip probe) means a collapse cannot hide behind a weak
     reference run."""
     top1 = probe_top1(PATHS[name])
     assert top1 >= MIN_TOP1, f"{name}: pair top-1 {top1:.3f} < {MIN_TOP1}"

@@ -1,6 +1,5 @@
 """Serving subsystem: checkpoint->serve parity, top-k parity, cache/version
-semantics, backpressure shed, pad-row accounting, the serve bench lane, and
-the serving CI gate.
+semantics, backpressure shed, and pad-row accounting.
 
 The read path's correctness bars (ISSUE 6): a serving pull must return rows
 bit-identical to the checkpointed tables on the f32 wire; the tiled top-k
@@ -11,7 +10,6 @@ reaches the run ledger and ``ledger-report --failures``; micro-batch pad
 rows (sentinel id 0) must never be cached or counted as served rows.
 """
 
-import json
 import os
 import sys
 import threading
@@ -23,7 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import jax.numpy as jnp
 
-import bench
 from swiftsnails_tpu.framework.checkpoint import load_tables, save_checkpoint
 from swiftsnails_tpu.serving import (
     HotRowCache,
@@ -32,16 +29,8 @@ from swiftsnails_tpu.serving import (
     normalize_table,
     topk_tiled,
 )
-from swiftsnails_tpu.serving.bench_lane import (
-    _build_logreg_checkpoint,
-    _build_word2vec_checkpoint,
-    serve_bench,
-)
-from swiftsnails_tpu.telemetry.ledger import (
-    Ledger,
-    check_regression,
-    render_failures,
-)
+from swiftsnails_tpu.serving.drill import build_word2vec_checkpoint
+from swiftsnails_tpu.telemetry.ledger import Ledger, render_failures
 
 DIM = 24
 CAP = 256
@@ -50,7 +39,7 @@ CAP = 256
 @pytest.fixture(scope="module")
 def w2v_ckpt(tmp_path_factory):
     root = str(tmp_path_factory.mktemp("serve") / "ckpt")
-    cfg = _build_word2vec_checkpoint(root, dim=DIM, capacity=CAP)
+    cfg, _ = build_word2vec_checkpoint(root, dim=DIM, capacity=CAP)
     return root, cfg
 
 
@@ -73,7 +62,7 @@ def test_pull_round_trip_bit_identical(w2v_ckpt):
 
 def test_load_tables_walks_back_over_corrupt_newest(tmp_path):
     root = str(tmp_path / "ckpt")
-    cfg = _build_word2vec_checkpoint(root, dim=8, capacity=64)
+    cfg, _ = build_word2vec_checkpoint(root, dim=8, capacity=64)
     state, _ = load_tables(root)
     save_checkpoint(root, state, step=2, wait=True)
     # flip bytes in step 2's biggest array file: CRC (or decode) must reject
@@ -131,13 +120,18 @@ def test_servant_topk_excludes_requested_ids(w2v_ckpt):
 
 def test_ctr_score_matches_trainer_predict(tmp_path):
     from swiftsnails_tpu.models.registry import get_model
+    from swiftsnails_tpu.utils.config import Config
 
     root = str(tmp_path / "ctr")
-    cfg = _build_logreg_checkpoint(root, num_fields=6, capacity=512)
+    cfg = Config({
+        "model": "logreg", "num_fields": "6", "capacity": "512",
+        "packed": "1", "seed": "11", "init_scale": "1.0",
+    })
     trainer = get_model("logreg")(
         cfg, mesh=None,
         data=(np.zeros(0, np.float32), np.zeros((0, 6), np.int32)),
     )
+    save_checkpoint(root, trainer.init_state(), step=1, wait=True)
     state, _ = load_tables(root)
     rng = np.random.default_rng(5)
     feats = rng.integers(0, 1 << 20, size=(9, 6)).astype(np.int32)
@@ -247,92 +241,3 @@ def test_backpressure_sheds_typed_error_and_ledger_event(tmp_path, capsys):
     assert ev["queue_depth"] == 1 and ev["shed_total"] == 1
     # ledger-report --failures renders the shed event
     assert "OVERLOAD kernel=pull" in render_failures(led)
-
-
-# ------------------------------------------------------- serve bench lane --
-
-
-@pytest.fixture()
-def isolated_bench(tmp_path, monkeypatch):
-    monkeypatch.setattr(bench, "LEDGER_PATH", str(tmp_path / "ledger.jsonl"))
-    monkeypatch.setattr(bench, "_SMALL", True)
-    monkeypatch.setitem(bench._state, "errors", [])
-    monkeypatch.setitem(bench._state, "serving", None)
-    return tmp_path
-
-
-def test_serve_lane_smoke(isolated_bench):
-    bench.measure_serving()
-    block = bench._state["serving"]
-    assert block and block["buckets"] == [8, 64]
-    for kernel in ("pull", "topk", "ctr_score"):
-        for b in block["buckets"]:
-            leg = block["kernels"][kernel][f"b{b}"]
-            assert leg["qps"] > 0
-            assert leg["p99_ms"] >= leg["p95_ms"] >= leg["p50_ms"] >= 0
-    assert block["qps"] == block["kernels"]["pull"]["b64"]["qps"]
-    assert 0.0 <= block["cache_hit_rate"] <= 1.0
-    assert block["cache_hit_rate"] > 0.5  # repeated hot set must hit
-    assert block["shed_count"] == 0
-    assert not bench._state["errors"]
-    # the block reaches the emitted JSON line (-> ledger payload)
-    payload = json.loads(bench._result_json())
-    assert payload["serving"]["qps"] == block["qps"]
-
-
-def test_serve_bench_standalone_small(tmp_path):
-    block = serve_bench(small=True, workdir=str(tmp_path))
-    assert block["checkpoint_step"] == 1
-    assert set(block["kernels"]) == {"pull", "topk", "ctr_score"}
-
-
-# ----------------------------------------------------------- serving gate --
-
-
-def _bench_record(value, serving=None, platform="tpu"):
-    payload = {
-        "metric": "word2vec_words_per_sec_per_chip", "value": value,
-        "unit": "words/sec/chip", "platform": platform, "config": {},
-    }
-    if serving is not None:
-        payload["serving"] = serving
-    return {"payload": payload}
-
-
-def test_check_regression_gates_serving_qps(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(
-        100_000.0, serving={"qps": 5000.0, "p99_ms": 2.0}))
-    led.append("bench", _bench_record(
-        101_000.0, serving={"qps": 1000.0, "p99_ms": 2.0}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "serving REGRESSION" in msg
-    assert "pull qps" in msg
-    assert msg.splitlines()[0].startswith("ok:")  # headline itself was fine
-
-
-def test_check_regression_gates_serving_p99(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    led.append("bench", _bench_record(
-        100_000.0, serving={"qps": 5000.0, "p99_ms": 2.0}))
-    led.append("bench", _bench_record(
-        101_000.0, serving={"qps": 5100.0, "p99_ms": 9.0}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 1 and "serving REGRESSION" in msg and "p99" in msg
-    # healthy serve lane passes alongside the headline
-    led.append("bench", _bench_record(
-        102_000.0, serving={"qps": 5200.0, "p99_ms": 1.9}))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0 and "serving ok" in msg
-
-
-def test_serving_gate_is_platform_scoped(tmp_path):
-    led = Ledger(str(tmp_path / "l.jsonl"))
-    # a fast TPU history must not gate a CPU CI record
-    led.append("bench", _bench_record(
-        100_000.0, serving={"qps": 50_000.0, "p99_ms": 0.1}))
-    led.append("bench", _bench_record(
-        101_000.0, serving={"qps": 200.0, "p99_ms": 8.0}, platform="cpu"))
-    rc, msg = check_regression(led, 10.0)
-    assert rc == 0
-    assert "single cpu record" in msg

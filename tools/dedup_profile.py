@@ -34,8 +34,8 @@ def main():
 
     V, DIM, W, PC, PN, UC = 1_000_000, 200, 5, 256, 64, 384
     S = -(-DIM // 128)
-    # centers per KERNEL CALL — the bench substep shape (bench.py caps the
-    # grouped batch at 8192 for SMEM; the macro is 8 scanned substeps).
+    # centers per KERNEL CALL — the cell's substep shape (the grouped batch
+    # is capped at 8192 for SMEM; the macro is 8 scanned substeps).
     # 98304-as-one-call overflows the 1 MiB SMEM prefetch budget.
     N = 8192
     SPC = 8  # substeps per timed dispatch, matching STEPS_PER_CALL

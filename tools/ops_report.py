@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """One-screen fleet ops dashboard reconstructed from the run ledger.
 
-Where ``ledger_report.py`` renders the full append-only history and gates
-CI, this is the *glance* view an operator checks before paging: the
+Where ``ledger-report`` renders the full append-only history, this is the
+*glance* view an operator checks before paging: the
 newest fleet bench block (per-replica qps/p50/p99/hit-rate, tracing
 overhead), the newest freshness lane (lag p99, bit parity, gap-drill
 recovery), the SLO error budget from recent ``slo_burn`` events, and the
