@@ -68,6 +68,32 @@ def _wait_rows(row_ref, chunk_ref, sem, count):
     jax.lax.fori_loop(0, count - nch * ch, w, 0)
 
 
+# DMA starts per iteration of the issue loops, each kernel alone on a v5e at
+# its cell's shapes. The fused scatter's 55,893 live rows (rowdma.py): 2.99 ->
+# 2.25 ms (16: 2.17; PERF.md, PR 27). The gather's 212,992 slots, waits
+# chunked (rowdma.py): 5.20 -> 3.37 ms (16: 3.22, 32: 3.15, 64: 3.11; PERF.md,
+# PR 29). The grouped SGNS substep's 104,800 starts (_grouped_kernel below):
+# 3.00 -> 1.87 ms, 28.6 -> 17.9 ns a start (16: 1.81; the context loop alone
+# unrolled: 2.07; PERF.md, PR 32): one constant for all three
+_START_UNROLL = 8
+
+
+def _start_rows(n, start_one):
+    """``start_one(j)`` for ``j`` in ``[0, n)``, ``_START_UNROLL`` to a loop
+    iteration and the remainder one by one: the scalar core pays per
+    iteration, not per DMA. ``n`` is a Python int or a traced scalar."""
+
+    def group(k, _):
+        for u in range(_START_UNROLL):
+            start_one(k * _START_UNROLL + u)
+        return 0
+
+    whole = n // _START_UNROLL
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(
+        whole * _START_UNROLL, n, lambda j, _: (start_one(j), 0)[1], 0)
+
+
 def _kernel(in_rows_ref, pos_rows_ref, pool_rows_ref, lr_ref,
             in_t_in, out_t_in, in_table, out_table, loss_ref,
             v_buf, u_buf, p_buf, read_sems, write_sems,
@@ -225,7 +251,7 @@ def _grouped_kernel(c_rows_ref, ctx_rows_ref, ctx_slot_ref, nctx_ref,
             src, dst = pair if read else pair[::-1]
             return pltpu.make_async_copy(src, dst, sems.at[slot])
 
-        def v_dma(p, _):
+        def v_dma(p):
             v = c_rows_ref[b * PC + p]
             if read:
                 mk(v_buf.at[slot, p], in_table, v & _ROW_MASK).start()
@@ -233,9 +259,8 @@ def _grouped_kernel(c_rows_ref, ctx_rows_ref, ctx_slot_ref, nctx_ref,
                 @pl.when((v >> 30) != 0)
                 def _():
                     mk(v_buf.at[slot, p], in_table, v & _ROW_MASK).start()
-            return 0
 
-        def u_dma(k, _):
+        def u_dma(k):
             # two-segment copy list (_cold_compact): the first nwu entries
             # are exactly the flagged last-occurrence writes, so the write
             # loop is bounded by nwu and issues UNCONDITIONALLY — no
@@ -243,16 +268,14 @@ def _grouped_kernel(c_rows_ref, ctx_rows_ref, ctx_slot_ref, nctx_ref,
             s = ctx_slot_ref[b * cap + k]
             row = ctx_rows_ref[b * cap + k]
             mk(u_buf.at[slot, s & _SLOT_MASK], out_table, row).start()
-            return 0
 
-        def p_dma(q, _):
+        def p_dma(q):
             mk(p_buf.at[slot, q], out_table, pool_rows_ref[b * PN + q]).start()
-            return 0
 
-        jax.lax.fori_loop(0, PC, v_dma, 0)
+        _start_rows(PC, v_dma)
         # read: all real slots; write: flagged prefix only
-        jax.lax.fori_loop(0, nctx_ref[b] if read else nwu_ref[b], u_dma, 0)
-        jax.lax.fori_loop(0, PN, p_dma, 0)
+        _start_rows(nctx_ref[b] if read else nwu_ref[b], u_dma)
+        _start_rows(PN, p_dma)
 
     def wait_all(b, slot, table_dir):
         read = table_dir == "read"

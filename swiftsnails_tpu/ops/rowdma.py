@@ -24,7 +24,10 @@ an option; equal-sized copies make shared byte-accounting exact):
   VMEM, emitting ``[N, S, 128]``. Block ``i+1``'s row DMAs are issued before
   block ``i`` is consumed, so issue latency overlaps the output pipeline.
   Every slot is live, so a block's copies are started ``_START_UNROLL`` to a
-  loop iteration and retired ``_WAIT_CHUNK`` rows to a wait (PR 29).
+  loop iteration and retired ``_WAIT_CHUNK`` rows to a wait (PR 29). Both
+  halves of that loop, ``_start_rows`` and ``_wait_rows``, live in
+  ``ops/fused_sgns.py`` beside the grouped SGNS kernel, which runs them too
+  (PR 32), with the readings that chose their constants.
 * :func:`scatter_add_rows` — push: read-modify-write ``table[r] += delta``
   per row, pipelined two blocks deep (reads of block ``i+1`` overlap writes
   of block ``i``). Rows MUST be unique (or >= capacity for padding slots,
@@ -57,7 +60,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from swiftsnails_tpu.ops.fused_sgns import _WAIT_CHUNK, _wait_rows
+from swiftsnails_tpu.ops.fused_sgns import _WAIT_CHUNK, _start_rows, _wait_rows
 
 ROW_LANES = 128
 
@@ -82,29 +85,6 @@ def unpack_rows(rows3d: jax.Array, dim: int) -> jax.Array:
     """[N, S, 128] -> [N, dim]."""
     n = rows3d.shape[0]
     return rows3d.reshape(n, -1)[:, :dim]
-
-
-# DMA starts per iteration of the issue loops, on a v5e at the Wide&Deep
-# cell's shapes. The fused scatter's 55,893 live rows: 2.99 -> 2.25 ms (16:
-# 2.17; PERF.md, PR 27). The gather's 212,992 slots, waits chunked: 5.20 ->
-# 3.37 ms (16: 3.22, 32: 3.15, 64: 3.11; PERF.md, PR 29): one constant for both
-_START_UNROLL = 8
-
-
-def _start_rows(n, start_one):
-    """``start_one(j)`` for ``j`` in ``[0, n)``, ``_START_UNROLL`` to a loop
-    iteration and the remainder one by one: the scalar core pays per
-    iteration, not per DMA. ``n`` is a Python int or a traced scalar."""
-
-    def group(k, _):
-        for u in range(_START_UNROLL):
-            start_one(k * _START_UNROLL + u)
-        return 0
-
-    whole = n // _START_UNROLL
-    jax.lax.fori_loop(0, whole, group, 0)
-    jax.lax.fori_loop(
-        whole * _START_UNROLL, n, lambda j, _: (start_one(j), 0)[1], 0)
 
 
 # --------------------------------------------------------------- gather ---

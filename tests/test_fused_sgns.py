@@ -178,36 +178,96 @@ def reference_grouped(in_t, out_t, centers, ctxs, pool_rows, lr, lam, window,
     return in_t, out_t, total_loss
 
 
-@pytest.mark.parametrize("seed", [0, 1])
-def test_grouped_matches_sequential_reference(seed):
-    from swiftsnails_tpu.ops.fused_sgns import fused_sgns_grouped_step
+def _grouped_inputs(rng, C, N, PC, PN, CW, blocks=None):
+    """Centers, contexts and pool rows of a grouped step. ``blocks`` gives,
+    per block of ``PC`` centers, (real context slots, distinct rows among
+    them): what the kernel reads as ``nctx`` and as its write prefix
+    ``nwrite_u``. Without it the slots are random, pads and a fully padded
+    center included."""
+    centers = rng.integers(0, C, N).astype(np.int32)
+    pool_rows = rng.integers(0, C, (N // PC) * PN).astype(np.int32)
+    if blocks is None:
+        ctxs = rng.integers(0, C, (N, CW)).astype(np.int32)
+        # random pads (including fully-padded centers) + duplicates
+        ctxs[rng.random((N, CW)) < 0.4] = -1
+        ctxs[3] = -1
+        return centers, ctxs, pool_rows
+    assert len(blocks) == N // PC
+    ctxs = np.full((N, CW), -1, np.int32)
+    for b, (real, distinct) in enumerate(blocks):
+        rows = rng.permutation(C)[:distinct]
+        rows = np.concatenate([rows, rng.choice(rows, real - distinct)]) if real else rows
+        slots = rng.permutation(PC * CW)[:real]
+        ctxs[b * PC + slots // CW, slots % CW] = rng.permutation(rows)
+    return centers, ctxs, pool_rows
 
+
+# name -> (seed, PC, PN, per block (real context slots, distinct rows) or None).
+# The issue loops start _START_UNROLL (8) copies to an iteration and the
+# remainder one by one: every count below 8, at 8, and 8k + r is a path
+_GROUPED_CASES = {
+    "seed0": (0, 8, 4, None),
+    "seed1": (1, 8, 4, None),
+    # reads of 0, 1..7, exactly 8 and 8k + r slots, all rows distinct, so
+    # the write prefix is as long as the read list
+    "ctx_0_5_8_19": (2, 8, 4, [(0, 0), (5, 5), (8, 8), (19, 19)]),
+    "ctx_1_7_16_33": (3, 8, 4, [(1, 1), (7, 7), (16, 16), (33, 33)]),
+    # duplicates: the write prefix (last occurrences only) is shorter than
+    # the read list and has edges of its own
+    "write_prefix_1_3_8_17": (4, 8, 4, [(9, 1), (12, 3), (21, 8), (40, 17)]),
+    # neither the centers nor the pool a multiple of 8: 12 = 8 + 4, 11 = 8 + 3
+    "pc12_pool11": (5, 12, 11, None),
+    "pc12_pool11_ctx_0_8_13_72": (6, 12, 11, [(0, 0), (8, 8), (13, 9), (72, 30)]),
+    # the same step with the starts one to an iteration, the loop before PR 32
+    "bit_equal_to_rolled_starts": (7, 8, 4, [(0, 0), (6, 4), (8, 8), (27, 19)]),
+}
+
+
+@pytest.mark.parametrize("case", list(_GROUPED_CASES))
+def test_grouped_matches_sequential_reference(case, monkeypatch):
+    from swiftsnails_tpu.ops import fused_sgns
+
+    seed, PC, PN, blocks = _GROUPED_CASES[case]
     rng = np.random.default_rng(seed)
     C, S, L = 64, 2, 128
-    N, PC, PN, W = 32, 8, 4, 3
+    N, W = 4 * PC, 3
     CW = 2 * W
     in_t = rng.normal(size=(C, S, L)).astype(np.float32) * 0.1
     out_t = rng.normal(size=(C, S, L)).astype(np.float32) * 0.1
-    centers = rng.integers(0, C, N).astype(np.int32)
-    ctxs = rng.integers(0, C, (N, CW)).astype(np.int32)
-    # random pads (including fully-padded centers) + duplicates
-    ctxs[rng.random((N, CW)) < 0.4] = -1
-    ctxs[3] = -1
-    pool_rows = rng.integers(0, C, (N // PC) * PN).astype(np.int32)
+    centers, ctxs, pool_rows = _grouped_inputs(rng, C, N, PC, PN, CW, blocks)
     lr, lam = 0.05, 0.625
+    if blocks is not None:  # the counts the kernel will be handed
+        per_block = ctxs.reshape(N // PC, PC * CW)
+        assert [(int((r >= 0).sum()), len(np.unique(r[r >= 0])))
+                for r in per_block] == blocks
+
+    def step():
+        return fused_sgns.fused_sgns_grouped_step(
+            jnp.asarray(in_t), jnp.asarray(out_t), jnp.asarray(centers),
+            jnp.asarray(ctxs), jnp.asarray(pool_rows),
+            lr=lr, lam=lam, window=W, centers_per_block=PC, pool_size=PN,
+            interpret=True,
+        )
 
     want_in, want_out, want_loss = reference_grouped(
         in_t, out_t, centers, ctxs, pool_rows, lr, lam, W, PC, PN
     )
-    got_in, got_out, got_loss = fused_sgns_grouped_step(
-        jnp.asarray(in_t), jnp.asarray(out_t), jnp.asarray(centers),
-        jnp.asarray(ctxs), jnp.asarray(pool_rows),
-        lr=lr, lam=lam, window=W, centers_per_block=PC, pool_size=PN,
-        interpret=True,
-    )
+    got_in, got_out, got_loss = step()
     np.testing.assert_allclose(np.asarray(got_in), want_in, rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(np.asarray(got_out), want_out, rtol=2e-5, atol=2e-6)
     np.testing.assert_allclose(float(got_loss), want_loss, rtol=1e-4)
+
+    if case == "bit_equal_to_rolled_starts":
+        # the jitted step keeps its trace: drop it on both sides of the patch
+        try:
+            with monkeypatch.context() as patch:
+                patch.setattr(fused_sgns, "_START_UNROLL", 1)
+                fused_sgns.fused_sgns_grouped_step.clear_cache()
+                rolled = step()
+        finally:
+            fused_sgns.fused_sgns_grouped_step.clear_cache()
+        for got, want in zip((got_in, got_out, got_loss), rolled):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # --------------------------------------------------------------- resident ---
