@@ -16,14 +16,17 @@ Config keys: ``seq_len``, ``n_layers``, ``n_heads``, ``d_model``,
 ``attention`` (``ring`` | ``ulysses`` | ``dense``), ``optimizer``
 (``sgd`` | ``momentum`` | ``adam`` | ``adamw``), plus the usual
 ``learning_rate``, ``batch_size``, ``num_iters``, ``data`` (a text corpus,
-or a ``.npy`` file of token ids with ``vocab_size`` stated).
+or a ``.npy`` file of token ids with ``vocab_size`` stated); ``block_length``,
+``mask_token_id`` for the block-diffusion objective.
 
 What every sequence trainer shares lives here once: the corpus and its
 windows (:meth:`SeqLMTrainer.batches`), the optimizer wiring, the one
 ``train_step`` (gradient step, then :meth:`SeqLMTrainer.after_update` for
-what a step changes that is no gradient) and the loss
-(:func:`next_token_loss`). ``models/moelm.py`` puts another block stack
-under them.
+what a step changes that is no gradient) and the loss (:func:`token_loss`),
+under either objective: the next token's, or, with ``block_length`` set, the
+masked tokens' of block diffusion (:func:`draw_noise` on the host,
+:func:`diffusion_inputs` in the step). ``models/moelm.py`` puts another block
+stack under them.
 """
 
 from __future__ import annotations
@@ -54,22 +57,25 @@ def _norm(x):
     return (x32 * scale).astype(x.dtype)
 
 
-def next_token_loss(hidden, head, targets, chunks: int = 1, matmul=jnp.dot):
+def token_loss(hidden, head, targets, chunks: int = 1, matmul=jnp.dot, weights=None):
     """Mean cross entropy of ``hidden [T, d] @ head [d, V]`` against
-    ``targets [T]``, float32. With ``chunks`` > 1 the tokens go by in that
-    many chunks, each chunk's logits recomputed in the backward pass, so that
-    only ``[T / chunks, V]`` logits (and as much gradient) exist at once."""
+    ``targets [T]``, float32, each token's term times ``weights [T]`` if
+    given (the sum still over ``T``). With ``chunks`` > 1 the tokens go by in
+    that many chunks, each chunk's logits recomputed in the backward pass, so
+    that only ``[T / chunks, V]`` logits (and as much gradient) exist at once."""
     t = hidden.shape[0]
     if t % chunks:
         raise ValueError(f"{t} tokens do not split into {chunks} chunks")
 
-    def chunk_loss(h, y):
+    def chunk_loss(h, y, *w):
         logits = matmul(h, head).astype(jnp.float32)
         lse = jax.nn.logsumexp(logits, axis=-1)
-        return jnp.sum(lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0])
+        ce = lse - jnp.take_along_axis(logits, y[:, None], axis=-1)[:, 0]
+        return jnp.sum(ce * w[0] if w else ce)
 
+    per_token = (targets,) if weights is None else (targets, weights)
     if chunks == 1:
-        return chunk_loss(hidden, targets) / t
+        return chunk_loss(hidden, *per_token) / t
     chunk_loss = jax.checkpoint(chunk_loss)
 
     def body(total, xs):
@@ -77,8 +83,39 @@ def next_token_loss(hidden, head, targets, chunks: int = 1, matmul=jnp.dot):
 
     total, _ = jax.lax.scan(
         body, jnp.zeros((), jnp.float32),
-        (hidden.reshape(chunks, t // chunks, -1), targets.reshape(chunks, t // chunks)))
+        (hidden.reshape(chunks, t // chunks, -1),
+         *(a.reshape(chunks, t // chunks) for a in per_token)))
     return total / t
+
+
+NOISE_EPS = 1e-3  # the least masking probability of the linear schedule (LLaDA, arXiv:2502.09992)
+
+
+def draw_noise(rng: np.random.Generator, tokens: np.ndarray, block: int):
+    """Block diffusion's forward process for ``tokens [B, L]``, on the host:
+    per block of ``block`` tokens ``t ~ U(0, 1)`` and ``p = (1 - eps) t + eps``
+    (the linear schedule with ``eps`` = :data:`NOISE_EPS`; one ``t`` a block:
+    arXiv:2503.09573), each token of the block masked independently with
+    probability ``p``. -> ``{"noised" [B, L] bool, "p_mask" [B, L / block]}``:
+    the draw is part of the batch, so a step is a function of its batch."""
+    b, seq = tokens.shape
+    if seq % block:
+        raise ValueError(f"{seq} tokens are no whole blocks of {block}")
+    p = ((1.0 - NOISE_EPS) * rng.random((b, seq // block)) + NOISE_EPS).astype(np.float32)
+    return {"noised": rng.random((b, seq)) < np.repeat(p, block, axis=1), "p_mask": p}
+
+
+def diffusion_inputs(batch, mask_id: int, block: int):
+    """What the stack and the loss take under block diffusion: (ids ``[B,
+    2L]``, the noised copy (the mask id where ``noised``) then the clean
+    copy; each position's place in its own copy ``[2L]``, for rotary; the
+    loss's weights ``[B, L]``, ``1 / p`` of its block where ``noised``, else 0)."""
+    tokens, noised = batch["tokens"], batch["noised"]
+    with phase_scope("noise"):
+        ids = jnp.concatenate([jnp.where(noised, mask_id, tokens), tokens], axis=1)
+        positions = jnp.tile(jnp.arange(tokens.shape[1]), 2)
+        weights = noised / jnp.repeat(batch["p_mask"], block, axis=1)
+    return ids, positions, weights
 
 
 def make_optimizer(cfg: Config, lr: float):
@@ -116,14 +153,24 @@ class SeqLMTrainer(Trainer):
         self.epochs = cfg.get_int("num_iters", 1)
         self.seed = cfg.get_int("seed", 0)
         self.opt = make_optimizer(cfg, self.lr)
+        # block diffusion: a row is ``seq_len`` tokens and twice as many positions
+        self.block_length = cfg.get_int("block_length", 0)
         if corpus_ids is None:
             with self.span("load-data"):
                 corpus_ids, vocab_size = self._load_corpus(cfg)
         self.corpus_ids = np.asarray(corpus_ids, dtype=np.int32)
         self.vocab_size = int(vocab_size)
         self._read_shape(cfg)
+        if self.block_length:
+            self.mask_token_id = cfg.get_int("mask_token_id")
+            if not 0 <= self.mask_token_id < self.vocab_size:
+                raise ValueError(f"mask_token_id {self.mask_token_id} is no row of the vocabulary")
+            if (self.corpus_ids == self.mask_token_id).any():
+                raise ValueError(f"the corpus holds the mask's id {self.mask_token_id}")
 
     def _read_shape(self, cfg: Config) -> None:
+        if self.block_length:
+            raise ValueError("seqlm's own stack is causal: block diffusion runs under moelm")
         self.n_layers = cfg.get_int("n_layers", 2)
         self.n_heads = cfg.get_int("n_heads", 4)
         self.d_model = cfg.get_int("d_model", 128)
@@ -221,12 +268,13 @@ class SeqLMTrainer(Trainer):
             x = x + jax.nn.gelu(y @ blk["w1"]) @ blk["w2"]
         return _norm(x)
 
-    def loss_fn(self, params, tokens, state):
+    def loss_fn(self, params, batch, state):
         """(loss, aux): ``aux`` is handed to :meth:`after_update`."""
         del state
+        tokens = batch["tokens"]
         b, l = tokens.shape[0], tokens.shape[1] - 1
         x = self.hidden(params, tokens[:, :-1]).reshape(b * l, -1)
-        return next_token_loss(x, params["embed"].T, tokens[:, 1:].reshape(-1)), {}
+        return token_loss(x, params["embed"].T, tokens[:, 1:].reshape(-1)), {}
 
     def after_update(self, state, aux):
         """(state, metrics) once the gradient step is in ``state``: whatever a
@@ -238,21 +286,26 @@ class SeqLMTrainer(Trainer):
 
     def batches(self) -> Iterator[Dict[str, np.ndarray]]:
         ids = self.corpus_ids
-        # +1 so each window has seq_len inputs and shifted targets
-        window = self.seq_len + 1
+        # +1 so each window has seq_len inputs and shifted targets; block
+        # diffusion's targets are the inputs' own places
+        window = self.seq_len + (0 if self.block_length else 1)
         n_windows = len(ids) // window
         rng = np.random.default_rng(self.seed)
+        noise_rng = np.random.default_rng([self.seed, 1])  # the order stays the seed's alone
         for _ in range(self.epochs):
             order = rng.permutation(n_windows)
             for start in range(0, n_windows - self.batch_size + 1, self.batch_size):
                 idx = order[start : start + self.batch_size]
                 toks = np.stack([ids[i * window : (i + 1) * window] for i in idx])
-                yield {"tokens": toks.astype(np.int32)}
+                batch = {"tokens": toks.astype(np.int32)}
+                if self.block_length:
+                    batch.update(draw_noise(noise_rng, toks, self.block_length))
+                yield batch
 
     def train_step(self, state, batch, rng):
         del rng
         (loss, aux), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
-            state["params"], batch["tokens"], state)
+            state["params"], batch, state)
         with phase_scope("opt"):
             updates, opt = self.opt.update(grads, state["opt"], state["params"])
             params = optax.apply_updates(state["params"], updates)
@@ -260,4 +313,5 @@ class SeqLMTrainer(Trainer):
         return state, {"loss": loss, **metrics}
 
     def items_per_batch(self, batch) -> int:
-        return int(batch["tokens"].shape[0] * (batch["tokens"].shape[1] - 1))
+        rows, width = batch["tokens"].shape
+        return int(rows * (width if self.block_length else width - 1))

@@ -265,8 +265,8 @@ def test_bfloat16_operands_stay_close_and_the_step_is_scoped():
     rounded, _ = _trainer(matmul_dtype="bfloat16")
     state = exact.init_state()
     tokens = jnp.asarray(next(iter(exact.batches()))["tokens"])
-    want, _ = jax.jit(exact.loss_fn)(state["params"], tokens, state)
-    got, _ = jax.jit(rounded.loss_fn)(state["params"], tokens, state)
+    want, _ = jax.jit(exact.loss_fn)(state["params"], {"tokens": tokens}, state)
+    got, _ = jax.jit(rounded.loss_fn)(state["params"], {"tokens": tokens}, state)
     assert float(got) == pytest.approx(float(want), rel=2e-3) and float(got) != float(want)
     text = jax.jit(rounded.train_step).lower(
         state, {"tokens": tokens}, jax.random.PRNGKey(0)).as_text(debug_info=True)

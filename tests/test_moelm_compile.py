@@ -1,5 +1,5 @@
-"""The kernels of the mixture-of-experts step (at Moonlight-16B-A3B's
-published widths) and the table plane's gather (at the Wide&Deep cell's
+"""The kernels of the mixture-of-experts step (at Moonlight-16B-A3B's and at
+SDAR-30B-A3B-Chat's published widths) and the table plane's gather (at the Wide&Deep cell's
 shapes) compile for a TPU v5e that is described and not attached: what
 interpret mode cannot show (tile alignment, VMEM, transposed products in
 Mosaic). Compile-only: nothing runs, and no time or result comes of it. The
@@ -16,8 +16,10 @@ import jax.numpy as jnp
 from swiftsnails_tpu.ops import flash_attention as fa
 from swiftsnails_tpu.ops import grouped_matmul as gm
 
-SEQ, HEADS, DK, DV = 8192, 16, 192, 128  # qk_nope 128 + qk_rope 64; v_head_dim 128
-HIDDEN, EXPERT_WIDTH, HELD, TOP_K = 2048, 1408, 8, 6
+SEQ, HIDDEN = 8192, 2048
+# (query heads, key/value heads, key width, value width, block-diffusion block, experts' width, held, a token)
+MOONLIGHT = (16, 16, 192, 128, None, 1408, 8, 6)  # qk_nope 128 + qk_rope 64; v_head_dim 128; causal
+SDAR = (32, 4, 128, 128, 4, 768, 16, 8)  # 8 query heads to a key/value head; 4,096 tokens in two copies
 
 
 @pytest.fixture(scope="module")
@@ -51,23 +53,31 @@ def _compiled(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-def test_attention_kernels_compile_at_published_widths(one_chip, no_cache):
+@pytest.mark.parametrize("widths", [MOONLIGHT, SDAR], ids=["moonlight-causal", "sdar-block-diffusion"])
+def test_attention_kernels_compile_at_published_widths(one_chip, no_cache, widths):
+    heads, kv_heads, dk, dv, diffusion_block = widths[:5]
+
     def loss(q, k, v, w):
-        return jnp.sum(fa.flash_attention(q, k, v, block=512, interpret=False) * w)
+        return jnp.sum(fa.flash_attention(q, k, v, block=512, interpret=False,
+                                          diffusion_block=diffusion_block) * w)
 
     f32 = jnp.float32
     compiled = _compiled(jax.grad(loss, (0, 1, 2)), one_chip,
-                         ((HEADS, SEQ, DK), f32), ((HEADS, SEQ, DK), f32),
-                         ((HEADS, SEQ, DV), f32), ((HEADS, SEQ, DV), f32))
+                         ((heads, SEQ, dk), f32), ((kv_heads, SEQ, dk), f32),
+                         ((kv_heads, SEQ, dv), f32), ((heads, SEQ, dv), f32))
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
         assert name in text and "tpu_custom_call" in text, name
-    # scores never leave VMEM: nothing the size of [heads, L, L] is allocated
-    assert compiled.memory_analysis().temp_size_in_bytes < HEADS * SEQ * SEQ * 4 / 4
+    # scores never leave VMEM, and keys and values are never copied out to the
+    # query heads: nothing the size of [heads, L, L] is allocated
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * SEQ * SEQ * 4 / 4
+    assert compiled.out_info[1].shape == (kv_heads, SEQ, dk)  # dk summed over the group in the kernel
 
 
-def test_grouped_products_compile_at_published_widths(one_chip, no_cache):
-    assignments = SEQ * TOP_K  # every token could choose six of the eight held
+@pytest.mark.parametrize("widths", [MOONLIGHT, SDAR], ids=["moonlight", "sdar"])
+def test_grouped_products_compile_at_published_widths(one_chip, no_cache, widths):
+    EXPERT_WIDTH, HELD, TOP_K = widths[5:]
+    assignments = SEQ * TOP_K  # every position could choose all of its experts among those held
     rows = gm.rows_for(assignments, HELD)
     assert rows == (assignments // gm.TILE + HELD) * gm.TILE
 
