@@ -38,8 +38,14 @@ gain and ``rms_norm_eps``):
   held of g_e E_e(y)`` + shared(y): what the experts on other chips would add
   is left out (the sum that normalises still runs over all that were chosen).
   Dropless: the held assignments are sorted by expert
-  (``ops/grouped_matmul.plan_rows``) and every one of them is computed,
-  under any skew; ``moe_dropped`` counts the ones that were not, and reads 0.
+  (``ops/grouped_matmul.plan_rows``) into a row layout with room for every
+  assignment of the step, and every one of them is computed, under any skew;
+  ``moe_dropped`` counts the ones that were not, and reads 0. The layout's
+  tiles that hold anything are the live ones (``moe_live_tile_share`` of
+  them): the moves and ``grouped_swiglu``'s kernels visit those and no
+  other, so between ``rows_of_tokens`` and ``tokens_of_rows`` the rows past
+  the live tiles are never written and never read, forward or backward, and
+  hold whatever the memory held.
 * **Balance** (``noaux_tc``): after the gradient step ``b_e += bias_update_rate
   * sign(mean(c) - c_e)``, ``c_e`` the step's tokens assigned to expert ``e``
   of that layer, all ``router_experts``; plus the sequence-wise loss
@@ -78,7 +84,7 @@ from swiftsnails_tpu.models.registry import register_model
 from swiftsnails_tpu.models.seqlm import SeqLMTrainer, diffusion_inputs, token_loss
 from swiftsnails_tpu.ops.flash_attention import BLOCK, flash_attention
 from swiftsnails_tpu.ops.grouped_matmul import (
-    TILE, grouped_matmul, plan_rows, rows_of_tokens, tokens_of_rows)
+    TILE, grouped_swiglu, plan_rows, rows_of_tokens, tokens_of_rows)
 from swiftsnails_tpu.utils.config import Config
 from swiftsnails_tpu.utils.profiling import phase_scope
 
@@ -299,7 +305,9 @@ class MoELMTrainer(SeqLMTrainer):
 
     def _experts(self, p, y, choices, gates):
         """(the held experts' part of the layer's output, assignments left
-        out: 0 by construction, counted all the same)."""
+        out: 0 by construction, counted all the same, the share of the row
+        layout's tiles that are live). Between the two moves every array of
+        the row layout is a kernel's, written and read for the live tiles only."""
         held, tile = self.experts_held, self.expert_tile
         local = choices - self.expert_offset
         owner = jnp.where((local >= 0) & (local < held), local, held)
@@ -307,11 +315,10 @@ class MoELMTrainer(SeqLMTrainer):
             plan = plan_rows(owner, held, tile)
             dropped = jnp.sum(owner < held, dtype=jnp.int32) - jnp.sum(
                 plan.source < owner.size, dtype=jnp.int32)
-        gm = functools.partial(grouped_matmul, plan=plan, tile=tile, dtype=self.matmul_dtype)
-        rows = rows_of_tokens(y, plan, tile)
-        hidden = jax.nn.silu(gm(rows, p["experts_gate"])) * gm(rows, p["experts_up"])
-        out = gm(hidden, p["experts_down"])
-        return tokens_of_rows(out, gates, plan, tile), dropped
+            live_share = plan.live_tiles / plan.tile_owner.shape[0]
+        out = grouped_swiglu(rows_of_tokens(y, plan, tile), p["experts_gate"], p["experts_up"],
+                             p["experts_down"], plan, tile, self.matmul_dtype)
+        return tokens_of_rows(out, gates, plan, tile), dropped, live_share
 
     def _dense_layer(self, x, p, b, positions=None):
         with phase_scope("attn"):
@@ -327,11 +334,11 @@ class MoELMTrainer(SeqLMTrainer):
             choices, gates, s = self.route(y, p["router"], bias)
             aux, counts = self._balance(s, choices, b)
         with phase_scope("experts"):
-            routed, dropped = self._experts(p, y, choices, gates)
+            routed, dropped, live_share = self._experts(p, y, choices, gates)
         with phase_scope("mlp"):
             shared = self._swiglu(p, "shared", y) if self.n_shared else 0.0
         return x + routed + shared, {"aux": aux, "counts": counts, "choices": choices,
-                                     "dropped": dropped}
+                                     "dropped": dropped, "live_tile_share": live_share}
 
     def stack(self, params, tokens, router_bias, positions=None):
         """(the stack's output after the last norm [B * L, d], what the
@@ -395,6 +402,7 @@ class MoELMTrainer(SeqLMTrainer):
             "moe_load_max_over_mean": jnp.mean(
                 jnp.max(held, axis=-1) / jnp.maximum(jnp.mean(held, axis=-1), 1.0)),
             "moe_dropped": state["dropped"],
+            "moe_live_tile_share": jnp.mean(aux["live_tile_share"]),
         }
         if self.block_length:
             state["noised"] = aux["noised"]

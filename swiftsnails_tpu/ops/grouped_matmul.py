@@ -11,27 +11,48 @@ past ``live_tiles`` do nothing and fetch nothing. Every expert gets at least
 one tile (all padding if it has no row), so that the weight gradient of an
 expert nobody chose is written as zeros, not left as it was.
 
-* :func:`grouped_matmul` — ``x [R, K]``, ``w [E, K, N]`` -> ``[R, N]`` float32
-  (``y[r] = x[r] @ w[tile_owner[r // tile]]``), differentiable in ``x`` and
-  ``w`` (``custom_vjp``): ``dx`` is the same kernel on the transposed weight
-  blocks, ``dw[e]`` sums ``x_tile.T @ dy_tile`` over the expert's tiles. Padding
-  rows of ``x`` must be zero (then they add nothing to ``dw``); the rows past
-  the live tiles, which no kernel writes, come back as zeros.
+* :func:`grouped_swiglu` — the held experts' whole feed-forward, ``(silu(x @
+  w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` with ``e`` the tile's owner,
+  differentiable in the rows and the three weights (``custom_vjp``). Every
+  array of the row layout between its argument and its result is written and
+  read by a kernel whose grid step does nothing past the live tiles: the
+  rounding of an operand, SwiGLU, its derivative and the sum of the two
+  products that make ``dx`` happen on a tile in VMEM. Forward: one kernel for
+  the gate and up products and SwiGLU (it keeps the two float32 products
+  where the backward pass follows), one for the down product. Backward: ``dy
+  @ w_down.T`` with SwiGLU's derivative behind it (``dg``, ``du``), ``dg @
+  w_gate.T + du @ w_up.T`` in one kernel, and ``dw[e]``, the sum of ``x_tile.T
+  @ dy_tile`` over the expert's tiles, three times. **What the rows past the
+  live tiles hold is unspecified**, in the result, in every intermediate and
+  in the rows' gradient: no kernel writes them, nothing reads them (the moves
+  stop at the live tiles too), and XLA walks no array of the layout's size.
+  Padding rows INSIDE a live tile must be zero in ``rows`` (the move fills
+  them); then they add nothing to ``dw``.
+* :func:`grouped_matmul` — one product, ``x [R, K]``, ``w [E, K, N]`` -> ``[R,
+  N]`` float32 (``y[r] = x[r] @ w[tile_owner[r // tile]]``), differentiable in
+  ``x`` and ``w``, out of the same kernels; its result and its ``dx`` are
+  zeros past the live tiles (an XLA pass over the layout: the tests'
+  reference for the fused form, on no model's path).
 * :func:`plan_rows` — from each assignment's expert to the row layout.
 * :func:`rows_of_tokens`, :func:`tokens_of_rows` — the moves between tokens and
   rows, forward and backward: loops over the live tiles only, a tile's rows
   gathered or added at a time, so that their time follows the assignments
-  held like the kernels' (XLA's gather costs a fixed time a row).
+  held like the kernels' (XLA's gather costs a fixed time a row). A move
+  towards the rows fills the live tiles of a buffer nobody zeroed; a move
+  towards the tokens adds a row as whole ``(8, 128)`` tiles.
 * :func:`grouped_flops` — operations per call, for the benchmark's roofline.
 
-Operands are rounded to ``dtype`` (bfloat16) for the MXU, accumulation and
-every result are float32. Off the chip the kernels run in interpret mode (``rowdma.on_tpu``).
+Operands are rounded to ``dtype`` (bfloat16) for the MXU, each once, in the
+kernel that multiplies it; accumulation, ``silu``, its derivative and every
+product's result are float32. Every ``pallas_call`` here has ``grouped_matmul``
+in its name: the benchmark finds the expert kernels by it. Off the chip the
+kernels run in interpret mode (``rowdma.on_tpu``).
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -106,11 +127,40 @@ def plan_rows(owner: jax.Array, experts: int, tile: int = TILE) -> RowPlan:
 # towards the rows a tile is gathered, towards the tokens it is added. A
 # token has one row at most in a tile (a tile has one expert), and padding
 # carries an index one past the end, which reads as zeros and is dropped
-# when written.
+# when written. A loop towards the rows starts from a buffer nobody filled
+# (:func:`_unfilled`) and writes its live tiles: the rows past them are
+# whatever was there.
 
 
 def _tile_of(a, t, tile):
     return jax.lax.dynamic_slice_in_dim(a, t * tile, tile)
+
+
+def _unfilled(shape, dtype, after):
+    """``shape`` of whatever the memory held: the result of a kernel that
+    writes nothing, which runs once ``after`` (a scalar) is there and, having
+    side effects, is never merged with its like (two loops would then share a
+    buffer, and XLA copies it for the second). (``lax.empty`` is the same
+    buffer with no operand: XLA allocates all fifteen of a step's at the
+    program's start and holds them, 5.8 GB of Moonlight's step, which then no
+    longer fits the chip.)"""
+    if not on_tpu():
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(
+        lambda after_ref, o_ref: None,
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(shape, dtype),
+        name="grouped_matmul_unfilled_rows",
+        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+    )(after.reshape(1))
+
+
+def _slabs(a):
+    """``[n, d]`` as ``[n, d / 128, 128]`` where ``d`` allows it: XLA's
+    scatter-add then moves a row as whole ``(8, 128)`` tiles, 55 ns a row of
+    2,048 for 96 on the chip (its gather reads 42 either way)."""
+    return a.reshape(a.shape[0], -1, 128) if a.shape[1] % 128 == 0 else a
 
 
 def _take(table, index):
@@ -125,7 +175,7 @@ def _rows_of_tokens(y, token, live_tiles, tile):
             rows, _take(y, _tile_of(token, t, tile)), t * tile, 0)
 
     return jax.lax.fori_loop(
-        0, live_tiles, gather, jnp.zeros((token.shape[0], y.shape[1]), y.dtype))
+        0, live_tiles, gather, _unfilled((token.shape[0], y.shape[1]), y.dtype, live_tiles))
 
 
 def _rows_fwd(y, token, live_tiles, tile):
@@ -137,18 +187,21 @@ def _rows_bwd(tile, res, g):
     token, live_tiles, like = res
 
     def add(t, dy):
-        return dy.at[_tile_of(token, t, tile)].add(_tile_of(g, t, tile), mode="drop")
+        return dy.at[_tile_of(token, t, tile)].add(_slabs(_tile_of(g, t, tile)), mode="drop")
 
-    dy = jax.lax.fori_loop(0, live_tiles, add, jnp.zeros((like.shape[0], g.shape[1]), g.dtype))
-    return dy, None, None
+    shape = (like.shape[0], g.shape[1])
+    dy = jax.lax.fori_loop(0, live_tiles, add, _slabs(jnp.zeros(shape, g.dtype)))
+    return dy.reshape(shape), None, None
 
 
 _rows_of_tokens.defvjp(_rows_fwd, _rows_bwd)
 
 
 def rows_of_tokens(y, plan: RowPlan, tile: int = TILE):
-    """``y [T, d]`` -> ``[R, d]``: row ``r`` is token ``plan.token[r]``, zeros
-    where it is padding or past the live tiles."""
+    """``y [T, d]`` -> ``[R, d]``: row ``r`` of a live tile is token
+    ``plan.token[r]``, or zeros where it is padding; the rows past the live
+    tiles are not written (unspecified), and neither is their part of
+    :func:`tokens_of_rows`' gradient."""
     return _rows_of_tokens(y, plan.token, plan.live_tiles, tile)
 
 
@@ -160,9 +213,11 @@ def _tokens_of_rows(rows, gates, source, live_tiles, tile):
     def add(t, out):
         src = _tile_of(source, t, tile)
         weighted = _tile_of(rows, t, tile) * _take(flat, src)[:, None]
-        return out.at[src // k].add(weighted, mode="drop")
+        return out.at[src // k].add(_slabs(weighted), mode="drop")
 
-    return jax.lax.fori_loop(0, live_tiles, add, jnp.zeros((tokens, rows.shape[1]), rows.dtype))
+    shape = (tokens, rows.shape[1])
+    return jax.lax.fori_loop(
+        0, live_tiles, add, _slabs(jnp.zeros(shape, rows.dtype))).reshape(shape)
 
 
 def _tokens_fwd(rows, gates, source, live_tiles, tile):
@@ -184,7 +239,7 @@ def _tokens_bwd(tile, res, g):
         return d_rows, d_flat
 
     d_rows, d_flat = jax.lax.fori_loop(
-        0, live_tiles, back, (jnp.zeros_like(rows), jnp.zeros_like(flat)))
+        0, live_tiles, back, (_unfilled(rows.shape, rows.dtype, live_tiles), jnp.zeros_like(flat)))
     return d_rows, d_flat.reshape(gates.shape), None, None
 
 
@@ -198,6 +253,11 @@ def tokens_of_rows(rows, gates, plan: RowPlan, tile: int = TILE):
 
 
 # ------------------------------------------------------------ kernels ---
+# One grid step a tile of the layout; ``tile_owner`` and ``live_tiles`` are
+# prefetched scalars. A step past the live tiles does nothing and its blocks
+# are the last live tile's again (nothing is fetched, nothing written back),
+# so a kernel's time follows the live tiles and the rows past them are never
+# read and never written. Operands are rounded to ``dtype`` in VMEM.
 
 
 def _last_live(t, live):
@@ -206,16 +266,53 @@ def _last_live(t, live):
     return jnp.minimum(t, live[0] - 1)
 
 
-def _mm_kernel(owner_ref, live_ref, x_ref, w_ref, o_ref, *, dims):
+def _mm_kernel(owner_ref, live_ref, *refs, dims, dtype):
+    """``o = x_1 . w_1 + x_2 . w_2 + ...``: ``refs`` are the ``x``, the ``w``, ``o``."""
+    del owner_ref
+    n = len(refs) // 2
+    o_ref = refs[-1]
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        first, *more = [
+            jax.lax.dot_general(x_ref[...].astype(dtype), w_ref[...], dims,
+                                preferred_element_type=jnp.float32)
+            for x_ref, w_ref in zip(refs[:n], refs[n:])]
+        o_ref[...] = sum(more, first)
+
+
+def _swiglu_kernel(owner_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref, *kept_refs, dtype):
+    """``h = silu(x . w_gate) * (x . w_up)`` rounded to ``dtype``; where the
+    backward pass is going to read them, the two products (float32) and the
+    rounded ``x`` too."""
     del owner_ref
 
     @pl.when(pl.program_id(0) < live_ref[0])
     def _():
-        o_ref[...] = jax.lax.dot_general(
-            x_ref[...], w_ref[...], dims, preferred_element_type=jnp.float32)
+        x = x_ref[...].astype(dtype)
+        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+        h_ref[...] = (jax.nn.silu(g) * u).astype(dtype)
+        for ref, value in zip(kept_refs, (g, u, x)):
+            ref[...] = value
 
 
-def _dw_kernel(owner_ref, live_ref, x_ref, dy_ref, o_ref):
+def _dswiglu_kernel(owner_ref, live_ref, dy_ref, g_ref, u_ref, wd_ref, dg_ref, du_ref, *, dtype):
+    """``dh = dy . w_down.T``, then SwiGLU's derivative on it: ``dg = dh * u *
+    silu'(g)`` and ``du = dh * silu(g)``, each rounded to ``dtype``."""
+    del owner_ref
+
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        dh = jax.lax.dot_general(dy_ref[...].astype(dtype), wd_ref[...], _TRANS_B,
+                                 preferred_element_type=jnp.float32)
+        g, u = g_ref[...], u_ref[...]
+        s = jax.nn.sigmoid(g)
+        dg_ref[...] = (dh * u * (s * (1.0 + g * (1.0 - s)))).astype(dtype)
+        du_ref[...] = (dh * (g * s)).astype(dtype)
+
+
+def _dw_kernel(owner_ref, live_ref, x_ref, dy_ref, o_ref, *, dtype):
     t = pl.program_id(2)
     live = t < live_ref[0]
     first = jnp.logical_or(t == 0, owner_ref[t] != owner_ref[jnp.maximum(t - 1, 0)])
@@ -227,7 +324,8 @@ def _dw_kernel(owner_ref, live_ref, x_ref, dy_ref, o_ref):
     @pl.when(live)
     def _():
         o_ref[...] += jax.lax.dot_general(
-            x_ref[...], dy_ref[...], _TRANS_A, preferred_element_type=jnp.float32)
+            x_ref[...].astype(dtype), dy_ref[...].astype(dtype), _TRANS_A,
+            preferred_element_type=jnp.float32)
 
 
 def _params(interpret, semantics):
@@ -237,29 +335,53 @@ def _params(interpret, semantics):
         dimension_semantics=semantics, vmem_limit_bytes=_VMEM_LIMIT)}
 
 
-def _mm(x, w, tile_owner, live_tiles, tile, transposed, interpret):
-    """``x [R, K] @ w[owner]`` with ``w [E, K, N]``, or, ``transposed``,
-    ``x [R, N] @ w[owner].T``."""
-    rows, width = x.shape
-    e, k, n = w.shape
-    out = k if transposed else n
-    assert width == (n if transposed else k) and rows % tile == 0
+class _Tiles(NamedTuple):
+    """What every kernel call of a layer shares."""
+
+    owner: jax.Array
+    live: jax.Array
+    tile: int
+    dtype: Any
+    interpret: bool
+
+
+def _by_tile(kernel, name, tiles: _Tiles, rows, weights, outs):
+    """``kernel`` over the live tiles: ``rows`` (``[R, *]`` each) and the
+    results (``outs``: a ``(width, dtype)`` each, ``[R, width]``) go by whole
+    tiles, ``weights`` (``[E, K, N]`` each) by the block of the tile's owner."""
+    n_rows = rows[0].shape[0]
+    assert n_rows % tiles.tile == 0 and all(r.shape[0] == n_rows for r in rows)
+
+    def row_spec(width):
+        return pl.BlockSpec((tiles.tile, width), lambda t, own, live: (_last_live(t, live), 0))
+
+    def weight_spec(w):
+        return pl.BlockSpec((None,) + w.shape[1:],
+                            lambda t, own, live: (own[_last_live(t, live)], 0, 0))
 
     return pl.pallas_call(
-        functools.partial(_mm_kernel, dims=_TRANS_B if transposed else (((1,), (0,)), ((), ()))),
+        functools.partial(kernel, dtype=tiles.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(rows // tile,),
-            in_specs=[
-                pl.BlockSpec((tile, width), lambda t, own, live: (_last_live(t, live), 0)),
-                pl.BlockSpec((None, k, n), lambda t, own, live: (own[_last_live(t, live)], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((tile, out), lambda t, own, live: (_last_live(t, live), 0)),
+            grid=(n_rows // tiles.tile,),
+            in_specs=[row_spec(r.shape[1]) for r in rows] + [weight_spec(w) for w in weights],
+            out_specs=[row_spec(width) for width, _ in outs],
         ),
-        out_shape=jax.ShapeDtypeStruct((rows, out), jnp.float32),
-        name="grouped_matmul_dx" if transposed else "grouped_matmul",
-        **_params(interpret, ("arbitrary",)),
-    )(tile_owner, live_tiles.reshape(1), x, w)
+        out_shape=[jax.ShapeDtypeStruct((n_rows, width), dt) for width, dt in outs],
+        name=name,
+        **_params(tiles.interpret, ("arbitrary",)),
+    )(tiles.owner, tiles.live.reshape(1), *rows, *weights)
+
+
+def _mm(xs, ws, tiles: _Tiles, transposed=False):
+    """``sum_i xs[i] [R, K] @ ws[i][owner]`` with ``ws[i] [E, K, N]`` (already
+    in ``dtype``), or, ``transposed``, ``sum_i xs[i] [R, N] @ ws[i][owner].T``."""
+    k, n = ws[0].shape[1:]
+    assert all(x.shape[1] == (n if transposed else k) for x in xs)
+    dims = _TRANS_B if transposed else (((1,), (0,)), ((), ()))
+    return _by_tile(functools.partial(_mm_kernel, dims=dims),
+                    "grouped_matmul_dx" if transposed else "grouped_matmul",
+                    tiles, xs, ws, [(k if transposed else n, jnp.float32)])[0]
 
 
 def _split(width: int, most: int) -> int:
@@ -270,16 +392,17 @@ def _split(width: int, most: int) -> int:
     return max(b for b in range(128, most + 1, 128) if width % b == 0)
 
 
-def _dw(x, dy, tile_owner, live_tiles, experts, tile, interpret):
+def _dw(x, dy, experts, tiles: _Tiles):
     """``dw [E, K, N]``: per expert, ``x_tile.T @ dy_tile`` summed over its
     tiles. The result is cut along whichever of K and N is the wider, so
     that a block stays resident while the expert's tiles go by."""
     rows, k = x.shape
     n = dy.shape[1]
+    tile = tiles.tile
     bk, bn = (_split(k, 512), n) if k >= n else (k, _split(n, 512))
 
     return pl.pallas_call(
-        _dw_kernel,
+        functools.partial(_dw_kernel, dtype=tiles.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(k // bk, n // bn, rows // tile),
@@ -292,35 +415,45 @@ def _dw(x, dy, tile_owner, live_tiles, experts, tile, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((experts, k, n), jnp.float32),
         name="grouped_matmul_dw",
-        **_params(interpret, ("parallel", "parallel", "arbitrary")),
-    )(tile_owner, live_tiles.reshape(1), x, dy)
+        **_params(tiles.interpret, ("parallel", "parallel", "arbitrary")),
+    )(tiles.owner, tiles.live.reshape(1), x, dy)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _gmm(x, w, tile_owner, live_tiles, tile, dtype, interpret):
-    return _mm(x.astype(dtype), w.astype(dtype), tile_owner, live_tiles, tile, False, interpret)
+def _tiles(plan: RowPlan, tile, dtype, interpret):
+    """(the plan's two scalar operands, what is static), as the ``custom_vjp``
+    functions take them; together they are a :class:`_Tiles`."""
+    interpret = (not on_tpu()) if interpret is None else interpret
+    return (plan.tile_owner, plan.live_tiles), (tile, jnp.dtype(dtype), interpret)
 
 
-def _gmm_fwd(x, w, tile_owner, live_tiles, tile, dtype, interpret):
-    x, w = x.astype(dtype), w.astype(dtype)
-    y = _mm(x, w, tile_owner, live_tiles, tile, False, interpret)
-    return y, (x, w, tile_owner, live_tiles)
+# ---------------------------------------------------------- one product ---
 
 
-def _gmm_bwd(tile, dtype, interpret, res, dy):
-    x, w, tile_owner, live_tiles = res
-    dy = dy.astype(dtype)
-    dx = _mm(dy, w, tile_owner, live_tiles, tile, True, interpret)
-    dw = _dw(x, dy, tile_owner, live_tiles, w.shape[0], tile, interpret)
-    return _live_rows(dx, live_tiles, tile), dw, None, None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _gmm(x, w, scalars, static):
+    tiles = _Tiles(*scalars, *static)
+    return _mm([x], [w.astype(tiles.dtype)], tiles)
+
+
+def _gmm_fwd(x, w, scalars, static):
+    tiles = _Tiles(*scalars, *static)
+    w = w.astype(tiles.dtype)
+    return _mm([x], [w], tiles), (x, w, scalars)
+
+
+def _gmm_bwd(static, res, dy):
+    x, w, scalars = res
+    tiles = _Tiles(*scalars, *static)
+    dx = _mm([dy], [w], tiles, transposed=True)
+    return _live_rows(dx, tiles), _dw(x, dy, w.shape[0], tiles), None
 
 
 _gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-def _live_rows(y, live_tiles, tile):
+def _live_rows(y, tiles: _Tiles):
     """Zeros in the rows past the live tiles, which no kernel wrote."""
-    live = jnp.arange(y.shape[0], dtype=jnp.int32) < live_tiles * tile
+    live = jnp.arange(y.shape[0], dtype=jnp.int32) < tiles.live * tiles.tile
     return jnp.where(live[:, None], y, 0)
 
 
@@ -328,7 +461,60 @@ def grouped_matmul(x, w, plan: RowPlan, tile: int = TILE, dtype=jnp.bfloat16, in
     """``y[r] = x[r] @ w[plan.tile_owner[r // tile]]`` over the live tiles,
     zeros after them; float32 in and out, the operands rounded to ``dtype``
     for the MXU."""
-    if interpret is None:
-        interpret = not on_tpu()
-    y = _gmm(x, w, plan.tile_owner, plan.live_tiles, tile, jnp.dtype(dtype), interpret)
-    return _live_rows(y, plan.live_tiles, tile)
+    scalars, static = _tiles(plan, tile, dtype, interpret)
+    return _live_rows(_gmm(x, w, scalars, static), _Tiles(*scalars, *static))
+
+
+# ------------------------------------------------ an expert's whole SwiGLU ---
+
+
+def _swiglu(x, wg, wu, tiles: _Tiles, keep: bool):
+    """(hidden in ``dtype``,) or, ``keep``, (hidden, gate, up: the two float32,
+    ``x`` in ``dtype``)."""
+    width = wg.shape[2]
+    outs = [(width, tiles.dtype)]
+    if keep:
+        outs += [(width, jnp.float32)] * 2 + [(x.shape[1], tiles.dtype)]
+    return _by_tile(_swiglu_kernel, "grouped_matmul_swiglu", tiles, [x], [wg, wu], outs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _gsw(x, wg, wu, wd, scalars, static):
+    tiles = _Tiles(*scalars, *static)
+    (hidden,) = _swiglu(x, wg.astype(tiles.dtype), wu.astype(tiles.dtype), tiles, keep=False)
+    return _mm([hidden], [wd.astype(tiles.dtype)], tiles)
+
+
+def _gsw_fwd(x, wg, wu, wd, scalars, static):
+    tiles = _Tiles(*scalars, *static)
+    wg, wu, wd = (w.astype(tiles.dtype) for w in (wg, wu, wd))
+    hidden, g, u, x = _swiglu(x, wg, wu, tiles, keep=True)
+    return _mm([hidden], [wd], tiles), (x, wg, wu, wd, hidden, g, u, scalars)
+
+
+def _gsw_bwd(static, res, dy):
+    x, wg, wu, wd, hidden, g, u, scalars = res
+    tiles = _Tiles(*scalars, *static)
+    experts, _, width = wg.shape
+    dg, du = _by_tile(_dswiglu_kernel, "grouped_matmul_dswiglu", tiles, [dy, g, u], [wd],
+                      [(width, tiles.dtype)] * 2)
+    dx = _mm([dg, du], [wg, wu], tiles, transposed=True)
+    return (dx, _dw(x, dg, experts, tiles), _dw(x, du, experts, tiles),
+            _dw(hidden, dy, experts, tiles), None)
+
+
+_gsw.defvjp(_gsw_fwd, _gsw_bwd)
+
+
+def grouped_swiglu(rows, w_gate, w_up, w_down, plan: RowPlan, tile: int = TILE,
+                   dtype=jnp.bfloat16, interpret=None):
+    """The held experts' feed-forward on their rows: ``(silu(x @ w_gate[e]) *
+    (x @ w_up[e])) @ w_down[e]`` with ``e = plan.tile_owner[r // tile]``, for
+    the rows of the live tiles; ``rows [R, K]`` float32, ``w_gate``, ``w_up``
+    ``[E, K, N]``, ``w_down [E, N, K]`` -> ``[R, K]`` float32. Differentiable
+    in all four. Nothing reads a row past the live tiles and nothing writes
+    one: what the result and the rows' gradient hold there is unspecified.
+    The operands of the five products (the rows, the hidden rows, and in the
+    backward pass ``dy``, ``dg``, ``du``) are rounded to ``dtype``; the
+    products' results, ``silu`` and its derivative are float32."""
+    return _gsw(rows, w_gate, w_up, w_down, *_tiles(plan, tile, dtype, interpret))

@@ -3,6 +3,7 @@ against the benchmark's plain float32 reference
 (``benchmark/models/moonlight.py``), small and on the CPU: the kernels run in
 interpret mode, matrix operands stay float32 so that the two agree closely."""
 
+import functools
 import importlib.util
 import os
 import sys
@@ -17,7 +18,7 @@ from swiftsnails_tpu.framework.trainer import TrainLoop
 from swiftsnails_tpu.models.moelm import MoELMTrainer
 from swiftsnails_tpu.ops.flash_attention import attention_flops, flash_attention
 from swiftsnails_tpu.ops.grouped_matmul import (
-    grouped_matmul, plan_rows, rows_for, rows_of_tokens, tokens_of_rows)
+    grouped_matmul, grouped_swiglu, plan_rows, rows_for, rows_of_tokens, tokens_of_rows)
 from swiftsnails_tpu.parallel.mesh import SEQ_AXIS, make_mesh
 from swiftsnails_tpu.parallel.sequence import reference_attention, ring_attention
 from swiftsnails_tpu.utils.config import Config
@@ -116,7 +117,7 @@ def test_train_steps_match_reference(moonlight):
         if i == 0:
             grad1 = {k: float(jnp.sum(v * v)) / (1 - 0.9) ** 2
                      for k, v in moonlight._flatten(state["opt"][0].mu).items()}
-        assert int(m["moe_dropped"]) == 0
+        assert int(m["moe_dropped"]) == 0 and 0 < float(m["moe_live_tile_share"]) <= 1
     np.testing.assert_allclose(losses, ref["loss"], rtol=2e-5)
     for k, want in ref["grad1"].items():
         assert grad1[k] == pytest.approx(want, rel=2e-3, abs=1e-12), k
@@ -145,8 +146,8 @@ def test_eight_shares_add_up_to_the_uncut_layer(moonlight):
         tr, _ = _trainer(batch_size=1, experts_held=2, expert_offset=2 * share)
         mine = {k: (v[2 * share: 2 * share + 2] if k.startswith("experts_") else v)
                 for k, v in p.items()}
-        routed, dropped = tr._experts(mine, y, choices, gates)
-        assert int(dropped) == 0
+        routed, dropped, live_share = tr._experts(mine, y, choices, gates)
+        assert int(dropped) == 0 and 0 < float(live_share) <= 1
         total = total + routed
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)
 
@@ -158,7 +159,9 @@ def test_dropless_when_every_token_goes_to_one_held_expert():
     tokens = y.shape[0]
     choices = jnp.tile(jnp.asarray([[5, 0, 15]], jnp.int32), (tokens, 1))  # only 5 is held (4..7)
     gates = jnp.full((tokens, 3), 0.5)
-    out, dropped = tr._experts(p, y, choices, gates)
+    out, dropped, live_share = tr._experts(p, y, choices, gates)
+    # the one expert's 64 rows in 8 tiles of 8, a tile of padding for each of the other three
+    assert float(live_share) == pytest.approx(11 / (rows_for(tokens * 3, 4, 8) // 8))
     e = 5 - tr.expert_offset
     want = 0.5 * (jax.nn.silu(y @ p["experts_gate"][e]) * (y @ p["experts_up"][e])) @ p["experts_down"][e]
     assert int(dropped) == 0
@@ -170,18 +173,20 @@ def test_dropless_when_every_token_goes_to_one_held_expert():
     assert int((plan.source < tokens * 3).sum()) == tokens * 3 <= rows_for(tokens * 3, 4, 8)
 
 
-def test_rows_and_tokens_are_each_others_transpose():
+@pytest.mark.parametrize("d", [5, 256])
+def test_rows_and_tokens_are_each_others_transpose(d):
     """The moves loop over the live tiles, forward and backward, and leave
-    the rows past them alone; their vjps are exact."""
+    the rows past them alone (unwritten: whatever the buffer held); their
+    vjps are exact. A width of whole lanes is added to the tokens as slabs."""
     rng = np.random.default_rng(0)
     owner = jnp.asarray(rng.integers(0, 6, (20, 2)).clip(max=4), jnp.int32)
     plan = plan_rows(owner, 4, 8)
     rows = rows_for(40, 4, 8)
     row_of = np.full(40, rows)  # the row of each assignment, from the plan's other direction
     row_of[np.asarray(plan.source)[np.asarray(plan.source) < 40]] = np.flatnonzero(np.asarray(plan.source) < 40)
-    y = jnp.asarray(rng.normal(size=(20, 5)), jnp.float32)
+    y = jnp.asarray(rng.normal(size=(20, d)), jnp.float32)
     gates = jnp.asarray(rng.normal(size=(20, 2)), jnp.float32)
-    wr = jnp.asarray(rng.normal(size=(rows, 5)), jnp.float32)
+    wr = jnp.asarray(rng.normal(size=(rows, d)), jnp.float32)
 
     def fast(y, gates):
         return jnp.sum(tokens_of_rows(rows_of_tokens(y, plan, 8) * wr, gates, plan, 8) ** 2)
@@ -189,7 +194,7 @@ def test_rows_and_tokens_are_each_others_transpose():
     def plain(y, gates):
         held = owner < 4
         each = jnp.repeat(y, 2, axis=0) * wr[np.minimum(row_of, rows - 1)]
-        return jnp.sum(jnp.einsum("tk,tkd->td", gates * held, each.reshape(20, 2, 5)) ** 2)
+        return jnp.sum(jnp.einsum("tk,tkd->td", gates * held, each.reshape(20, 2, d)) ** 2)
 
     assert float(fast(y, gates)) == pytest.approx(float(plain(y, gates)), rel=1e-5)
     for a, b in zip(jax.grad(fast, (0, 1))(y, gates), jax.grad(plain, (0, 1))(y, gates)):
@@ -198,8 +203,10 @@ def test_rows_and_tokens_are_each_others_transpose():
     np.testing.assert_array_equal(row_of < rows, np.asarray(owner).reshape(-1) < 4)
     assert row_of[row_of < rows].max() < int(plan.live_tiles) * 8
     np.testing.assert_array_equal(np.asarray(plan.token), np.minimum(np.asarray(plan.source) // 2, 20))
-    moved = np.asarray(rows_of_tokens(y, plan, 8))
-    assert not moved[int(plan.live_tiles) * 8:].any()
+    moved = np.asarray(rows_of_tokens(y, plan, 8))[: int(plan.live_tiles) * 8]
+    padding = np.asarray(plan.token)[: len(moved)] == 20
+    assert padding.any() and not moved[padding].any()  # padding inside a live tile is zeros
+    np.testing.assert_array_equal(moved[~padding], np.asarray(y)[np.asarray(plan.token)[: len(moved)][~padding]])
 
 
 @pytest.mark.parametrize("case", ["spread", "one_expert", "none_held"])
@@ -225,6 +232,74 @@ def test_grouped_matmul_and_its_gradients(case):
     assert float(fast(x, w)) == pytest.approx(float(plain(x, w)), rel=1e-5, abs=1e-5)
     for got, want in zip(jax.grad(fast, (0, 1))(x, w), jax.grad(plain, (0, 1))(x, w)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("case", ["spread", "one_expert", "none_held", "bfloat16", "nan_past_live"])
+def test_grouped_swiglu_and_its_gradients(case):
+    """The fused feed-forward of the held experts against the plain ``einsum``
+    form and against the three public products with SwiGLU between them:
+    value and the gradients of the rows and the three weights. Nothing reads
+    the rows past the live tiles: filled with NaN in every input (the rows
+    and, in the backward pass, the result's cotangent) they change nothing."""
+    rng = np.random.default_rng(2)
+    e, tile, k, n, a = 4, 16, 32, 48, 100
+    owner = {"one_expert": np.full(a, 2), "none_held": np.full(a, e)}.get(
+        case, rng.integers(0, e + 3, a).clip(max=e)).astype(np.int32)
+    dtype = jnp.bfloat16 if case == "bfloat16" else jnp.float32
+    plan = plan_rows(jnp.asarray(owner)[:, None], e, tile)
+    rows = rows_for(a, e, tile)
+    live = (jnp.arange(rows) < plan.live_tiles * tile)[:, None]
+    assert rows > int(plan.live_tiles) * tile  # there are rows past the live tiles
+    held = np.asarray(plan.source) < a
+    row_of = np.zeros(a, np.int64)  # an assignment that is not held reads any row: masked below
+    row_of[np.asarray(plan.source)[held]] = np.flatnonzero(held)
+    shapes = ((a, k), (e, k, n), (e, k, n), (e, n, k), (a, k))
+    x, wg, wu, wd, g = (jnp.asarray(rng.normal(size=s) * 0.3, jnp.float32) for s in shapes)
+    mask = jnp.asarray(owner < e)[:, None]
+
+    @jax.custom_vjp
+    def poison(y):  # the cotangent's rows past the live tiles become NaN
+        return y
+
+    poison.defvjp(lambda y: (y, None), lambda _, ct: (jnp.where(live, ct, jnp.nan),))
+
+    def on_rows(feed_forward, nan=False):
+        def loss(x, wg, wu, wd):
+            xr = jnp.where((plan.source < a)[:, None], x[jnp.minimum(plan.source, a - 1)], 0)
+            if nan:
+                xr = jnp.where(live, xr, jnp.nan)
+            y = feed_forward(xr, wg, wu, wd)
+            y = jnp.where(live, poison(y) if nan else y, 0)
+            return jnp.sum(jnp.where(mask, y[row_of], 0) * g)
+        return loss
+
+    def fused(dt):
+        return lambda xr, *w: grouped_swiglu(xr, *w, plan, tile=tile, dtype=dt)
+
+    def composed(dt):
+        gm = functools.partial(grouped_matmul, plan=plan, tile=tile, dtype=dt)
+        return lambda xr, wg, wu, wd: gm(jax.nn.silu(gm(xr, wg)) * gm(xr, wu), wd)
+
+    def plain(x, wg, wu, wd):
+        own = np.minimum(owner, e - 1)
+        hidden = jax.nn.silu(jnp.einsum("ak,akn->an", x, wg[own])) * jnp.einsum("ak,akn->an", x, wu[own])
+        return jnp.sum(jnp.where(mask, jnp.einsum("an,ank->ak", hidden, wd[own]), 0) * g)
+
+    def both(f):
+        value, grads = jax.value_and_grad(f, (0, 1, 2, 3))(x, wg, wu, wd)
+        return [np.asarray(value)] + [np.asarray(t) for t in grads]
+
+    got = both(on_rows(fused(dtype), nan=case == "nan_past_live"))
+    assert all(np.isfinite(t).all() for t in got)
+    # the same rounding points as the three products composed: equal but for the order of sums
+    for have, want in zip(got, both(on_rows(composed(dtype)))):
+        np.testing.assert_allclose(have, want, rtol=1e-5, atol=1e-5)
+    # and against the plain form: float32 exactly that, bfloat16 operands a rounding away
+    close = dict(rtol=1e-4, atol=1e-4) if dtype == jnp.float32 else dict(rtol=0.1, atol=0.05)
+    for have, want in zip(got, both(plain)):
+        np.testing.assert_allclose(have, want, **close)
+    if case == "none_held":
+        assert not any(t.any() for t in got)
 
 
 @pytest.mark.parametrize("kernel", ["flash", "ring"])
