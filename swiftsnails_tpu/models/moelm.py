@@ -1,15 +1,33 @@
 """A mixture-of-experts language model's block stack, as one chip of an
-expert-parallel deployment holds it: latent attention (MLA, DeepSeek-V3's) or
-grouped-query attention with a norm on every head's query and key
-(Qwen3-MoE's), SwiGLU, leading dense layers (there may be none), then
-mixture-of-experts layers of which this chip holds ``experts_held`` routed
-experts from ``expert_offset`` on, and a slice of the vocabulary. Corpus,
-windows, optimizer wiring, the one ``train_step``, the loss and its two
-objectives are ``models/seqlm.py``'s; this file is the stack they drive. A
-layer's kind follows the published keys that are there: ``kv_lora_rank``
-marks latent attention, else ``num_key_value_heads`` and ``head_dim`` the
-grouped-query one; ``scoring_func`` the router's scores; ``block_length`` the
-block-diffusion objective and its attention mask.
+expert-parallel deployment holds it: a mixer, then a feed-forward part, layer
+by layer. The mixer is latent attention (MLA, DeepSeek-V3's), grouped-query
+attention (Qwen3-MoE's, with a norm on every head's query and key; or without
+it and without rotary, its output gated), or a gated delta rule with a decay
+per channel (KDA, Kimi Linear's, arXiv:2510.26692); the feed-forward part is
+SwiGLU in the leading dense layers (there may be none) and a mixture of
+experts after them, of which this chip holds ``experts_held`` routed experts
+from ``expert_offset`` on; and the chip holds a slice of the vocabulary.
+Corpus, windows, optimizer wiring, the one ``train_step``, the loss and its
+two objectives are ``models/seqlm.py``'s; this file is the stack they drive.
+
+**The layer table.** ``mixers`` names each layer's kind of mixer. It follows
+the published keys that are there: ``kv_lora_rank`` marks latent attention,
+else ``num_key_value_heads`` and ``head_dim`` the grouped-query one; with
+``gqa_layers`` (a list of layer numbers) the layers named run that attention
+and every other layer the delta rule (``linear_attn_config.*``). The
+parameters of a kind are stacked on a leading axis: the feed-forward parts by
+theirs (``dense``, ``moe``), and the mixers with them where the table holds
+one kind (the tree of a model of one kind is what it was before the table),
+by their own kind (``gqa``, ``kda``) where it holds several
+(:meth:`MoELMTrainer._layer_table`). ``scoring_func`` names the router's
+scores; ``block_length`` the block-diffusion objective and its attention mask.
+
+**Held heads.** ``num_attention_heads``, ``num_key_value_heads`` and
+``linear_attn_config.num_heads`` are what THIS CHIP holds, as
+``experts_held`` is: where a deployment shares a mixer's heads out, ``W_o``
+maps the held ``heads x width`` channels to ``hidden_size`` and its output is
+a partial sum, which goes on as it is (no exchange is run); the norms, the
+low-rank gates' first halves, the router and the shared experts are whole.
 
 Per layer, residual ``x``, ``h = RMSNorm_w(x)`` (every norm has a learned
 gain and ``rms_norm_eps``):
@@ -24,6 +42,27 @@ gain and ``rms_norm_eps``):
   ``k_j = RMSNorm_knorm(W_k h)_j``, ``v_j = (W_v h)_j`` (``num_key_value_heads``
   x ``head_dim``); rotary over the whole head on ``q`` and ``k``; softmax of
   ``q_i . k_(i // group) / sqrt(head_dim)``; ``W_o [o_i]``. No bias anywhere.
+  ``qk_norm: 0`` leaves the two head norms out, ``use_rope: 0`` the rotary
+  (MLA's too), and ``use_gqa_gate: 1`` multiplies ``[o_i]`` elementwise by
+  ``sigmoid(W_z h)`` (``W_z [d, heads x head_dim]``) before ``W_o``.
+* **KDA** (``H`` heads of width ``K`` = ``linear_attn_config.head_dim``; in
+  brackets what the published keys do not state and ``benchmark/configs/``
+  lists as assumed). ``c_w(u)_t = silu(sum_j w_j u_(t - taps + 1 + j))`` a
+  causal depthwise convolution of ``short_conv_kernel_size`` taps, zero
+  history before each row [no bias]; ``q_t = l2norm(c_q(W_q h)_t)``, ``k_t =
+  l2norm(c_k(W_k h)_t)`` a head [1e-6 under the root], ``v_t = c_v(W_v h)_t``;
+  ``g_t = -exp(a_log_head) * softplus(F_up F_down h_t + dt_bias)`` a channel
+  [the gates' rank = ``K``, no bias but ``dt_bias``],
+  ``alpha_t = exp(g_t)``; ``beta_t = beta_max * sigmoid(W_b h_t)`` a head
+  (``kda_allow_neg_eigval``: ``beta_max`` 2, else 1); per head ``S_0 = 0 [K,
+  K]``: ``S'_t = diag(alpha_t) S_(t-1)``; ``S_t = S'_t + beta_t k_t (v_t -
+  S'_t^T k_t)^T``; ``o_t = S_t^T q_t / sqrt(K)`` [the scale]; ``y_t =
+  RMSNorm_onorm(o_t)`` (over the head's ``K``) ``* sigmoid(G_up G_down h_t)``;
+  ``W_o y``. State and convolution run across the documents of a packed row.
+  The recurrence is computed by chunks of 64 tokens
+  (``ops/gated_delta.py``, where the chunked form stands). Fresh leaves
+  [assumed]: ``a_log = log U(1, 16)``, ``dt_bias`` the inverse softplus of a
+  rate log-uniform in (0.001, 0.1), the taps U(-1/2, 1/2) (:func:`init_leaf`).
 * **Mask and positions.** Causal, rotary at ``0..L-1``. Under block diffusion
   a row is its noised copy followed by its clean copy, 2L positions, each
   rotated by its place in its own copy, under
@@ -54,17 +93,29 @@ gain and ``rms_norm_eps``):
   are kept all the same).
 
 Precision: parameters, gradients, optimizer state, norms, softmax, router
-and loss float32; the operands of every other matrix product are rounded to
-``matmul_dtype`` (bfloat16), accumulated in float32, in the backward pass
-too (:func:`mm`). The parameters of a kind of layer are stacked on a leading
-axis; the layers run one after another, unrolled (under a ``lax.scan`` over
-the stack the compiled step needs 4 GB more at the published widths, and no
-longer fits the chip), each rematerialised in the backward pass (``remat: 1``).
+and loss float32, and of a delta-rule layer the convolutions, the gates'
+activations, the log-decays, their running sums and exponentials, the
+triangular solve and the recurrent state; the operands of every other matrix
+product are rounded to ``matmul_dtype`` (bfloat16), accumulated in float32, in
+the backward pass too (:func:`mm`). The layers run one after another, unrolled
+(under a ``lax.scan`` over the stack the compiled step needs 4 GB more at the
+published widths, and no longer fits the chip), each rematerialised in the
+backward pass (``remat: 1``).
+
+Counters, in ``after_update``'s metrics and the state: ``moe_*`` (above) and,
+where the table has delta-rule layers, ``kda_decay_mean`` (state:
+``kda_decay``): the mean ``alpha`` over the step's tokens, heads and
+channels, the layers averaged; a gate that stopped decaying reads 1, one that
+wipes the state 0.
 
 Config keys (the published names where there is one): ``hidden_size``,
 ``num_hidden_layers``, ``first_k_dense_replace``, ``num_attention_heads``,
 ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``
-(or ``num_key_value_heads``, ``head_dim``), ``rope_theta``, ``rms_norm_eps``, ``intermediate_size``,
+(or ``num_key_value_heads``, ``head_dim``), ``rope_theta``, ``use_rope``,
+``qk_norm``, ``use_gqa_gate``, ``gqa_layers``, ``linear_attn_config.num_heads``,
+``linear_attn_config.head_dim``, ``linear_attn_config.short_conv_kernel_size``,
+``kda_allow_neg_eigval``, ``rms_norm_eps``,
+``intermediate_size``,
 ``moe_intermediate_size``, ``n_shared_experts``, ``num_experts_per_tok``,
 ``routed_scaling_factor``, ``scoring_func``, ``vocab_size``; ``router_experts`` (the router's
 width: the deployment's experts), ``experts_held``, ``expert_offset``;
@@ -83,6 +134,7 @@ import jax.numpy as jnp
 from swiftsnails_tpu.models.registry import register_model
 from swiftsnails_tpu.models.seqlm import SeqLMTrainer, diffusion_inputs, token_loss
 from swiftsnails_tpu.ops.flash_attention import BLOCK, flash_attention
+from swiftsnails_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from swiftsnails_tpu.ops.grouped_matmul import (
     TILE, grouped_swiglu, plan_rows, rows_of_tokens, tokens_of_rows)
 from swiftsnails_tpu.utils.config import Config
@@ -130,13 +182,46 @@ def rotary(x, theta: float, positions=None):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
+L2_EPS = 1e-6  # under the root of a head's sum of squares (KDA's q and k)
+
+
+def l2_norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
+
+
+def causal_conv(u, w):
+    """``u [B, L, channels]``, ``w [taps, channels]`` -> ``sum_j w_j *
+    u_(t - taps + 1 + j)``, a depthwise convolution with zero history before
+    each row, no bias."""
+    taps, seq = w.shape[0], u.shape[1]
+    padded = jnp.pad(u, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(w[j] * padded[:, j: j + seq] for j in range(taps))
+
+
+def init_leaf(key, name: str, shape, std: float):
+    """A fresh leaf by its name: a norm's gain 1; KDA's ``a_log`` the log of
+    U(1, 16), its ``dt_bias`` the inverse softplus of a rate log-uniform in
+    (0.001, 0.1), a convolution's taps U(-1/2, 1/2) (fan-in 4); every other
+    leaf N(0, std^2)."""
+    if name.endswith("norm"):
+        return jnp.ones(shape, jnp.float32)
+    if name == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    if name == "dt_bias":
+        rate = jnp.exp(jax.random.uniform(key, shape, jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        return rate + jnp.log(-jnp.expm1(-rate))
+    if name.startswith("conv_"):
+        return jax.random.uniform(key, shape, jnp.float32, -0.5, 0.5)
+    return jax.random.normal(key, shape, jnp.float32) * std
+
+
 # ---------------------------------------------------------- the trainer ---
 
 
 @register_model("moelm")
 class MoELMTrainer(SeqLMTrainer):
     name = "moelm"
-    attention_block, expert_tile = BLOCK, TILE  # the kernels' own; a test sets smaller ones
+    attention_block, expert_tile, kda_chunk = BLOCK, TILE, CHUNK  # the kernels' own; a test sets smaller ones
 
     def _read_shape(self, cfg: Config) -> None:
         g = cfg.get_int
@@ -152,6 +237,18 @@ class MoELMTrainer(SeqLMTrainer):
             if self.n_heads % self.kv_heads:
                 raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         self.rope_theta = cfg.get_float("rope_theta", 10000.0)
+        self.use_rope = cfg.get_bool("use_rope", True)
+        self.qk_norm = cfg.get_bool("qk_norm", True)
+        self.attn_gate = cfg.get_bool("use_gqa_gate", False)
+        kind = "mla" if self.kv_rank else "gqa"
+        self.mixers = (kind,) * self.n_layers  # the layer table: layer -> kind of mixer
+        if "gqa_layers" in cfg:  # the layers named run that attention, the others the delta rule
+            named = {int(i) for i in cfg.get_str("gqa_layers").strip("[]() ").split(",") if i.strip()}
+            self.mixers = tuple(kind if i in named else "kda" for i in range(self.n_layers))
+        if "kda" in self.mixers:
+            self.kda_heads, self.kda_dim = g("linear_attn_config.num_heads"), g("linear_attn_config.head_dim")
+            self.kda_taps = g("linear_attn_config.short_conv_kernel_size")
+            self.kda_beta_max = 2.0 if cfg.get_bool("kda_allow_neg_eigval", False) else 1.0
         self.eps = cfg.get_float("rms_norm_eps", 1e-6)
         self.dense_width = g("intermediate_size", 0)
         self.expert_width = g("moe_intermediate_size")
@@ -180,19 +277,43 @@ class MoELMTrainer(SeqLMTrainer):
 
     # -- parameters ----------------------------------------------------------
 
-    def param_shapes(self) -> Dict[str, Any]:
-        """The parameter tree's shapes; layers of a kind are stacked on a
-        leading axis. Norm gains start at 1, everything else N(0, init_std)."""
+    def _layer_table(self):
+        """Per layer, where its leaves are: ((group, place in the group) of
+        its mixer's, the same of its feed-forward's). The feed-forward parts
+        are stacked by their kind (``dense``, ``moe``); the mixers by theirs
+        where the table holds several kinds, and with the layer's
+        feed-forward part where it holds one."""
+        ffn = ("dense",) * self.n_dense + ("moe",) * (self.n_layers - self.n_dense)
+        groups = (self.mixers if len(set(self.mixers)) > 1 else ffn, ffn)
+        return [tuple((g[i], g[:i].count(g[i])) for g in groups) for i in range(self.n_layers)]
+
+    def _mixer_shapes(self, kind: str) -> Dict[str, Any]:
         d, h = self.d_model, self.n_heads
-        if self.kv_rank:
+        if kind == "kda":
+            h, hd = self.kda_heads, self.kda_dim
+            r = hd  # the low-rank gates' rank
+            wide = {k: (d, h * hd) for k in ("wq", "wk", "wv")}
+            taps = {"conv_" + k: (self.kda_taps, h * hd) for k in "qkv"}
+            return {"attn_norm": (d,), **wide, **taps, "f_down": (d, r), "f_up": (r, h * hd),
+                    "a_log": (h,), "dt_bias": (h * hd,), "wb": (d, h), "g_down": (d, r),
+                    "g_up": (r, h * hd), "o_norm": (hd,), "wo": (h * hd, d)}
+        if kind == "mla":
             attn = {"wq": (d, h * (self.nope + self.rope)),
                     "wkv_a": (d, self.kv_rank + self.rope), "kv_norm": (self.kv_rank,),
                     "wkv_b": (self.kv_rank, h * (self.nope + self.v_dim))}
         else:
             hd, kv = self.v_dim, self.kv_heads
-            attn = {"wq": (d, h * hd), "wk": (d, kv * hd), "wv": (d, kv * hd),
-                    "q_norm": (hd,), "k_norm": (hd,)}
-        attn = {"attn_norm": (d,), **attn, "wo": (h * self.v_dim, d), "mlp_norm": (d,)}
+            attn = {"wq": (d, h * hd), "wk": (d, kv * hd), "wv": (d, kv * hd)}
+            if self.qk_norm:
+                attn.update({"q_norm": (hd,), "k_norm": (hd,)})
+            if self.attn_gate:
+                attn["wz"] = (d, h * hd)
+        return {"attn_norm": (d,), **attn, "wo": (h * self.v_dim, d)}
+
+    def param_shapes(self) -> Dict[str, Any]:
+        """The parameter tree's shapes; layers of a kind are stacked on a
+        leading axis (:meth:`_layer_table`). Fresh leaves: :func:`init_leaf`."""
+        d = self.d_model
 
         def swiglu(prefix, width):
             if not width:
@@ -201,24 +322,24 @@ class MoELMTrainer(SeqLMTrainer):
                     f"{prefix}_down": (width, d)}
 
         e, w = self.experts_held, self.expert_width
-        moe = {**attn, "router": (d, self.router_experts),
-               **swiglu("shared", self.n_shared * w),
-               "experts_gate": (e, d, w), "experts_up": (e, d, w), "experts_down": (e, w, d)}
-        stack = lambda n, tree: {k: (n,) + s for k, s in tree.items()}  # noqa: E731
-        tree = {"embed": (self.vocab_size, d), "head": (d, self.vocab_size), "final_norm": (d,),
-                "moe": stack(self.n_layers - self.n_dense, moe)}
-        if self.n_dense:
-            tree["dense"] = stack(self.n_dense, {**attn, **swiglu("mlp", self.dense_width)})
-        return tree
+        ffn = {"dense": {"mlp_norm": (d,), **swiglu("mlp", self.dense_width)},
+               "moe": {"mlp_norm": (d,), "router": (d, self.router_experts),
+                       **swiglu("shared", self.n_shared * w),
+                       "experts_gate": (e, d, w), "experts_up": (e, d, w), "experts_down": (e, w, d)}}
+        groups = {}
+        for kind, (mixer, forward) in zip(self.mixers, self._layer_table()):
+            for (group, place), leaves in ((mixer, self._mixer_shapes(kind)), (forward, ffn[forward[0]])):
+                n, tree = groups.get(group, (0, {}))
+                groups[group] = (max(n, place + 1), {**tree, **leaves})
+        return {"embed": (self.vocab_size, d), "head": (d, self.vocab_size), "final_norm": (d,),
+                **{group: {k: (n,) + s for k, s in tree.items()} for group, (n, tree) in groups.items()}}
 
     def init_state(self) -> Dict[str, Any]:
         leaves, tree = jax.tree_util.tree_flatten_with_path(
             self.param_shapes(), is_leaf=lambda s: isinstance(s, tuple))
         keys = jax.random.split(jax.random.PRNGKey(self.seed), len(leaves))
-        params = tree.unflatten([
-            jnp.ones(s, jnp.float32) if path[-1].key.endswith("norm")
-            else jax.random.normal(k, s, jnp.float32) * self.init_std
-            for (path, s), k in zip(leaves, keys)])
+        params = tree.unflatten([init_leaf(k, path[-1].key, s, self.init_std)
+                                 for (path, s), k in zip(leaves, keys)])
         return self.state_of(params)
 
     def state_of(self, params) -> Dict[str, Any]:
@@ -235,6 +356,8 @@ class MoELMTrainer(SeqLMTrainer):
                  "dropped": jnp.zeros((), jnp.int32)}
         if self.block_length:
             state["noised"] = jnp.zeros((), jnp.int32)
+        if "kda" in self.mixers:
+            state["kda_decay"] = jnp.zeros((), jnp.float32)
         return state
 
     # -- layers --------------------------------------------------------------
@@ -257,10 +380,12 @@ class MoELMTrainer(SeqLMTrainer):
 
     def _grouped_qkv(self, p, y, shape, spin):
         """Grouped queries: (q ``[B, L, heads, head_dim]``, k, v ``[B, L,
-        key/value heads, head_dim]``), each head's query and key normed."""
+        key/value heads, head_dim]``), each head's query and key normed
+        (``qk_norm``)."""
         heads = lambda w, n: self._mm(y, w).reshape(*shape, n, self.v_dim)  # noqa: E731
-        q = rms_norm(heads(p["wq"], self.n_heads), p["q_norm"], self.eps)
-        k = rms_norm(heads(p["wk"], self.kv_heads), p["k_norm"], self.eps)
+        norm = (lambda t, gain: rms_norm(t, p[gain], self.eps)) if self.qk_norm else (lambda t, gain: t)
+        q = norm(heads(p["wq"], self.n_heads), "q_norm")
+        k = norm(heads(p["wk"], self.kv_heads), "k_norm")
         return spin(q), spin(k), heads(p["wv"], self.kv_heads)
 
     def _attention(self, p, x, b, positions=None):
@@ -268,14 +393,47 @@ class MoELMTrainer(SeqLMTrainer):
         [L]`` are rotary's (``0..L-1`` if none)."""
         seq = x.shape[0] // b
         y = rms_norm(x, p["attn_norm"], self.eps)
-        spin = jax.vmap(lambda t: rotary(t, self.rope_theta, positions))
+        spin = jax.vmap(lambda t: rotary(t, self.rope_theta, positions)) if self.use_rope else (lambda t: t)
         qkv = self._latent_qkv if self.kv_rank else self._grouped_qkv
         q, k, v = qkv(p, y, (b, seq), spin)
         fold = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, seq, t.shape[-1])  # noqa: E731
         o = flash_attention(fold(q), fold(k), fold(v), block=self.attention_block,
                             dtype=self.matmul_dtype, diffusion_block=self.block_length or None)
-        o = o.reshape(b, self.n_heads, seq, self.v_dim).transpose(0, 2, 1, 3)
-        return self._mm(o.reshape(b * seq, -1), p["wo"])
+        o = o.reshape(b, self.n_heads, seq, self.v_dim).transpose(0, 2, 1, 3).reshape(b * seq, -1)
+        if self.attn_gate:
+            o = o * jax.nn.sigmoid(self._mm(y, p["wz"]))
+        return self._mm(o, p["wo"])
+
+    def _kda(self, p, x, b):
+        """``x [B * L, d]`` -> (the delta-rule mixer's output, same shape; the
+        mean decay ``alpha`` over its tokens, heads and channels). State and
+        convolution start at zero with each row and run across its documents."""
+        seq, h, hd = x.shape[0] // b, self.kda_heads, self.kda_dim
+        y = rms_norm(x, p["attn_norm"], self.eps)
+        heads = lambda t: t.reshape(b, seq, h, -1)  # noqa: E731
+        conv = lambda c: heads(jax.nn.silu(causal_conv(  # noqa: E731
+            self._mm(y, p["w" + c]).reshape(b, seq, -1), p["conv_" + c])))
+        q, k, v = l2_norm(conv("q")), l2_norm(conv("k")), conv("v")
+        rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
+        g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
+        beta = self.kda_beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
+        fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, seq, -1)  # noqa: E731
+        o = gated_delta_rule(fold(q), fold(k), fold(v), fold(g), fold(heads(beta))[..., 0],
+                             chunk=self.kda_chunk, dtype=self.matmul_dtype)
+        o = o.reshape(b, h, seq, hd).transpose(0, 2, 1, 3).reshape(b * seq, h, hd)
+        gate = jax.nn.sigmoid(self._mm(self._mm(y, p["g_down"]), p["g_up"]))
+        o = rms_norm(o, p["o_norm"], self.eps).reshape(b * seq, -1) * gate
+        return self._mm(o, p["wo"]), jnp.mean(jnp.exp(g))
+
+    def _mix(self, kind, p, x, b, positions=None):
+        """A layer's first half: ``x`` + its mixer's output, and what the
+        mixer counted."""
+        if kind == "kda":
+            with phase_scope("kda"):
+                out, decay = self._kda(p, x, b)
+            return x + out, {"decay": decay}
+        with phase_scope("attn"):
+            return x + self._attention(p, x, b, positions), {}
 
     def _swiglu(self, p, prefix, y):
         hidden = jax.nn.silu(self._mm(y, p[prefix + "_gate"])) * self._mm(y, p[prefix + "_up"])
@@ -320,15 +478,13 @@ class MoELMTrainer(SeqLMTrainer):
                              p["experts_down"], plan, tile, self.matmul_dtype)
         return tokens_of_rows(out, gates, plan, tile), dropped, live_share
 
-    def _dense_layer(self, x, p, b, positions=None):
-        with phase_scope("attn"):
-            x = x + self._attention(p, x, b, positions)
+    def _dense_layer(self, x, p, b, positions=None, kind=None):
+        x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
         with phase_scope("mlp"):
-            return x + self._swiglu(p, "mlp", rms_norm(x, p["mlp_norm"], self.eps))
+            return x + self._swiglu(p, "mlp", rms_norm(x, p["mlp_norm"], self.eps)), counted
 
-    def _moe_layer(self, x, p, bias, b, positions=None):
-        with phase_scope("attn"):
-            x = x + self._attention(p, x, b, positions)
+    def _moe_layer(self, x, p, bias, b, positions=None, kind=None):
+        x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
         with phase_scope("route"):
             y = rms_norm(x, p["mlp_norm"], self.eps)
             choices, gates, s = self.route(y, p["router"], bias)
@@ -337,26 +493,34 @@ class MoELMTrainer(SeqLMTrainer):
             routed, dropped, live_share = self._experts(p, y, choices, gates)
         with phase_scope("mlp"):
             shared = self._swiglu(p, "shared", y) if self.n_shared else 0.0
-        return x + routed + shared, {"aux": aux, "counts": counts, "choices": choices,
+        return x + routed + shared, {**counted, "aux": aux, "counts": counts, "choices": choices,
                                      "dropped": dropped, "live_tile_share": live_share}
 
     def stack(self, params, tokens, router_bias, positions=None):
         """(the stack's output after the last norm [B * L, d], what the
-        mixture layers counted, stacked by layer)."""
+        mixture layers counted, stacked by layer; ``kda_decay``, if the table
+        has such layers, is theirs alone)."""
         b = tokens.shape[0]
         wrap = jax.checkpoint if self.remat else (lambda f: f)
         with phase_scope("head"):
             x = params["embed"][tokens.reshape(-1)]
-        layer_of = lambda tree, i: {k: v[i] for k, v in tree.items()}  # noqa: E731
-        dense = wrap(lambda x, p: self._dense_layer(x, p, b, positions))
-        sparse = wrap(lambda x, p, bias: self._moe_layer(x, p, bias, b, positions))
-        for i in range(self.n_dense):
-            x = dense(x, layer_of(params["dense"], i))
-        seen = []
-        for i in range(self.n_layers - self.n_dense):
-            x, counted = sparse(x, layer_of(params["moe"], i), router_bias[i])
-            seen.append(counted)
+        kinds = set(self.mixers)
+        dense = {k: wrap(functools.partial(self._dense_layer, b=b, positions=positions, kind=k)) for k in kinds}
+        sparse = {k: wrap(functools.partial(self._moe_layer, b=b, positions=positions, kind=k)) for k in kinds}
+        seen, decays = [], []
+        for i, (kind, homes) in enumerate(zip(self.mixers, self._layer_table())):
+            p = {k: v[place] for group, place in dict.fromkeys(homes) for k, v in params[group].items()}
+            if i < self.n_dense:
+                x, counted = dense[kind](x, p)
+            else:
+                x, counted = sparse[kind](x, p, router_bias[i - self.n_dense])
+            if "decay" in counted:
+                decays.append(counted.pop("decay"))
+            if i >= self.n_dense:
+                seen.append(counted)
         seen = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *seen)
+        if decays:
+            seen["kda_decay"] = jnp.stack(decays)
         with phase_scope("head"):
             return rms_norm(x, params["final_norm"], self.eps), seen
 
@@ -407,4 +571,6 @@ class MoELMTrainer(SeqLMTrainer):
         if self.block_length:
             state["noised"] = aux["noised"]
             metrics["diffusion_masked_share"] = aux["noised"] / (self.batch_size * self.seq_len)
+        if "kda_decay" in aux:
+            state["kda_decay"] = metrics["kda_decay_mean"] = jnp.mean(aux["kda_decay"])
         return state, metrics

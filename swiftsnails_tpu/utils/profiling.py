@@ -80,13 +80,16 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # ``head`` embedding, final norm, output head and loss, ``opt`` the optimizer
 # and whatever else a step changes that is no gradient, ``noise`` what turns
 # a block-diffusion batch into the stack's input (the mask id where the batch
-# says noised, the two copies, their positions, the loss's weights). Scopes are metadata on the operations (no
+# says noised, the two copies, their positions, the loss's weights), ``kda``
+# a gated delta-rule mixer (norm, projections, convolutions, gates, the
+# chunked recurrence, which ``ops/gated_delta.py`` names ``phase_kda_core``
+# inside it, the gated norm and the output projection). Scopes are metadata on the operations (no
 # operation, no flag): a ``profile_dir`` capture shows them as the name
 # scope of each device operation, and ``benchmark/lib/scopes.py`` sums
 # device time by the innermost one. The prefix stays clear of the ``ssn_*``
 # labels that ``telemetry/audit.py`` groups collective bytes by.
 PHASES = ("prep", "fused", "pull", "push", "dense",
-          "attn", "mlp", "route", "experts", "head", "opt", "noise")
+          "attn", "mlp", "route", "experts", "head", "opt", "noise", "kda")
 
 
 def phase_scope(phase: str):

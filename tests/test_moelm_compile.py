@@ -1,5 +1,6 @@
 """The kernels of the mixture-of-experts step (at Moonlight-16B-A3B's and at
-SDAR-30B-A3B-Chat's published widths) and the table plane's gather (at the Wide&Deep cell's
+SDAR-30B-A3B-Chat's published widths), the chunked delta rule at
+Solar-Open2-250B's, and the table plane's gather (at the Wide&Deep cell's
 shapes) compile for a TPU v5e that is described and not attached: what
 interpret mode cannot show (tile alignment, VMEM, transposed products in
 Mosaic). Compile-only: nothing runs, and no time or result comes of it. The
@@ -115,6 +116,26 @@ def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkey
     for name in ("grouped_matmul_swiglu", "grouped_matmul_dswiglu", "grouped_matmul_dx", "grouped_matmul_dw"):
         assert any(name in line for line in kernels), name
     assert whole.memory_analysis().temp_size_in_bytes <= unfused.memory_analysis().temp_size_in_bytes
+
+
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache):
+    """Solar-Open2-250B's delta-rule layer as the cell holds it: 8 heads of
+    128, chunks of 64, forward and backward. XLA's throughout: one triangular
+    solve a chunk, and nothing the size of a state a token (``[8, 2048, 128,
+    128]`` float32 is 1 GiB) or of every pair's decay is allocated."""
+    from swiftsnails_tpu.ops.gated_delta import gated_delta_rule
+
+    heads, seq, width = 8, 2048, 128
+
+    def loss(q, k, v, g, beta, w):
+        return jnp.sum(gated_delta_rule(q, k, v, g, beta) * w)
+
+    f32, wide = jnp.float32, (heads, seq, width)
+    compiled = _compiled(jax.grad(loss, (0, 1, 2, 3, 4)), one_chip, (wide, f32), (wide, f32), (wide, f32),
+                         (wide, f32), ((heads, seq), f32), (wide, f32))
+    assert "InvertDiagBlocksLowerTriangular" in compiled.as_text() or "triangular" in compiled.as_text().lower()
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * width * width * 4 / 4
+    assert [o.shape for o in compiled.out_info] == [wide, wide, wide, wide, (heads, seq)]
 
 
 # the Wide&Deep cell's pull (212,992 ids into a 4 GiB table of [2, 128]

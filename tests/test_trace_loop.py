@@ -342,7 +342,7 @@ def test_widedeep_step_names_its_phases(monkeypatch):
 
 def test_phase_scope_knows_its_names():
     assert PHASES == ("prep", "fused", "pull", "push", "dense",
-                      "attn", "mlp", "route", "experts", "head", "opt", "noise")
+                      "attn", "mlp", "route", "experts", "head", "opt", "noise", "kda")
     assert all(p.isalpha() and p.islower() for p in PHASES)  # benchmark/lib/scopes.py reads phase_[a-z]+
     with pytest.raises(ValueError):
         phase_scope("warmup")
