@@ -102,7 +102,10 @@ the backward pass too (:func:`mm`). The layers run one after another, unrolled
 published widths, and no longer fits the chip), each rematerialised in the
 backward pass (``remat: 1``).
 
-Counters, in ``after_update``'s metrics and the state: ``moe_*`` (above) and,
+Counters, in ``after_update``'s metrics and the state: ``moe_*`` (above);
+``attn_whole_tile_share`` (metrics only, a constant of the trace): of the tiles
+an attention kernel visits, the share the mask allows whole
+(``ops/flash_attention.tile_classes``); and,
 where the table has delta-rule layers, ``kda_decay_mean`` (state:
 ``kda_decay``): the mean ``alpha`` over the step's tokens, heads and
 channels, the layers averaged; a gate that stopped decaying reads 1, one that
@@ -133,7 +136,7 @@ import jax.numpy as jnp
 
 from swiftsnails_tpu.models.registry import register_model
 from swiftsnails_tpu.models.seqlm import SeqLMTrainer, diffusion_inputs, token_loss
-from swiftsnails_tpu.ops.flash_attention import BLOCK, flash_attention
+from swiftsnails_tpu.ops.flash_attention import BLOCK, flash_attention, tile_classes
 from swiftsnails_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from swiftsnails_tpu.ops.grouped_matmul import (
     TILE, grouped_swiglu, plan_rows, rows_of_tokens, tokens_of_rows)
@@ -568,6 +571,10 @@ class MoELMTrainer(SeqLMTrainer):
             "moe_dropped": state["dropped"],
             "moe_live_tile_share": jnp.mean(aux["live_tile_share"]),
         }
+        if set(self.mixers) != {"kda"}:  # a constant of the trace: the mask, the row's positions and the tile
+            tiles = tile_classes(self.seq_len * (2 if self.block_length else 1), self.attention_block,
+                                 self.block_length or None)
+            metrics["attn_whole_tile_share"] = jnp.float32(tiles["whole"] / tiles["live"])
         if self.block_length:
             state["noised"] = aux["noised"]
             metrics["diffusion_masked_share"] = aux["noised"] / (self.batch_size * self.seq_len)

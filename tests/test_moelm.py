@@ -118,6 +118,7 @@ def test_train_steps_match_reference(moonlight):
             grad1 = {k: float(jnp.sum(v * v)) / (1 - 0.9) ** 2
                      for k, v in moonlight._flatten(state["opt"][0].mu).items()}
         assert int(m["moe_dropped"]) == 0 and 0 < float(m["moe_live_tile_share"]) <= 1
+        assert float(m["attn_whole_tile_share"]) == pytest.approx(1 / 3)  # two causal tiles a side: 1 of 3 visited
     np.testing.assert_allclose(losses, ref["loss"], rtol=2e-5)
     for k, want in ref["grad1"].items():
         assert grad1[k] == pytest.approx(want, rel=2e-3, abs=1e-12), k

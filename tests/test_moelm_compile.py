@@ -21,6 +21,7 @@ SEQ, HIDDEN = 8192, 2048
 # (query heads, key/value heads, key width, value width, block-diffusion block, experts' width, held, a token)
 MOONLIGHT = (16, 16, 192, 128, None, 1408, 8, 6)  # qk_nope 128 + qk_rope 64; v_head_dim 128; causal
 SDAR = (32, 4, 128, 128, 4, 768, 16, 8)  # 8 query heads to a key/value head; 4,096 tokens in two copies
+SOLAR_SOFTMAX = (8, 1, 128, 128, None)  # Solar-Open2-250B's softmax layer as its cell holds it: 8 heads to 1, causal
 
 
 @pytest.fixture(scope="module")
@@ -54,8 +55,13 @@ def _compiled(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("widths", [MOONLIGHT, SDAR], ids=["moonlight-causal", "sdar-block-diffusion"])
-def test_attention_kernels_compile_at_published_widths(one_chip, no_cache, widths):
+@pytest.mark.parametrize("widths,seq", [(MOONLIGHT, SEQ), (SDAR, SEQ), (SOLAR_SOFTMAX, 2048)],
+                         ids=["moonlight-causal", "sdar-block-diffusion", "solar-causal-8-to-1"])
+def test_attention_kernels_compile_at_published_widths(one_chip, no_cache, widths, seq):
+    """Each kernel over its grid of live steps (the step table in scalar
+    memory; under block diffusion with the second body, a block-diagonal
+    tile's ``[128, 128]`` pieces) at the three cells' widths and tiles a
+    side: 16, 8 a copy, 4."""
     heads, kv_heads, dk, dv, diffusion_block = widths[:5]
 
     def loss(q, k, v, w):
@@ -64,15 +70,15 @@ def test_attention_kernels_compile_at_published_widths(one_chip, no_cache, width
 
     f32 = jnp.float32
     compiled = _compiled(jax.grad(loss, (0, 1, 2)), one_chip,
-                         ((heads, SEQ, dk), f32), ((kv_heads, SEQ, dk), f32),
-                         ((kv_heads, SEQ, dv), f32), ((heads, SEQ, dv), f32))
+                         ((heads, seq, dk), f32), ((kv_heads, seq, dk), f32),
+                         ((kv_heads, seq, dv), f32), ((heads, seq, dv), f32))
     text = compiled.as_text()
     for name in ("flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"):
         assert name in text and "tpu_custom_call" in text, name
     # scores never leave VMEM, and keys and values are never copied out to the
     # query heads: nothing the size of [heads, L, L] is allocated
-    assert compiled.memory_analysis().temp_size_in_bytes < heads * SEQ * SEQ * 4 / 4
-    assert compiled.out_info[1].shape == (kv_heads, SEQ, dk)  # dk summed over the group in the kernel
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * seq * 4 / 4
+    assert compiled.out_info[1].shape == (kv_heads, seq, dk)  # dk summed over the group in the kernel
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["products", "fused"])

@@ -7,6 +7,7 @@ run in interpret mode, matrix operands stay float32 so that the two agree
 closely. ``tests/test_moelm.py`` holds the latent-attention kind to its own
 reference the same way."""
 
+import dataclasses
 import importlib.util
 import os
 import sys
@@ -20,7 +21,8 @@ import jax.numpy as jnp
 from swiftsnails_tpu.framework.trainer import TrainLoop
 from swiftsnails_tpu.models.moelm import MoELMTrainer, rotary
 from swiftsnails_tpu.models.seqlm import SeqLMTrainer, diffusion_inputs, draw_noise, token_loss
-from swiftsnails_tpu.ops.flash_attention import attention_flops, flash_attention
+from swiftsnails_tpu.ops import flash_attention as fa
+from swiftsnails_tpu.ops.flash_attention import attention_flops, flash_attention, tile_classes
 from swiftsnails_tpu.utils.config import Config
 from swiftsnails_tpu.utils.metrics import MetricsLogger
 
@@ -101,6 +103,143 @@ def test_flash_attention_under_a_mask_and_over_grouped_heads(sdar, mask, heads, 
     want = jax.grad(lambda *a: jnp.sum(plain(*a) * w), (0, 1, 2))(q, k, v)
     for a, b in zip(got, want):  # sums of up to 8 x 128 float32 products in another order
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=5e-3, atol=5e-4)
+
+
+def _one_piece(mask):
+    """``mask`` with every visited tile taken as one piece under its mask:
+    the kernels as they were before a tile had a class."""
+
+    @dataclasses.dataclass(frozen=True)
+    class OnePiece(type(mask)):
+        def classes(self, qi, kj, n):
+            return {"whole": True}
+
+    return OnePiece(**dataclasses.asdict(mask))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("mask,heads,kv_heads,dk,dv", [
+    (fa.Causal(), 2, 2, 24, 16), (fa.Causal(), 8, 1, 16, 16),
+    (fa.BlockDiffusion(4), 2, 2, 16, 16), (fa.BlockDiffusion(4), 8, 1, 16, 8)],
+    ids=["causal-unequal-widths", "causal-8-to-1", "diffusion-1-to-1", "diffusion-8-to-1"])
+def test_a_cut_tile_by_its_pieces_is_the_whole_tile_bit_for_bit(mask, heads, kv_heads, dk, dv, dtype):
+    """The forward output, ``dq``, ``dk`` and ``dv`` of the kernels as shipped
+    (a tile the mask cuts computes only its sub-tiles that hold a pair)
+    against the same kernels under a mask whose every tile is one piece,
+    three tiles a side (a copy) and four sub-tiles a tile's side, so that
+    whole, lower, diagonal and dead steps all occur. What a piece leaves out
+    is exact zeros in the whole tile's sums; on the chip the comparison is
+    made at the cells' widths against the parent's kernels (PERF.md, PR 37)."""
+    tile, copies = 16, 2 if isinstance(mask, fa.BlockDiffusion) else 1
+    seq = 3 * tile * copies
+    assert fa._parts(mask, tile, True) == 4 and fa._parts(mask, tile, False) == 1  # [4, 4] is no whole lanes
+    classes = tile_classes(seq, tile, getattr(mask, "block_length", None))
+    assert min(classes.values()) > 0, classes
+    ks = jax.random.split(jax.random.PRNGKey(1), 4)
+    q, k = jax.random.normal(ks[0], (heads, seq, dk)), jax.random.normal(ks[1], (kv_heads, seq, dk))
+    v, w = jax.random.normal(ks[2], (kv_heads, seq, dv)), jax.random.normal(ks[3], (heads, seq, dv))
+
+    def results(mask):
+        o, vjp = jax.vjp(lambda q, k, v: fa._attend(q, k, v, tile, jnp.dtype(dtype), True, mask), q, k, v)
+        return [np.asarray(t) for t in (o, *vjp(w))]
+
+    for name, got, want in zip(("o", "dq", "dk", "dv"), results(mask), results(_one_piece(mask))):
+        assert np.isfinite(got).all() and np.array_equal(got, want), name
+
+
+def _walks(mask, n):
+    """Every step of the masks' two rectangular walks, dead ones too, as
+    plain numbers: ``(qi, j, kj, live)`` of ``key_tile`` and ``(kj, i, qi,
+    live)`` of ``query_tile``."""
+    qi, j = np.meshgrid(np.arange(n), np.arange(mask.key_steps(n)), indexing="ij")
+    by_query = zip(qi.ravel(), j.ravel(), *(np.broadcast_to(t, qi.shape).ravel() for t in mask.key_tile(qi, j, n)))
+    kj, i = np.meshgrid(np.arange(n), np.arange(n), indexing="ij")
+    by_key = zip(kj.ravel(), i.ravel(), *(np.broadcast_to(t, kj.shape).ravel() for t in mask.query_tile(kj, i, n)))
+    return [tuple(int(x) for x in s) for s in by_query], [tuple(int(x) for x in s) for s in by_key]
+
+
+def _edges(steps, row):
+    """Where the tile named in ``row`` changes along a step table: (whether a
+    step is the first of its tile, whether it is the last)."""
+    run, last = [int(x) for x in steps[row]], steps.shape[1] - 1
+    return ([int(t == 0 or run[t] != run[t - 1]) for t in range(last + 1)],
+            [int(t == last or run[t] != run[t + 1]) for t in range(last + 1)])
+
+
+@pytest.mark.parametrize("per_copy,tile,block_length", [
+    (1, 8, None), (2, 4, None), (3, 8, None), (5, 4, None),
+    (1, 4, 4), (2, 4, 2), (3, 8, 2), (4, 8, 1), (3, 16, 4), (2, 32, 8), (2, 16, 8)],
+    ids=lambda x: str(x))
+def test_a_tile_s_class_says_where_its_mask_keeps_a_pair(sdar, per_copy, tile, block_length):
+    """Over every step of the masks' walks: a visited tile has exactly one
+    class; ``whole`` says that ``keep`` over the tile is all true (where a
+    tile holds several blocks); the pieces of its class hold every pair
+    ``keep`` allows and lie inside the tile, each sub-tile at most once; the
+    live tiles' ``keep`` are the dense mask and the tiles never visited hold
+    no pair; every row's first live tile holds a pair it may see (the
+    module's standing promise) and a cut tile of several blocks at least one.
+    The step tables hold the live steps in the walks' order and no dead one,
+    with the first and the last step of every accumulated tile marked, under
+    grouped heads too; :func:`tile_classes` counts what the walk counts."""
+    mask = fa._mask_of(block_length)
+    n = per_copy * (2 if block_length else 1)
+    seq, full = n * tile, ((0, tile), (0, tile))
+    parts = fa._parts(mask, tile, True)
+    assert parts == (4 if tile % (4 * mask.unit) == 0 else 1)
+    dense = sdar.allowed_pairs(seq // 2, block_length) if block_length else np.tril(np.ones((seq, seq), bool))
+    by_query, by_key = _walks(mask, n)
+    one_block_diagonal = {(q, k) for q in range(n) for k in range(n)
+                          if tile == (block_length or 1) and q % per_copy == k % per_copy}
+    seen, counts, table = np.zeros_like(dense), {"live": 0, "whole": 0, "cut": 0, "dead": 0}, []
+    for qi, j, kj, live in by_query:
+        if not live:
+            counts["dead"] += 1
+            continue
+        keep = np.asarray(mask.keep(jnp.int32(qi), jnp.int32(kj), n, tile, *full))
+        kinds = [kind for kind, here in mask.classes(qi, kj, n).items() if here]
+        assert len(kinds) == 1 and kinds[0] in fa._KINDS, (qi, kj, kinds)
+        whole = kinds == ["whole"]
+        # (a tile of ONE block is full on the diagonal, or empty where only earlier blocks
+        # count, and has a cut tile's class either way)
+        assert whole == (keep.all() and (qi, kj) not in one_block_diagonal), (qi, kj)
+        assert keep.any() or (qi, kj) in one_block_diagonal, (qi, kj)
+        covered = np.zeros_like(keep)
+        for rows, cols in fa._pieces(kinds[0], tile, parts):
+            part = np.asarray(mask.keep(jnp.int32(qi), jnp.int32(kj), n, tile, rows, cols))
+            at = (slice(rows[0], rows[0] + rows[1]), slice(cols[0], cols[0] + cols[1]))
+            assert part.shape == keep[at].shape and np.array_equal(part, keep[at]) and not covered[at].any()
+            covered[at] = True
+        assert not (keep & ~covered).any(), (qi, kj, kinds)
+        assert (len(fa._pieces(kinds[0], tile, parts)) > 1) == (kinds == ["diagonal"] and parts > 1)
+        if not any(row[0] == qi for row in table):
+            assert keep.any(axis=1).all(), (qi, kj)  # the running maximum is finite from the first step on
+        assert not seen[qi * tile:(qi + 1) * tile, kj * tile:(kj + 1) * tile].any()  # a tile is visited once
+        seen[qi * tile:(qi + 1) * tile, kj * tile:(kj + 1) * tile] = keep
+        counts["live"] += 1
+        counts["whole" if whole else "cut"] += 1
+        table.append((qi, kj, fa._KINDS.index(kinds[0])))
+    assert np.array_equal(seen, dense)
+    assert counts == tile_classes(seq, tile, block_length)
+    steps = fa._steps(mask, n)
+    assert steps.dtype == np.int32 and [tuple(c) for c in steps[:3].T] == table and not steps[fa._HEAD].any()
+    assert _edges(steps, fa._QI) == ([int(f) for f in steps[fa._FIRST]], [int(f) for f in steps[fa._LAST]])
+    # the key side: a key tile's live steps once a query head of the group, in the walk's order
+    kinds_of = {(qi, kj): kind for qi, kj, kind in table}
+    want = [(qi, kj, kinds_of[qi, kj], head) for kj in range(n) for head in range(3)
+            for k, _, qi, live in by_key if live and k == kj]
+    steps = fa._steps(mask, n, group=3)
+    assert [tuple(c) for c in steps[[fa._QI, fa._KJ, fa._KIND, fa._HEAD]].T] == want
+    assert {(qi, kj) for qi, kj, *_ in want} == set(kinds_of) and len(want) == 3 * len(table)
+    assert steps[fa._FIRST].sum() == steps[fa._LAST].sum() == n
+    assert _edges(steps, fa._KJ) == ([int(f) for f in steps[fa._FIRST]], [int(f) for f in steps[fa._LAST]])
+
+
+@pytest.mark.parametrize("seq,block_length,live,whole", [
+    (8192, None, 136, 120), (8192, 4, 80, 56), (2048, None, 10, 6)], ids=["moonlight", "sdar", "solar"])
+def test_tile_classes_at_the_cells_shapes(seq, block_length, live, whole):
+    got = tile_classes(seq, diffusion_block=block_length)  # the kernels' own tile of 512
+    steps = {None: (seq // 512) ** 2, 4: 16 * 9}[block_length]
+    assert got == {"live": live, "whole": whole, "cut": live - whole, "dead": steps - live}
 
 
 def test_a_tile_holds_whole_blocks_and_a_copy_whole_tiles():
@@ -280,6 +419,7 @@ def test_train_steps_under_the_diffusion_loss_match_reference(sdar):
         assert int(m["moe_dropped"]) == 0
         assert float(m["diffusion_masked_share"]) == pytest.approx(b["noised"].mean())
         assert int(state["noised"]) == b["noised"].sum()
+        assert float(m["attn_whole_tile_share"]) == 0.25  # two tiles a copy: 2 of the 8 visited hold earlier blocks only
     np.testing.assert_allclose(losses, ref["loss"], rtol=2e-5)
     for k, want in ref["grad1"].items():
         assert grad1[k] == pytest.approx(want, rel=2e-3, abs=1e-12), k

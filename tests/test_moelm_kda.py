@@ -157,6 +157,7 @@ def test_train_steps_match_reference(solar):
                      for k, v in solar._flatten(state["opt"][0].mu).items()}
         assert int(m["moe_dropped"]) == 0
         assert 0.5 < float(m["kda_decay_mean"]) < 1.0 and float(state["kda_decay"]) == float(m["kda_decay_mean"])
+        assert float(m["attn_whole_tile_share"]) == 0.5  # the softmax layer's three causal tiles a side: 3 of 6
     np.testing.assert_allclose(losses, ref["loss"], rtol=2e-5)
     assert set(grad1) == set(ref["grad1"]) == set(solar.shapes(keys))
     for k, want in ref["grad1"].items():
