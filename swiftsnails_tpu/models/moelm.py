@@ -141,7 +141,7 @@ from swiftsnails_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from swiftsnails_tpu.ops.grouped_matmul import (
     TILE, grouped_swiglu, plan_rows, rows_of_tokens, tokens_of_rows)
 from swiftsnails_tpu.utils.config import Config
-from swiftsnails_tpu.utils.profiling import phase_scope
+from swiftsnails_tpu.utils.profiling import part_scope, phase_scope
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -395,38 +395,43 @@ class MoELMTrainer(SeqLMTrainer):
         """``x [B * L, d]`` -> the block's output, same shape; ``positions
         [L]`` are rotary's (``0..L-1`` if none)."""
         seq = x.shape[0] // b
-        y = rms_norm(x, p["attn_norm"], self.eps)
-        spin = jax.vmap(lambda t: rotary(t, self.rope_theta, positions)) if self.use_rope else (lambda t: t)
-        qkv = self._latent_qkv if self.kv_rank else self._grouped_qkv
-        q, k, v = qkv(p, y, (b, seq), spin)
-        fold = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, seq, t.shape[-1])  # noqa: E731
-        o = flash_attention(fold(q), fold(k), fold(v), block=self.attention_block,
-                            dtype=self.matmul_dtype, diffusion_block=self.block_length or None)
-        o = o.reshape(b, self.n_heads, seq, self.v_dim).transpose(0, 2, 1, 3).reshape(b * seq, -1)
-        if self.attn_gate:
-            o = o * jax.nn.sigmoid(self._mm(y, p["wz"]))
-        return self._mm(o, p["wo"])
+        with part_scope("attn", "in"):
+            y = rms_norm(x, p["attn_norm"], self.eps)
+            spin = jax.vmap(lambda t: rotary(t, self.rope_theta, positions)) if self.use_rope else (lambda t: t)
+            qkv = self._latent_qkv if self.kv_rank else self._grouped_qkv
+            fold = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, seq, t.shape[-1])  # noqa: E731
+            q, k, v = (fold(t) for t in qkv(p, y, (b, seq), spin))
+        with part_scope("attn", "core"):
+            o = flash_attention(q, k, v, block=self.attention_block,
+                                dtype=self.matmul_dtype, diffusion_block=self.block_length or None)
+        with part_scope("attn", "out"):
+            o = o.reshape(b, self.n_heads, seq, self.v_dim).transpose(0, 2, 1, 3).reshape(b * seq, -1)
+            if self.attn_gate:
+                o = o * jax.nn.sigmoid(self._mm(y, p["wz"]))
+            return self._mm(o, p["wo"])
 
     def _kda(self, p, x, b):
         """``x [B * L, d]`` -> (the delta-rule mixer's output, same shape; the
         mean decay ``alpha`` over its tokens, heads and channels). State and
         convolution start at zero with each row and run across its documents."""
         seq, h, hd = x.shape[0] // b, self.kda_heads, self.kda_dim
-        y = rms_norm(x, p["attn_norm"], self.eps)
-        heads = lambda t: t.reshape(b, seq, h, -1)  # noqa: E731
-        conv = lambda c: heads(jax.nn.silu(causal_conv(  # noqa: E731
-            self._mm(y, p["w" + c]).reshape(b, seq, -1), p["conv_" + c])))
-        q, k, v = l2_norm(conv("q")), l2_norm(conv("k")), conv("v")
-        rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
-        g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
-        beta = self.kda_beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
-        fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, seq, -1)  # noqa: E731
-        o = gated_delta_rule(fold(q), fold(k), fold(v), fold(g), fold(heads(beta))[..., 0],
-                             chunk=self.kda_chunk, dtype=self.matmul_dtype)
-        o = o.reshape(b, h, seq, hd).transpose(0, 2, 1, 3).reshape(b * seq, h, hd)
-        gate = jax.nn.sigmoid(self._mm(self._mm(y, p["g_down"]), p["g_up"]))
-        o = rms_norm(o, p["o_norm"], self.eps).reshape(b * seq, -1) * gate
-        return self._mm(o, p["wo"]), jnp.mean(jnp.exp(g))
+        with part_scope("kda", "in"):
+            y = rms_norm(x, p["attn_norm"], self.eps)
+            heads = lambda t: t.reshape(b, seq, h, -1)  # noqa: E731
+            conv = lambda c: heads(jax.nn.silu(causal_conv(  # noqa: E731
+                self._mm(y, p["w" + c]).reshape(b, seq, -1), p["conv_" + c])))
+            q, k, v = l2_norm(conv("q")), l2_norm(conv("k")), conv("v")
+            rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
+            g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
+            beta = self.kda_beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
+            fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, seq, -1)  # noqa: E731
+            folded = fold(q), fold(k), fold(v), fold(g), fold(heads(beta))[..., 0]
+        o = gated_delta_rule(*folded, chunk=self.kda_chunk, dtype=self.matmul_dtype)  # names ``kda`` / ``core`` itself
+        with part_scope("kda", "out"):
+            o = o.reshape(b, h, seq, hd).transpose(0, 2, 1, 3).reshape(b * seq, h, hd)
+            gate = jax.nn.sigmoid(self._mm(self._mm(y, p["g_down"]), p["g_up"]))
+            o = rms_norm(o, p["o_norm"], self.eps).reshape(b * seq, -1) * gate
+            return self._mm(o, p["wo"]), jnp.mean(jnp.exp(g))
 
     def _mix(self, kind, p, x, b, positions=None):
         """A layer's first half: ``x`` + its mixer's output, and what the
@@ -434,9 +439,12 @@ class MoELMTrainer(SeqLMTrainer):
         if kind == "kda":
             with phase_scope("kda"):
                 out, decay = self._kda(p, x, b)
-            return x + out, {"decay": decay}
+                with part_scope("kda", "out"):  # the residual sum goes where ``W_o`` is
+                    return x + out, {"decay": decay}
         with phase_scope("attn"):
-            return x + self._attention(p, x, b, positions), {}
+            out = self._attention(p, x, b, positions)
+            with part_scope("attn", "out"):
+                return x + out, {}
 
     def _swiglu(self, p, prefix, y):
         hidden = jax.nn.silu(self._mm(y, p[prefix + "_gate"])) * self._mm(y, p[prefix + "_up"])
@@ -472,14 +480,18 @@ class MoELMTrainer(SeqLMTrainer):
         held, tile = self.experts_held, self.expert_tile
         local = choices - self.expert_offset
         owner = jnp.where((local >= 0) & (local < held), local, held)
-        with phase_scope("route"):
+        with part_scope("route", "plan"):
             plan = plan_rows(owner, held, tile)
             dropped = jnp.sum(owner < held, dtype=jnp.int32) - jnp.sum(
                 plan.source < owner.size, dtype=jnp.int32)
             live_share = plan.live_tiles / plan.tile_owner.shape[0]
-        out = grouped_swiglu(rows_of_tokens(y, plan, tile), p["experts_gate"], p["experts_up"],
-                             p["experts_down"], plan, tile, self.matmul_dtype)
-        return tokens_of_rows(out, gates, plan, tile), dropped, live_share
+        with part_scope("experts", "gather"):
+            rows = rows_of_tokens(y, plan, tile)
+        with part_scope("experts", "products"):
+            out = grouped_swiglu(rows, p["experts_gate"], p["experts_up"], p["experts_down"], plan, tile,
+                                 self.matmul_dtype)
+        with part_scope("experts", "scatter"):
+            return tokens_of_rows(out, gates, plan, tile), dropped, live_share
 
     def _dense_layer(self, x, p, b, positions=None, kind=None):
         x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
@@ -488,7 +500,7 @@ class MoELMTrainer(SeqLMTrainer):
 
     def _moe_layer(self, x, p, bias, b, positions=None, kind=None):
         x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
-        with phase_scope("route"):
+        with phase_scope("route"), part_scope("route", "score"):
             y = rms_norm(x, p["mlp_norm"], self.eps)
             choices, gates, s = self.route(y, p["router"], bias)
             aux, counts = self._balance(s, choices, b)

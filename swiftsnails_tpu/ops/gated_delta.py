@@ -46,9 +46,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from swiftsnails_tpu.utils.profiling import part_scope
+
 CHUNK = 64  # tokens a chunk
 SUB = 16  # tokens a sub-block of the pairwise sums; CHUNK (or a smaller chunk) is a multiple
-CORE_SCOPE = "phase_kda_core"
+CORE_SCOPE = ("kda", "core")  # of ``utils/profiling.PARTS``: the named scope ``phase_kda_core``
 
 
 def recurrence(q, k, v, g, beta, scale=None):
@@ -134,7 +136,7 @@ def _rule(q, k, v, g, beta, chunk, sub, scale, dtype):
 
 
 def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype):
-    with jax.named_scope(CORE_SCOPE):
+    with part_scope(*CORE_SCOPE):
         parts = _chunk_parts(*(_chunked(a, chunk) for a in (q, k, v, g, beta)), scale, sub, dtype)
 
         def step(state, part):
@@ -149,7 +151,7 @@ def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype):
 
 def _rule_bwd(chunk, sub, scale, dtype, res, do):
     q, k, v, g, beta, entering = res
-    with jax.named_scope(CORE_SCOPE):
+    with part_scope(*CORE_SCOPE):
         parts, parts_vjp = jax.vjp(
             lambda *a: _chunk_parts(*(_chunked(x, chunk) for x in a), scale, sub, dtype), q, k, v, g, beta)
 

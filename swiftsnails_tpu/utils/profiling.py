@@ -11,7 +11,8 @@ keys:
 
 And the names the device timeline carries: :func:`step_annotation` puts the
 step number on the host side of a capture, :func:`phase_scope` names what a
-part of a jitted step is for, in every model alike.
+stretch of a jitted step is for, in every model alike, and :func:`part_scope`
+what stands in front of, inside and behind a phase's kernels.
 """
 
 from __future__ import annotations
@@ -88,8 +89,36 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # scope of each device operation, and ``benchmark/lib/scopes.py`` sums
 # device time by the innermost one. The prefix stays clear of the ``ssn_*``
 # labels that ``telemetry/audit.py`` groups collective bytes by.
+#
+# Four phases of a language-model step have parts (``phase_<phase>_<part>``,
+# set inside the phase's scope around the CALL, so that a ``custom_vjp``'s
+# backward pass and the rematerialised forward carry it as they carry the
+# phase; ``benchmark/lib/parts.py`` reads them). ``attn`` / ``in``: the
+# block's norm, the projections to q, k and v with the latent norm or the
+# per-head norms, rotary and the head-major transposes in front of the
+# kernels. ``attn`` / ``core``: the ``flash_attention`` call alone, its three
+# kernels and what it does around them in XLA. ``attn`` / ``out``: the way
+# back from head-major, the sigmoid gate where there is one, ``W_o`` and the
+# residual sum. ``kda`` / ``in``: the norm, the q, k and v projections with
+# their causal convolutions, SiLU and the l2 norm, the rate's two products
+# and ``g``, beta, the head-major transposes. ``kda`` / ``core``: the chunked
+# recurrence (``ops/gated_delta.py`` sets it, forward and backward).
+# ``kda`` / ``out``: the way back from head-major, the low-rank gate, the
+# gated head norm, ``W_o``, the decay's mean and the residual sum.
+# ``experts`` / ``gather``: tokens to rows, a live tile at a time.
+# ``experts`` / ``products``: ``grouped_swiglu``, the grouped kernels, the
+# stacked weights' casts and ``dw``. ``experts`` / ``scatter``: rows back to
+# tokens under their gates. ``route`` / ``score``: the feed-forward part's
+# norm, the router's product, scores, top-k, gates, the balance loss and the
+# counts. ``route`` / ``plan``: the sort of the held assignments by expert
+# into the row layout (inside ``phase_experts``), ``dropped`` and the live
+# tiles' share.
 PHASES = ("prep", "fused", "pull", "push", "dense",
           "attn", "mlp", "route", "experts", "head", "opt", "noise", "kda")
+PARTS = {"attn": ("in", "core", "out"),
+         "kda": ("in", "core", "out"),
+         "experts": ("gather", "products", "scatter"),
+         "route": ("score", "plan")}
 
 
 def phase_scope(phase: str):
@@ -97,3 +126,10 @@ def phase_scope(phase: str):
     if phase not in PHASES:
         raise ValueError(f"unknown phase {phase!r}; one of {PHASES}")
     return jax.named_scope("phase_" + phase)
+
+
+def part_scope(phase: str, part: str):
+    """``jax.named_scope`` of a pair of :data:`PARTS`: ``phase_<phase>_<part>``."""
+    if part not in PARTS.get(phase, ()):
+        raise ValueError(f"unknown part {phase!r} / {part!r}; one of {PARTS}")
+    return jax.named_scope(f"phase_{phase}_{part}")

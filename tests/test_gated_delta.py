@@ -95,4 +95,4 @@ def test_operations_are_counted_and_the_core_is_scoped():
     args, w = _inputs(16)
     text = jax.jit(jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=8) * w), argnums=(0, 3))).lower(
         *args).as_text(debug_info=True)
-    assert CORE_SCOPE in text and "triangular_solve" in text  # one solve a chunk, no inverse by powers
+    assert "phase_%s_%s" % CORE_SCOPE in text and "triangular_solve" in text  # one solve a chunk, no inverse by powers
