@@ -84,7 +84,13 @@ gain and ``rms_norm_eps``):
   them): the moves and ``grouped_swiglu``'s kernels visit those and no
   other, so between ``rows_of_tokens`` and ``tokens_of_rows`` the rows past
   the live tiles are never written and never read, forward or backward, and
-  hold whatever the memory held.
+  hold whatever the memory held. The tile's height follows the load: the
+  trainer picks it when it reads its shapes, from the assignments a held
+  expert expects a step, ``positions * num_experts_per_tok /
+  router_experts`` (``ops/grouped_matmul.tile_for``: 128, 256 or 512 rows),
+  and logs it; ``moe_tile_fill_share`` is the share of the live tiles' rows
+  that hold an assignment, the rest being padding the kernels multiply and
+  the moves carry.
 * **Balance** (``noaux_tc``): after the gradient step ``b_e += bias_update_rate
   * sign(mean(c) - c_e)``, ``c_e`` the step's tokens assigned to expert ``e``
   of that layer, all ``router_experts``; plus the sequence-wise loss
@@ -129,6 +135,7 @@ width: the deployment's experts), ``experts_held``, ``expert_offset``;
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Dict
 
 import jax
@@ -139,7 +146,7 @@ from swiftsnails_tpu.models.seqlm import SeqLMTrainer, diffusion_inputs, token_l
 from swiftsnails_tpu.ops.flash_attention import BLOCK, flash_attention, tile_classes
 from swiftsnails_tpu.ops.gated_delta import CHUNK, gated_delta_rule
 from swiftsnails_tpu.ops.grouped_matmul import (
-    TILE, grouped_swiglu, plan_rows, rows_of_tokens, tokens_of_rows)
+    TILE, grouped_swiglu, plan_rows, rows_of_tokens, tile_for, tokens_of_rows)
 from swiftsnails_tpu.utils.config import Config
 from swiftsnails_tpu.utils.profiling import part_scope, phase_scope
 
@@ -224,7 +231,9 @@ def init_leaf(key, name: str, shape, std: float):
 @register_model("moelm")
 class MoELMTrainer(SeqLMTrainer):
     name = "moelm"
-    attention_block, expert_tile, kda_chunk = BLOCK, TILE, CHUNK  # the kernels' own; a test sets smaller ones
+    # The kernels' own; a test sets smaller ones. ``expert_tile`` is picked per trainer, by
+    # ``_read_shape``, from the assignments a held expert expects a step (``grouped_matmul.tile_for``).
+    attention_block, expert_tile, kda_chunk = BLOCK, TILE, CHUNK
 
     def _read_shape(self, cfg: Config) -> None:
         g = cfg.get_int
@@ -277,6 +286,14 @@ class MoELMTrainer(SeqLMTrainer):
             raise ValueError(f"scoring_func must be sigmoid or softmax, got {self.scoring}")
         if self.expert_offset + self.experts_held > self.router_experts:
             raise ValueError("the experts held lie outside the router's")
+        expected = self._positions() * self.top_k / self.router_experts
+        self.expert_tile = tile_for(expected)
+        logging.getLogger(__name__).info(
+            "experts: row tile %d for %.1f assignments expected a held expert a step", self.expert_tile, expected)
+
+    def _positions(self) -> int:
+        """The positions a step routes: both copies under block diffusion."""
+        return self.batch_size * self.seq_len * (2 if self.block_length else 1)
 
     # -- parameters ----------------------------------------------------------
 
@@ -351,7 +368,7 @@ class MoELMTrainer(SeqLMTrainer):
         positions routed are the two copies', and ``noised`` counts the step's
         masked tokens)."""
         n_moe = self.n_layers - self.n_dense
-        positions = self.batch_size * self.seq_len * (2 if self.block_length else 1)
+        positions = self._positions()
         state = {"params": params, "opt": self.opt.init(params),
                  "router_bias": jnp.zeros((n_moe, self.router_experts), jnp.float32),
                  "counts": jnp.zeros((n_moe, self.router_experts), jnp.int32),
@@ -473,25 +490,28 @@ class MoELMTrainer(SeqLMTrainer):
         return self.aux_alpha * jnp.mean(jnp.sum(f * share, axis=-1)), hit.sum(axis=0)
 
     def _experts(self, p, y, choices, gates):
-        """(the held experts' part of the layer's output, assignments left
-        out: 0 by construction, counted all the same, the share of the row
-        layout's tiles that are live). Between the two moves every array of
-        the row layout is a kernel's, written and read for the live tiles only."""
+        """(the held experts' part of the layer's output, what the plan
+        counted: ``dropped``, the assignments left out, 0 by construction;
+        ``live_tile_share``, of the row layout's tiles the live ones;
+        ``tile_fill_share``, of the live tiles' rows those that hold an
+        assignment). Between the two moves every array of the row layout is a
+        kernel's, written and read for the live tiles only."""
         held, tile = self.experts_held, self.expert_tile
         local = choices - self.expert_offset
         owner = jnp.where((local >= 0) & (local < held), local, held)
         with part_scope("route", "plan"):
             plan = plan_rows(owner, held, tile)
-            dropped = jnp.sum(owner < held, dtype=jnp.int32) - jnp.sum(
-                plan.source < owner.size, dtype=jnp.int32)
-            live_share = plan.live_tiles / plan.tile_owner.shape[0]
+            counted = {
+                "dropped": jnp.sum(owner < held, dtype=jnp.int32) - jnp.sum(plan.source < owner.size, dtype=jnp.int32),
+                "live_tile_share": plan.live_tiles / plan.tile_owner.shape[0],
+                "tile_fill_share": jnp.sum(plan.counts) / (plan.live_tiles * tile)}
         with part_scope("experts", "gather"):
             rows = rows_of_tokens(y, plan, tile)
         with part_scope("experts", "products"):
             out = grouped_swiglu(rows, p["experts_gate"], p["experts_up"], p["experts_down"], plan, tile,
                                  self.matmul_dtype)
         with part_scope("experts", "scatter"):
-            return tokens_of_rows(out, gates, plan, tile), dropped, live_share
+            return tokens_of_rows(out, gates, plan, tile), counted
 
     def _dense_layer(self, x, p, b, positions=None, kind=None):
         x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
@@ -505,11 +525,10 @@ class MoELMTrainer(SeqLMTrainer):
             choices, gates, s = self.route(y, p["router"], bias)
             aux, counts = self._balance(s, choices, b)
         with phase_scope("experts"):
-            routed, dropped, live_share = self._experts(p, y, choices, gates)
+            routed, planned = self._experts(p, y, choices, gates)
         with phase_scope("mlp"):
             shared = self._swiglu(p, "shared", y) if self.n_shared else 0.0
-        return x + routed + shared, {**counted, "aux": aux, "counts": counts, "choices": choices,
-                                     "dropped": dropped, "live_tile_share": live_share}
+        return x + routed + shared, {**counted, **planned, "aux": aux, "counts": counts, "choices": choices}
 
     def stack(self, params, tokens, router_bias, positions=None):
         """(the stack's output after the last norm [B * L, d], what the
@@ -582,6 +601,7 @@ class MoELMTrainer(SeqLMTrainer):
                 jnp.max(held, axis=-1) / jnp.maximum(jnp.mean(held, axis=-1), 1.0)),
             "moe_dropped": state["dropped"],
             "moe_live_tile_share": jnp.mean(aux["live_tile_share"]),
+            "moe_tile_fill_share": jnp.mean(aux["tile_fill_share"]),
         }
         if set(self.mixers) != {"kda"}:  # a constant of the trace: the mask, the row's positions and the tile
             tiles = tile_classes(self.seq_len * (2 if self.block_length else 1), self.attention_block,

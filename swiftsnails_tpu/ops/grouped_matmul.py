@@ -6,16 +6,35 @@ owner, segments first, a count of the live tiles, no work past it: the
 contract ``parallel/store.merge_duplicate_rows`` + ``live_count`` have for
 table rows). A tile of ``tile`` rows therefore belongs to one expert,
 ``tile_owner[t]``, and the kernel is a plain matmul whose weight block is
-picked by that scalar: no capacity, no dropped row, under any skew; tiles
-past ``live_tiles`` do nothing and fetch nothing. Every expert gets at least
-one tile (all padding if it has no row), so that the weight gradient of an
-expert nobody chose is written as zeros, not left as it was.
+picked by that scalar: no capacity, no dropped row, under any skew; a
+kernel's grid ends at ``live_tiles``, so the tiles past them cost nothing.
+Every expert gets at least one tile (all padding if it has no row), so that
+the weight gradient of an expert nobody chose is written as zeros, not left
+as it was; one with more rows than a tile takes as many as it needs, and
+its consecutive tiles keep the weight block's index, which is then fetched
+once.
+
+**Who picks the tile.** The caller: every function here takes ``tile`` (the
+default :data:`TILE`, 512 rows, is the largest of :data:`TILES`), and one
+layout is built and walked at one tile. :func:`tile_for` is the rule, from
+the one thing that matters: the assignments a held expert expects a call.
+A tile is the least an expert costs, in rows multiplied and rows moved, so
+it should be the smallest that an expert at twice the mean load still fits
+(128, 256, else 512: whole ``(16, 128)`` operand tiles and whole passes of a
+128-wide MXU). ``models/moelm.MoELMTrainer._read_shape`` calls it with
+``positions * num_experts_per_tok / router_experts`` when it reads its
+shapes, logs the choice, and counts what came of it
+(``moe_tile_fill_share``): 51 assignments an expert take 128 rows, 512 or
+768 take 512. At 128 rows a product no longer hides a weight block's
+fetch: the kernels run at the pace of the bytes they must move (an
+expert's weights read, its gradient written), which is what is left when
+nothing is padding.
 
 * :func:`grouped_swiglu` — the held experts' whole feed-forward, ``(silu(x @
   w_gate[e]) * (x @ w_up[e])) @ w_down[e]`` with ``e`` the tile's owner,
   differentiable in the rows and the three weights (``custom_vjp``). Every
   array of the row layout between its argument and its result is written and
-  read by a kernel whose grid step does nothing past the live tiles: the
+  read by a kernel whose grid ends at the live tiles: the
   rounding of an operand, SwiGLU, its derivative and the sum of the two
   products that make ``dx`` happen on a tile in VMEM. Forward: one kernel for
   the gate and up products and SwiGLU (it keeps the two float32 products
@@ -64,7 +83,8 @@ from swiftsnails_tpu.ops.rowdma import on_tpu
 _TRANS_B = (((1,), (1,)), ((), ()))
 _TRANS_A = (((0,), (0,)), ((), ()))
 _VMEM_LIMIT = 96 * 1024 * 1024
-TILE = 512
+TILES = (128, 256, 512)  # the row tiles a caller picks from: whole (16, 128) operand tiles, whole MXU passes
+TILE = TILES[-1]
 
 
 def grouped_flops(live_rows: float, k: int, n: int) -> float:
@@ -85,6 +105,14 @@ class RowPlan(NamedTuple):
     tile_owner: jax.Array
     live_tiles: jax.Array
     counts: jax.Array
+
+
+def tile_for(expected: float) -> int:
+    """The row tile for held experts that expect ``expected`` assignments
+    each a call: the smallest of :data:`TILES` that holds twice that (an
+    expert at twice the mean load still fits one tile), else the largest. An
+    expert with more rows than a tile takes as many tiles as it needs."""
+    return next((tile for tile in TILES if tile >= 2 * expected), TILES[-1])
 
 
 def rows_for(assignments: int, experts: int, tile: int = TILE) -> int:
@@ -253,79 +281,62 @@ def tokens_of_rows(rows, gates, plan: RowPlan, tile: int = TILE):
 
 
 # ------------------------------------------------------------ kernels ---
-# One grid step a tile of the layout; ``tile_owner`` and ``live_tiles`` are
-# prefetched scalars. A step past the live tiles does nothing and its blocks
-# are the last live tile's again (nothing is fetched, nothing written back),
-# so a kernel's time follows the live tiles and the rows past them are never
-# read and never written. Operands are rounded to ``dtype`` in VMEM.
+# One grid step a LIVE tile: the grid's extent over the tiles is
+# ``live_tiles`` itself, a traced scalar (every expert has a tile, so it is
+# at least 1), and ``tile_owner`` is a prefetched scalar array. No step runs
+# for a tile past the live ones, so a kernel's time follows the live tiles
+# at any tile height (a layout of 136 tiles of 128 rows with 8 live is 8
+# steps, not 136) and the rows past them are never read and never written.
+# Operands are rounded to ``dtype`` in VMEM.
 
 
-def _last_live(t, live):
-    """A tile past the live ones is the last live one again: nothing new is
-    fetched, nothing is written back."""
-    return jnp.minimum(t, live[0] - 1)
-
-
-def _mm_kernel(owner_ref, live_ref, *refs, dims, dtype):
+def _mm_kernel(owner_ref, *refs, dims, dtype):
     """``o = x_1 . w_1 + x_2 . w_2 + ...``: ``refs`` are the ``x``, the ``w``, ``o``."""
     del owner_ref
     n = len(refs) // 2
     o_ref = refs[-1]
-
-    @pl.when(pl.program_id(0) < live_ref[0])
-    def _():
-        first, *more = [
-            jax.lax.dot_general(x_ref[...].astype(dtype), w_ref[...], dims,
-                                preferred_element_type=jnp.float32)
-            for x_ref, w_ref in zip(refs[:n], refs[n:])]
-        o_ref[...] = sum(more, first)
+    first, *more = [
+        jax.lax.dot_general(x_ref[...].astype(dtype), w_ref[...], dims,
+                            preferred_element_type=jnp.float32)
+        for x_ref, w_ref in zip(refs[:n], refs[n:])]
+    o_ref[...] = sum(more, first)
 
 
-def _swiglu_kernel(owner_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref, *kept_refs, dtype):
+def _swiglu_kernel(owner_ref, x_ref, wg_ref, wu_ref, h_ref, *kept_refs, dtype):
     """``h = silu(x . w_gate) * (x . w_up)`` rounded to ``dtype``; where the
     backward pass is going to read them, the two products (float32) and the
     rounded ``x`` too."""
     del owner_ref
-
-    @pl.when(pl.program_id(0) < live_ref[0])
-    def _():
-        x = x_ref[...].astype(dtype)
-        g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
-        h_ref[...] = (jax.nn.silu(g) * u).astype(dtype)
-        for ref, value in zip(kept_refs, (g, u, x)):
-            ref[...] = value
+    x = x_ref[...].astype(dtype)
+    g = jnp.dot(x, wg_ref[...], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[...], preferred_element_type=jnp.float32)
+    h_ref[...] = (jax.nn.silu(g) * u).astype(dtype)
+    for ref, value in zip(kept_refs, (g, u, x)):
+        ref[...] = value
 
 
-def _dswiglu_kernel(owner_ref, live_ref, dy_ref, g_ref, u_ref, wd_ref, dg_ref, du_ref, *, dtype):
+def _dswiglu_kernel(owner_ref, dy_ref, g_ref, u_ref, wd_ref, dg_ref, du_ref, *, dtype):
     """``dh = dy . w_down.T``, then SwiGLU's derivative on it: ``dg = dh * u *
     silu'(g)`` and ``du = dh * silu(g)``, each rounded to ``dtype``."""
     del owner_ref
-
-    @pl.when(pl.program_id(0) < live_ref[0])
-    def _():
-        dh = jax.lax.dot_general(dy_ref[...].astype(dtype), wd_ref[...], _TRANS_B,
-                                 preferred_element_type=jnp.float32)
-        g, u = g_ref[...], u_ref[...]
-        s = jax.nn.sigmoid(g)
-        dg_ref[...] = (dh * u * (s * (1.0 + g * (1.0 - s)))).astype(dtype)
-        du_ref[...] = (dh * (g * s)).astype(dtype)
+    dh = jax.lax.dot_general(dy_ref[...].astype(dtype), wd_ref[...], _TRANS_B,
+                             preferred_element_type=jnp.float32)
+    g, u = g_ref[...], u_ref[...]
+    s = jax.nn.sigmoid(g)
+    dg_ref[...] = (dh * u * (s * (1.0 + g * (1.0 - s)))).astype(dtype)
+    du_ref[...] = (dh * (g * s)).astype(dtype)
 
 
-def _dw_kernel(owner_ref, live_ref, x_ref, dy_ref, o_ref, *, dtype):
+def _dw_kernel(owner_ref, x_ref, dy_ref, o_ref, *, dtype):
     t = pl.program_id(2)
-    live = t < live_ref[0]
-    first = jnp.logical_or(t == 0, owner_ref[t] != owner_ref[jnp.maximum(t - 1, 0)])
 
-    @pl.when(jnp.logical_and(live, first))
+    @pl.when(jnp.logical_or(t == 0, owner_ref[t] != owner_ref[jnp.maximum(t - 1, 0)]))
     def _():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(live)
-    def _():
-        o_ref[...] += jax.lax.dot_general(
-            x_ref[...].astype(dtype), dy_ref[...].astype(dtype), _TRANS_A,
-            preferred_element_type=jnp.float32)
+    o_ref[...] += jax.lax.dot_general(
+        x_ref[...].astype(dtype), dy_ref[...].astype(dtype), _TRANS_A,
+        preferred_element_type=jnp.float32)
 
 
 def _params(interpret, semantics):
@@ -353,24 +364,23 @@ def _by_tile(kernel, name, tiles: _Tiles, rows, weights, outs):
     assert n_rows % tiles.tile == 0 and all(r.shape[0] == n_rows for r in rows)
 
     def row_spec(width):
-        return pl.BlockSpec((tiles.tile, width), lambda t, own, live: (_last_live(t, live), 0))
+        return pl.BlockSpec((tiles.tile, width), lambda t, own: (t, 0))
 
     def weight_spec(w):
-        return pl.BlockSpec((None,) + w.shape[1:],
-                            lambda t, own, live: (own[_last_live(t, live)], 0, 0))
+        return pl.BlockSpec((None,) + w.shape[1:], lambda t, own: (own[t], 0, 0))
 
     return pl.pallas_call(
         functools.partial(kernel, dtype=tiles.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n_rows // tiles.tile,),
+            num_scalar_prefetch=1,
+            grid=(tiles.live,),
             in_specs=[row_spec(r.shape[1]) for r in rows] + [weight_spec(w) for w in weights],
             out_specs=[row_spec(width) for width, _ in outs],
         ),
         out_shape=[jax.ShapeDtypeStruct((n_rows, width), dt) for width, dt in outs],
         name=name,
         **_params(tiles.interpret, ("arbitrary",)),
-    )(tiles.owner, tiles.live.reshape(1), *rows, *weights)
+    )(tiles.owner, *rows, *weights)
 
 
 def _mm(xs, ws, tiles: _Tiles, transposed=False):
@@ -395,28 +405,32 @@ def _split(width: int, most: int) -> int:
 def _dw(x, dy, experts, tiles: _Tiles):
     """``dw [E, K, N]``: per expert, ``x_tile.T @ dy_tile`` summed over its
     tiles. The result is cut along whichever of K and N is the wider, so
-    that a block stays resident while the expert's tiles go by."""
-    rows, k = x.shape
-    n = dy.shape[1]
+    that a block stays resident while the expert's tiles go by; the cut is
+    512 wide at the largest tile and widens as the tile shrinks (a row
+    block ``[tile, cut]`` holds as much at any tile), because every block of
+    the cut is one more pass over the live tiles: at 128 rows a product is
+    too short to hide a pass's steps behind, and the result's write, once an
+    expert and block, sets the pace."""
+    k, n = x.shape[1], dy.shape[1]
     tile = tiles.tile
-    bk, bn = (_split(k, 512), n) if k >= n else (k, _split(n, 512))
+    most = 512 * max(1, TILE // tile)
+    bk, bn = (_split(k, most), n) if k >= n else (k, _split(n, most))
 
     return pl.pallas_call(
         functools.partial(_dw_kernel, dtype=tiles.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(k // bk, n // bn, rows // tile),
+            num_scalar_prefetch=1,
+            grid=(k // bk, n // bn, tiles.live),
             in_specs=[
-                pl.BlockSpec((tile, bk), lambda i, j, t, own, live: (_last_live(t, live), i)),
-                pl.BlockSpec((tile, bn), lambda i, j, t, own, live: (_last_live(t, live), j)),
+                pl.BlockSpec((tile, bk), lambda i, j, t, own: (t, i)),
+                pl.BlockSpec((tile, bn), lambda i, j, t, own: (t, j)),
             ],
-            out_specs=pl.BlockSpec(
-                (None, bk, bn), lambda i, j, t, own, live: (own[_last_live(t, live)], i, j)),
+            out_specs=pl.BlockSpec((None, bk, bn), lambda i, j, t, own: (own[t], i, j)),
         ),
         out_shape=jax.ShapeDtypeStruct((experts, k, n), jnp.float32),
         name="grouped_matmul_dw",
         **_params(tiles.interpret, ("parallel", "parallel", "arbitrary")),
-    )(tiles.owner, tiles.live.reshape(1), x, dy)
+    )(tiles.owner, x, dy)
 
 
 def _tiles(plan: RowPlan, tile, dtype, interpret):

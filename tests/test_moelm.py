@@ -147,8 +147,8 @@ def test_eight_shares_add_up_to_the_uncut_layer(moonlight):
         tr, _ = _trainer(batch_size=1, experts_held=2, expert_offset=2 * share)
         mine = {k: (v[2 * share: 2 * share + 2] if k.startswith("experts_") else v)
                 for k, v in p.items()}
-        routed, dropped, live_share = tr._experts(mine, y, choices, gates)
-        assert int(dropped) == 0 and 0 < float(live_share) <= 1
+        routed, planned = tr._experts(mine, y, choices, gates)
+        assert int(planned["dropped"]) == 0 and 0 < float(planned["live_tile_share"]) <= 1
         total = total + routed
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)
 
@@ -160,12 +160,13 @@ def test_dropless_when_every_token_goes_to_one_held_expert():
     tokens = y.shape[0]
     choices = jnp.tile(jnp.asarray([[5, 0, 15]], jnp.int32), (tokens, 1))  # only 5 is held (4..7)
     gates = jnp.full((tokens, 3), 0.5)
-    out, dropped, live_share = tr._experts(p, y, choices, gates)
+    out, planned = tr._experts(p, y, choices, gates)
     # the one expert's 64 rows in 8 tiles of 8, a tile of padding for each of the other three
-    assert float(live_share) == pytest.approx(11 / (rows_for(tokens * 3, 4, 8) // 8))
+    assert float(planned["live_tile_share"]) == pytest.approx(11 / (rows_for(tokens * 3, 4, 8) // 8))
+    assert float(planned["tile_fill_share"]) == pytest.approx(8 / 11)
     e = 5 - tr.expert_offset
     want = 0.5 * (jax.nn.silu(y @ p["experts_gate"][e]) * (y @ p["experts_up"][e])) @ p["experts_down"][e]
-    assert int(dropped) == 0
+    assert int(planned["dropped"]) == 0
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4, atol=2e-5)
     none = plan_rows(jnp.full((tokens, 3), 4, jnp.int32), 4, 8)  # nothing held: a tile of padding an expert
     assert int(none.live_tiles) == 4 and int((none.source < tokens * 3).sum()) == 0

@@ -22,6 +22,9 @@ SEQ, HIDDEN = 8192, 2048
 MOONLIGHT = (16, 16, 192, 128, None, 1408, 8, 6)  # qk_nope 128 + qk_rope 64; v_head_dim 128; causal
 SDAR = (32, 4, 128, 128, 4, 768, 16, 8)  # 8 query heads to a key/value head; 4,096 tokens in two copies
 SOLAR_SOFTMAX = (8, 1, 128, 128, None)  # Solar-Open2-250B's softmax layer as its cell holds it: 8 heads to 1, causal
+# the expert layers: (experts' width, held, a token, the router's experts, hidden, positions a step)
+EXPERTS = {"moonlight": MOONLIGHT[5:] + (64, HIDDEN, SEQ), "sdar": SDAR[5:] + (128, HIDDEN, SEQ),
+           "solar": (1280, 8, 8, 320, 4096, 2048)}
 
 
 @pytest.fixture(scope="module")
@@ -82,29 +85,34 @@ def test_attention_kernels_compile_at_published_widths(one_chip, no_cache, width
 
 
 @pytest.mark.parametrize("fused", [False, True], ids=["products", "fused"])
-@pytest.mark.parametrize("widths", [MOONLIGHT, SDAR], ids=["moonlight", "sdar"])
-def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkeypatch, widths, fused):
+@pytest.mark.parametrize("cell", ["moonlight", "sdar", "solar"])
+def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkeypatch, cell, fused):
+    """At the tile the rule gives each cell's load: 512 rows for Moonlight
+    and SDAR, 128 for Solar (a grid that ends at the live tiles, ``dw``'s
+    wider cut)."""
     monkeypatch.setattr(gm, "on_tpu", lambda: True)  # the backend here is the CPU; the moves' buffers are kernels' too
-    EXPERT_WIDTH, HELD, TOP_K = widths[5:]
-    assignments = SEQ * TOP_K  # every position could choose all of its experts among those held
-    rows = gm.rows_for(assignments, HELD)
-    assert rows == (assignments // gm.TILE + HELD) * gm.TILE
+    EXPERT_WIDTH, HELD, TOP_K, ROUTED, hidden, seq = EXPERTS[cell]
+    tile = gm.tile_for(seq * TOP_K / ROUTED)
+    assert tile == (128 if cell == "solar" else gm.TILE)
+    assignments = seq * TOP_K  # every position could choose all of its experts among those held
+    rows = gm.rows_for(assignments, HELD, tile)
+    assert rows == (assignments // tile + HELD) * tile
 
     def loss(feed_forward, y, w_gate, w_up, w_down, gates, owner):  # the moves ride along
-        plan = gm.plan_rows(owner, HELD)
-        out = feed_forward(gm.rows_of_tokens(y, plan), w_gate, w_up, w_down, plan)
-        return jnp.sum(gm.tokens_of_rows(out, gates, plan))
+        plan = gm.plan_rows(owner, HELD, tile)
+        out = feed_forward(gm.rows_of_tokens(y, plan, tile), w_gate, w_up, w_down, plan, tile)
+        return jnp.sum(gm.tokens_of_rows(out, gates, plan, tile))
 
-    def products(rows, w_gate, w_up, w_down, plan):
-        mm = functools.partial(gm.grouped_matmul, plan=plan, interpret=False)
+    def products(rows, w_gate, w_up, w_down, plan, tile):
+        mm = functools.partial(gm.grouped_matmul, plan=plan, tile=tile, interpret=False)
         return mm(jax.nn.silu(mm(rows, w_gate)) * mm(rows, w_up), w_down)
 
     def compiled(feed_forward):
-        f32, wide = jnp.float32, (HELD, HIDDEN, EXPERT_WIDTH)
+        f32, wide = jnp.float32, (HELD, hidden, EXPERT_WIDTH)
         return _compiled(jax.grad(functools.partial(loss, feed_forward), (0, 1, 2, 3, 4)), one_chip,
-                         ((SEQ, HIDDEN), f32), (wide, f32), (wide, f32),
-                         ((HELD, EXPERT_WIDTH, HIDDEN), f32), ((SEQ, TOP_K), f32),
-                         ((SEQ, TOP_K), jnp.int32))
+                         ((seq, hidden), f32), (wide, f32), (wide, f32),
+                         ((HELD, EXPERT_WIDTH, hidden), f32), ((seq, TOP_K), f32),
+                         ((seq, TOP_K), jnp.int32))
 
     unfused = compiled(products)
     text = unfused.as_text()

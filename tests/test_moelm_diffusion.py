@@ -325,8 +325,8 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(sdar):
         tr, _ = _trainer(batch_size=1, experts_held=2, expert_offset=2 * share)
         mine = {k: (v[2 * share: 2 * share + 2] if k.startswith("experts_") else v)
                 for k, v in p.items()}
-        routed, dropped, _ = tr._experts(mine, y, choices, gates)
-        assert int(dropped) == 0
+        routed, planned = tr._experts(mine, y, choices, gates)
+        assert int(planned["dropped"]) == 0
         total = total + routed
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)
 
@@ -337,10 +337,10 @@ def test_dropless_when_every_choice_of_every_position_is_held():
     y = _x(tr, 2 * tr.seq_len)
     choices = jnp.tile(jnp.asarray([[4, 6, 7]], jnp.int32), (y.shape[0], 1))  # 4..7 are held
     gates = jnp.tile(jnp.asarray([[0.5, 0.3, 0.2]]), (y.shape[0], 1))
-    out, dropped, _ = tr._experts(p, y, choices, gates)
+    out, planned = tr._experts(p, y, choices, gates)
     want = sum(g * (jax.nn.silu(y @ p["experts_gate"][e]) * (y @ p["experts_up"][e])) @ p["experts_down"][e]
                for g, e in ((0.5, 0), (0.3, 2), (0.2, 3)))
-    assert int(dropped) == 0
+    assert int(planned["dropped"]) == 0
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-4, atol=2e-5)
 
 

@@ -263,8 +263,8 @@ def test_the_shares_of_the_experts_and_the_shared_expert_once_add_up_to_the_uncu
     for share in range(4):
         tr, _ = _trainer(batch_size=1, experts_held=4, expert_offset=4 * share)
         mine = {k: (v[4 * share: 4 * share + 4] if k.startswith("experts_") else v) for k, v in p.items()}
-        routed, dropped, _ = tr._experts(mine, y, choices, gates)
-        assert int(dropped) == 0
+        routed, planned = tr._experts(mine, y, choices, gates)
+        assert int(planned["dropped"]) == 0
         total = total + routed
     np.testing.assert_allclose(np.asarray(total), np.asarray(want), rtol=2e-4, atol=2e-5)
 
