@@ -1,5 +1,5 @@
 """The gated delta rule with a decay per channel (Kimi Delta Attention,
-arXiv:2510.26692), by chunks: the recurrent mixer beside
+arXiv:2510.26692), by chunks, as Mosaic kernels: the recurrent mixer beside
 ``ops/flash_attention.py``'s softmax.
 
 Per head, with ``q_t, k_t`` of width K, ``v_t`` of width V, a log-decay
@@ -28,29 +28,62 @@ scaled through ``G`` at the row block's first token (``exp(G_r - ref) *
 exp(ref - G_j)``, each factor at most 1) and multiplied on the MXU.
 
 What is float32: ``g``, its running sums, every exponential, the triangular
-solve, the state. The operands of the matrix products are rounded to
+solve, the state. The operands of the other matrix products are rounded to
 ``dtype`` and accumulated in float32, forward and backward.
 
-Everything that is one chunk's alone (:func:`_chunk_parts`) runs for all
-chunks at once; :func:`_carry` is what passes from chunk to chunk. The
-backward pass keeps the inputs and one ``[K, V]`` state a chunk and head
-(never one a token), computes the chunks' parts again and walks the chunks
-backwards from the states entering them. It is XLA's throughout (named scope
-``phase_kda_core``, which ``benchmark/metrics/kernel.kda_roofline.py`` reads).
+Two kernels, each over the grid (group of heads, chunk), a group's chunks in
+order; a grid step holds ``HEADS`` heads' chunk in VMEM and nothing of a
+chunk's own goes out to HBM:
+
+* ``gated_delta_fwd`` computes the chunk's parts (:func:`_chunk`: the running
+  sums, :func:`_pairs`, the solve by :func:`_inverse`), then carries the
+  state, which stays in a VMEM scratch from chunk to chunk (:func:`_carry`),
+  writes ``o`` and keeps the state entering each chunk for the backward pass.
+* ``gated_delta_bwd`` walks the chunks last first from the states kept,
+  computes each chunk's parts again, carries ``dS`` in VMEM and gives the
+  gradients of all five inputs: through the carry, through the solve (a
+  product with the inverse's transpose) and through the pairwise sums.
+
+The pairwise sums inside a sub-block are the work of the VPU: there two
+heads' chunks lie side by side on the lanes, channels down the sublanes
+(:func:`_pack`), so that a token's sum over its channels is a sum down the
+sublanes, and a key ``d`` tokens back is a roll of the lanes. The solve
+(:func:`_inverse`) is float32: the unit-lower diagonal blocks of ``SUB`` by
+forward substitution on the VPU, the blocks below joined by products on the
+MXU at float32 precision, ``(I + M)^-1 = (I - M)(I + M^2)`` for the strictly
+block-lower rest ``M``: each such product is six passes of the MXU.
+
+All of it stands under the named scope ``phase_kda_core``, which
+``benchmark/metrics/kernel.kda_roofline.py`` reads; besides the two kernels
+that holds only what XLA does at the call's edge (a head count made even by
+padding, ``beta``'s trailing axis). Off the chip the kernels run in interpret
+mode (``rowdma.on_tpu``).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from swiftsnails_tpu.ops.rowdma import on_tpu
 from swiftsnails_tpu.utils.profiling import part_scope
 
 CHUNK = 64  # tokens a chunk
 SUB = 16  # tokens a sub-block of the pairwise sums; CHUNK (or a smaller chunk) is a multiple
+HEADS = 8  # heads a grid step at most: the walk's fixed cost a step is paid once for all of them
 CORE_SCOPE = ("kda", "core")  # of ``utils/profiling.PARTS``: the named scope ``phase_kda_core``
+
+_VMEM_LIMIT = 64 * 1024 * 1024
+_HIGHEST = jax.lax.Precision.HIGHEST
+# batched over the leading (head) axis: a @ b, a @ b.T, a.T @ b
+_NN = (((2,), (1,)), ((0,), (0,)))
+_NT = (((2,), (2,)), ((0,), (0,)))
+_TN = (((1,), (1,)), ((0,), (0,)))
 
 
 def recurrence(q, k, v, g, beta, scale=None):
@@ -71,99 +104,356 @@ def recurrence(q, k, v, g, beta, scale=None):
     return jnp.moveaxis(o, 0, 1)
 
 
-def _dot(spec, a, b, dtype):
-    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype), preferred_element_type=jnp.float32)
+def _dot(a, b, dims, dtype):
+    """A product a head, operands rounded to ``dtype``, float32 sums (float32
+    operands at float32 precision)."""
+    precision = _HIGHEST if jnp.dtype(dtype) == jnp.float32 else None
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype), dims, precision=precision,
+                               preferred_element_type=jnp.float32)
 
 
-def _pairs(x, k, run, sub, dtype):
+def _exact(a, b, dims):
+    """A product a head of float32 operands at float32 precision: the running
+    sums and the solve."""
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _ones_where(mask):
+    return jnp.where(mask, 1.0, 0.0).astype(jnp.float32)
+
+
+def _pack(x):
+    """``[h, C, W]`` -> ``[h / 2, W, 2 C]``: two heads' tokens side by side on
+    the lanes, the width down the sublanes."""
+    h, c, w = x.shape
+    return jnp.swapaxes(x.reshape(h // 2, 2 * c, w), 1, 2)
+
+
+def _unpack(x):
+    """:func:`_pack`'s inverse."""
+    p, w, c2 = x.shape
+    return jnp.swapaxes(x, 1, 2).reshape(2 * p, c2 // 2, w)
+
+
+class _Walk(NamedTuple):
+    """The inside sub-block walk's view of a chunk (:func:`_pack`'s layout):
+    for ``d`` tokens back, the keys ``d`` back and the decay between."""
+
+    kT: jax.Array  # [h / 2, K, 2 C]
+    runT: jax.Array
+    sub: int
+
+    def back(self, d):
+        """(the keys ``d`` tokens back, ``exp(G_r - G_(r-d))``, 0 where the
+        key lies in another sub-block; ``None`` for ``d = 0``)."""
+        if not d:
+            return self.kT, None
+        lane = _iota(self.kT.shape, 2) % self.sub
+        rolled = pltpu.roll(self.runT, d, 2)
+        return pltpu.roll(self.kT, d, 2), jnp.exp(jnp.where(lane >= d, self.runT - rolled, -jnp.inf))
+
+    def diagonal(self, d):
+        """Where in ``[h / 2, C, 2 C]`` (a pair matrix's transpose, packed)
+        the pair (token ``r``, token ``r - d``) lies."""
+        c2 = self.kT.shape[2]
+        shape = (1, c2 // 2, c2)
+        return _iota(shape, 1) == _iota(shape, 2) % (c2 // 2) - d
+
+
+class _Across(NamedTuple):
+    """The pairs between sub-blocks: the rows of block ``i`` against the keys
+    before it, for every ``i > 0`` at once (``(n - 1) K`` wide)."""
+
+    f: jax.Array  # exp(G_r - ref(r)) [h, C, K]
+    keys: jax.Array  # [k exp(ref_i - G) for i > 0] [h, C, (n - 1) K], 0 at and after block i
+    decays: tuple  # exp(ref_i - G), 0 at and after block i, each [h, C, K]
+    blocks: tuple  # the rows of block i, each [1, C, K]
+
+    def rows(self, x):
+        """``x exp(G - ref)``, block ``i``'s rows in the ``i``-th strip."""
+        scaled = x * self.f
+        return jnp.concatenate([jnp.where(b, scaled, 0.0) for b in self.blocks], axis=2)
+
+
+def _across(k, run, sub):
+    h, c, w = k.shape
+    rows = _iota((1, c, w), 1)
+    firsts = [run[:, i * sub:i * sub + 1, :] for i in range(c // sub)]
+    ref = jnp.concatenate([jnp.broadcast_to(f, (h, sub, w)) for f in firsts], axis=1)
+    decays = tuple(jnp.exp(jnp.where(rows < i * sub, f - run, -jnp.inf)) for i, f in enumerate(firsts) if i)
+    return _Across(jnp.exp(run - ref), jnp.concatenate([k * e for e in decays], axis=2), decays,
+                   tuple(rows // sub == i for i in range(1, c // sub)))
+
+
+def _pairs(xs, k, run, sub, dtype):
     """``sum_c x_rc k_jc exp(G_rc - G_jc)`` for ``j <= r``, 0 above the
-    diagonal: ``x, k, run [..., C, K]`` (``run`` the running sum ``G``, never
-    rising along ``C``) -> ``[..., C, C]``."""
-    *lead, chunk, width = x.shape
-    n = chunk // sub
-    blocks = lambda a: a.reshape(*lead, n, sub, width)  # noqa: E731
-    xb, kb, gb = blocks(x), blocks(k), blocks(run)
-    ref = gb[..., :1, :]  # G at each sub-block's first token
-    # between sub-blocks, through the row block's reference: [..., i, r, j-block, s]
-    earlier = (jnp.arange(n)[:, None] > jnp.arange(n)[None, :])[:, :, None, None]
-    down = jnp.exp(jnp.where(earlier, ref[..., :, None, :, :] - gb[..., None, :, :, :], -jnp.inf))
-    across = _dot("...irc,...ijsc->...irjs", xb * jnp.exp(gb - ref), kb[..., None, :, :, :] * down, dtype)
-    # inside a sub-block, the differences themselves: [..., i, r, s]
-    low = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
-    decay = jnp.exp(jnp.where(low, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
-    inside = jnp.sum(xb[..., :, None, :] * kb[..., None, :, :] * decay, axis=-1)
-    own = jnp.eye(n, dtype=bool)[:, None, :, None]
-    return jnp.where(own, inside[..., :, :, None, :], across).reshape(*lead, chunk, chunk)
+    diagonal, for each ``x`` of ``xs``: ``x, k, run [h, C, K]`` (``h`` even,
+    ``run`` the running sum ``G``, never rising along ``C``) -> ``[h, C, C]``
+    each; with the walk and the strips, which the backward pass reads."""
+    h, c, _ = k.shape
+    walk = _Walk(_pack(k), _pack(run), sub)
+    xT = [_pack(x) for x in xs]
+    inside = [jnp.zeros((h // 2, c, 2 * c), jnp.float32) for _ in xs]
+    for d in range(walk.sub):
+        keys, decay = walk.back(d)
+        if decay is not None:
+            keys = keys * decay
+        at = walk.diagonal(d)
+        inside = [jnp.where(at, jnp.sum(x * keys, axis=1, keepdims=True), a) for x, a in zip(xT, inside)]
+    sums = [_unpack(a) for a in inside]
+    across = None
+    if c > sub:
+        across = _across(k, run, sub)
+        sums = [s + _dot(across.rows(x), across.keys, _NT, dtype) for x, s in zip(xs, sums)]
+    return sums, walk, xT, across
 
 
-def _chunk_parts(q, k, v, g, beta, scale, sub, dtype):
-    """What a chunk needs of its own tokens alone, for every chunk at once
-    (all ``[H, N, C, ...]``): ``q * exp(G) * scale``, the solve's ``W`` and
-    ``U``, the pairwise query-key sums, ``k * exp(G_C - G)`` and ``exp(G_C)``."""
-    run = jnp.cumsum(g, axis=-2)
-    last, since_start = run[..., -1:, :], jnp.exp(run)
-    k_in = k * since_start
-    strictly = jnp.tril(jnp.ones((q.shape[-2],) * 2, bool), -1)
-    a = jnp.where(strictly, _pairs(k, k, run, sub, dtype), 0.0) * beta[..., None]
-    solved = jax.lax.linalg.triangular_solve(  # (I + A) X = beta [K exp(G) | V]; A's diagonal is not read
-        a, beta[..., None] * jnp.concatenate([k_in, v], axis=-1),
-        left_side=True, lower=True, unit_diagonal=True)
-    width = k.shape[-1]
-    return (q * since_start * scale, solved[..., :width], solved[..., width:],
-            _pairs(q, k, run, sub, dtype) * scale, k * jnp.exp(last - run), jnp.exp(last[..., 0, :]))
+def _pairs_bwd(d_sums, xs, k, walk, xT, across, dtype):
+    """The gradients of :func:`_pairs`' sums ``d_sums`` (each ``[h, C, C]``,
+    0 where no pair is) -> (the gradient of each ``x``, of ``k``, of ``run``).
+    The reference token of a strip cancels out of every pair, and is held."""
+    d_xT = [jnp.zeros_like(x) for x in xT]
+    d_kT, d_runT = jnp.zeros_like(walk.kT), jnp.zeros_like(walk.kT)
+    lanes = walk.kT.shape[2]
+    packed = [_pack(d) for d in d_sums]
+    for d in range(walk.sub):
+        at = walk.diagonal(d)
+        weights = [jnp.sum(jnp.where(at, p, 0.0), axis=1, keepdims=True) for p in packed]  # d_sum[r, r - d]
+        keys, decay = walk.back(d)
+        row_side = keys if decay is None else keys * decay
+        d_xT = [dx + w * row_side for dx, w in zip(d_xT, weights)]
+        key_side = sum(w * x for w, x in zip(weights, xT))
+        if decay is None:
+            d_kT = d_kT + key_side
+            continue
+        key_side = key_side * decay
+        term = key_side * keys
+        d_kT = d_kT + pltpu.roll(key_side, lanes - d, 2)
+        d_runT = d_runT + term - pltpu.roll(term, lanes - d, 2)
+    d_xs = [_unpack(dx) for dx in d_xT]
+    d_k, d_run = _unpack(d_kT), _unpack(d_runT)
+    if across is not None:
+        d_keys, w = 0.0, k.shape[2]
+        for i, (d, x) in enumerate(zip(d_sums, xs)):
+            strips = _dot(d, across.keys, _NN, dtype)
+            d_keys = d_keys + _dot(d, across.rows(x), _TN, dtype)
+            d_scaled = sum(jnp.where(b, strips[..., j * w:(j + 1) * w], 0.0) for j, b in enumerate(across.blocks))
+            d_xs[i] = d_xs[i] + d_scaled * across.f
+            d_run = d_run + d_scaled * x * across.f
+        for j, e in enumerate(across.decays):
+            d_e = d_keys[..., j * w:(j + 1) * w] * e
+            d_k = d_k + d_e
+            d_run = d_run - d_e * k
+    return d_xs, d_k, d_run
 
 
-def _carry(dtype, state, parts):
-    """One chunk, all heads: the state entering it ``[H, K, V]`` and its
-    parts ``[H, C, ...]`` -> (the state leaving it, its outputs ``[H, C, V]``)."""
-    q_in, w, u, qk, k_out, decay = parts
-    fresh = u - _dot("hck,hkv->hcv", w, state, dtype)
-    o = _dot("hck,hkv->hcv", q_in, state, dtype) + _dot("hcj,hjv->hcv", qk, fresh, dtype)
-    return decay[..., None] * state + _dot("hck,hcv->hkv", k_out, fresh, dtype), o
+def _power_inverse(n, order, eye):
+    """``(I + n)^-1`` for ``n^order = 0``: ``(I - n)(I + n^2)(I + n^4)...``."""
+    out, power, span = eye - n, n, 2
+    while span < order:
+        power = _exact(power, power, _NN)
+        out = _exact(out, eye + power, _NN)
+        span *= 2
+    return out
 
 
-def _chunked(a, chunk):
-    return a.reshape(a.shape[0], a.shape[1] // chunk, chunk, *a.shape[2:])
+def _inverse(a, sub):
+    """``(I + a)^-1`` for ``a [h, C, C]`` strictly lower, float32: the
+    diagonal blocks of ``sub`` by forward substitution, a row of every block
+    a step on the VPU; the blocks below them by products on the MXU: with
+    ``N`` the diagonal blocks of ``a`` and ``M = (I + N)^-1 (a - N)``,
+    strictly block-lower, ``(I + a)^-1 = (I + M)^-1 (I + N)^-1``."""
+    h, c, _ = a.shape
+    rows, cols = _iota((1, c, c), 1), _iota((1, c, c), 2)
+    eye = _ones_where(rows == cols)
+    own = jnp.where(rows // sub == cols // sub, a, 0.0)
+    inv = jnp.broadcast_to(eye, (h, c, c))
+    for j in range(sub - 1):  # row j of each block is final: take it out of the rows below it in the block
+        inv = inv - jnp.concatenate([own[:, b:b + sub, b + j:b + j + 1] * inv[:, b + j:b + j + 1, :]
+                                     for b in range(0, c, sub)], axis=1)
+    if c > sub:
+        inv = _exact(_power_inverse(_exact(inv, a - own, _NN), c // sub, eye), inv, _NN)
+    return inv
 
 
-def _by_chunk(parts):  # [H, N, ...] -> [N, H, ...]: the scan's order
-    return tuple(jnp.moveaxis(p, 1, 0) for p in parts)
+class _Parts(NamedTuple):
+    """What a chunk needs of its own tokens alone (``[h, C, ...]``)."""
+
+    run: jax.Array  # G
+    grow: jax.Array  # exp(G)
+    q_in: jax.Array  # q exp(G) scale
+    k_in: jax.Array  # k exp(G)
+    k_out: jax.Array  # k exp(G_C - G)
+    decay: jax.Array  # exp(G_C) [h, 1, K]
+    akk: jax.Array  # the keys' pairwise sums below the diagonal
+    aqk: jax.Array  # the queries' against the keys, at and below, times scale
+    inv: jax.Array  # (I + diag(beta) akk)^-1
+    x: jax.Array  # [W | U]
+    pairs: tuple  # (walk, packed rows, strips): what the backward pass of the sums reads
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _rule(q, k, v, g, beta, chunk, sub, scale, dtype):
-    return _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype)[0]
+def _chunk(q, k, v, g, beta, scale, sub, dtype):
+    """A chunk's parts for ``h`` heads: ``q, k, g [h, C, K]``, ``v [h, C, V]``,
+    ``beta [h, C, 1]``."""
+    c = q.shape[1]
+    rows, cols = _iota((1, c, c), 1), _iota((1, c, c), 2)
+    lower = jnp.broadcast_to(_ones_where(cols <= rows), (q.shape[0], c, c))
+    run = _exact(lower, g, _NN)  # the running sums, at float32 precision
+    last, grow = run[:, c - 1:, :], jnp.exp(run)
+    k_in = k * grow
+    (akk, aqk), walk, xT, across = _pairs((k, q), k, run, sub, dtype)
+    akk = jnp.where(cols < rows, akk, 0.0)
+    inv = _inverse(beta * akk, sub)
+    x = _exact(inv, beta * jnp.concatenate([k_in, v], axis=2), _NN)
+    return _Parts(run, grow, q * grow * scale, k_in, k * jnp.exp(last - run), jnp.exp(last),
+                  akk, aqk * scale, inv, x, (walk, xT, across))
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype):
+def _carry(p, state, dtype):
+    """One chunk: the state entering it (transposed, ``[h, V, K]``) -> (its
+    outputs ``[h, C, V]``, the state leaving it)."""
+    width = p.k_in.shape[2]
+    fresh = p.x[..., width:] - _dot(p.x[..., :width], state, _NT, dtype)
+    o = _dot(p.q_in, state, _NT, dtype) + _dot(p.aqk, fresh, _NN, dtype)
+    return o, state * p.decay + _dot(fresh, p.k_out, _TN, dtype)
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, entering_ref, state_ref, *, scale, sub, dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        state_ref[...] = jnp.zeros_like(state_ref)
+
+    state = state_ref[...]
+    entering_ref[...] = state
+    p = _chunk(q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...], scale, sub, dtype)
+    o, state_ref[...] = _carry(p, state, dtype)
+    o_ref[...] = o
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref, do_ref,
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, d_state_ref, *, scale, sub, dtype):
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        d_state_ref[...] = jnp.zeros_like(d_state_ref)
+
+    q, k, v, g, beta = q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...]
+    state, d_leaving, do = entering_ref[...], d_state_ref[...], do_ref[...]
+    p = _chunk(q, k, v, g, beta, scale, sub, dtype)
+    c, width = q.shape[1], q.shape[2]
+    w = p.x[..., :width]
+    # the carry, backwards
+    fresh = p.x[..., width:] - _dot(w, state, _NT, dtype)
+    d_fresh = _dot(p.aqk, do, _TN, dtype) + _dot(p.k_out, d_leaving, _NT, dtype)
+    rows, cols = _iota((1, c, c), 1), _iota((1, c, c), 2)
+    d_aqk = jnp.where(cols <= rows, _dot(do, fresh, _NT, dtype), 0.0)
+    d_q_in = _dot(do, state, _NN, dtype)
+    d_k_out = _dot(fresh, d_leaving, _NN, dtype)
+    d_decay = jnp.sum(state * d_leaving, axis=1, keepdims=True)
+    d_state_ref[...] = (d_leaving * p.decay + _dot(do, p.q_in, _TN, dtype)
+                        - _dot(d_fresh, w, _TN, dtype))
+    # the solve, backwards: (I + A)^T dB = [dW | dU]
+    d_rhs = _exact(p.inv, jnp.concatenate([-_dot(d_fresh, state, _NN, dtype), d_fresh], axis=2), _TN)
+    d_a = -jnp.where(cols < rows, _exact(d_rhs, p.x, _NT), 0.0)
+    db_ref[...] = (jnp.sum(d_a * p.akk, axis=2, keepdims=True)
+                   + jnp.sum(d_rhs * jnp.concatenate([p.k_in, v], axis=2), axis=2, keepdims=True))
+    d_rhs = beta * d_rhs
+    dv_ref[...] = d_rhs[..., width:]
+    d_k_in = d_rhs[..., :width]
+    # the parts, backwards
+    (d_k_pairs, d_q_pairs), d_k, d_run = _pairs_bwd((beta * d_a, d_aqk * scale), (k, q), k, *p.pairs, dtype)
+    d_run = d_run + d_q_in * p.q_in + d_k_in * p.k_in - d_k_out * p.k_out
+    d_last = jnp.sum(d_k_out * p.k_out, axis=1, keepdims=True) + d_decay * p.decay
+    d_run = d_run + jnp.where(_iota((1, c, width), 1) == c - 1, d_last, 0.0)
+    dq_ref[...] = d_q_pairs + d_q_in * p.grow * scale
+    dk_ref[...] = d_k + d_k_pairs + d_k_in * p.grow + d_k_out * jnp.exp(p.run[:, c - 1:, :] - p.run)
+    after = jnp.broadcast_to(_ones_where(cols >= rows), (q.shape[0], c, c))
+    dg_ref[...] = _exact(after, d_run, _NN)  # the sums from each token to the chunk's end
+
+
+def _heads(h):
+    """(heads a grid step, the heads padded to whole steps): an even number,
+    two heads to the lanes of the inside walk."""
+    step = min(HEADS, h + h % 2)
+    return step, -(-h // step) * step
+
+
+def _pad(a, heads):
+    return a if a.shape[0] == heads else jnp.pad(a, [(0, heads - a.shape[0])] + [(0, 0)] * (a.ndim - 1))
+
+
+def _call(kernel, name, grid, interpret, in_specs, out_specs, out_shape, scratch):
+    params = {"interpret": True} if interpret else {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_VMEM_LIMIT)}
+    return pl.pallas_call(kernel, grid=grid, in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+                          scratch_shapes=[pltpu.VMEM(scratch, jnp.float32)], name=name, **params)
+
+
+def _specs(step, chunk, n, reverse=False):
+    """Block specs of a grid (group of heads, chunk): ``tokens(width)`` the
+    step's chunk, ``state`` its entering state; chunks last first with
+    ``reverse``."""
+    at = (lambda j: n - 1 - j) if reverse else (lambda j: j)  # noqa: E731
+    tokens = lambda width: pl.BlockSpec((step, chunk, width), lambda i, j: (i, at(j), 0))  # noqa: E731
+    return tokens, lambda value, width: pl.BlockSpec((step, None, value, width), lambda i, j: (i, at(j), 0, 0))
+
+
+def _forward(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
+    h, seq, width = q.shape
+    value = v.shape[2]
+    step, heads = _heads(h)
+    n = seq // chunk
+    tokens, state = _specs(step, chunk, n)
+    f32 = jnp.float32
+    o, entering = _call(
+        functools.partial(_fwd_kernel, scale=scale, sub=sub, dtype=dtype), "gated_delta_fwd",
+        (heads // step, n), interpret,
+        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1)],
+        [tokens(value), state(value, width)],
+        [jax.ShapeDtypeStruct((heads, seq, value), f32), jax.ShapeDtypeStruct((heads, n, value, width), f32)],
+        (step, value, width),
+    )(*(_pad(a, heads) for a in (q, k, v, g, beta[..., None])))
+    return o[:h], entering
+
+
+def _backward(q, k, v, g, beta, entering, do, chunk, sub, scale, dtype, interpret):
+    h, seq, width = q.shape
+    value = v.shape[2]
+    step, heads = _heads(h)
+    n = seq // chunk
+    tokens, state = _specs(step, chunk, n, reverse=True)
+    f32 = jnp.float32
+    like = lambda w: jax.ShapeDtypeStruct((heads, seq, w), f32)  # noqa: E731
+    grads = _call(
+        functools.partial(_bwd_kernel, scale=scale, sub=sub, dtype=dtype), "gated_delta_bwd",
+        (heads // step, n), interpret,
+        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1), state(value, width),
+         tokens(value)],
+        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1)],
+        [like(width), like(width), like(value), like(width), like(1)],
+        (step, value, width),
+    )(*(_pad(a, heads) for a in (q, k, v, g, beta[..., None])), entering, _pad(do, heads))
+    dq, dk, dv, dg, dbeta = (a[:h] for a in grads)
+    return dq, dk, dv, dg, dbeta[..., 0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _rule(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
+    return _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret)[0]
+
+
+def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
     with part_scope(*CORE_SCOPE):
-        parts = _chunk_parts(*(_chunked(a, chunk) for a in (q, k, v, g, beta)), scale, sub, dtype)
-
-        def step(state, part):
-            left, o = _carry(dtype, state, part)
-            return left, (o, state)
-
-        start = jnp.zeros((q.shape[0], q.shape[-1], v.shape[-1]), jnp.float32)
-        _, (o, entering) = jax.lax.scan(step, start, _by_chunk(parts))
-        o = jnp.moveaxis(o, 0, 1).reshape(v.shape)
+        o, entering = _forward(q, k, v, g, beta, chunk, sub, scale, dtype, interpret)
     return o, (q, k, v, g, beta, entering)
 
 
-def _rule_bwd(chunk, sub, scale, dtype, res, do):
-    q, k, v, g, beta, entering = res
+def _rule_bwd(chunk, sub, scale, dtype, interpret, res, do):
     with part_scope(*CORE_SCOPE):
-        parts, parts_vjp = jax.vjp(
-            lambda *a: _chunk_parts(*(_chunked(x, chunk) for x in a), scale, sub, dtype), q, k, v, g, beta)
-
-        def step(d_left, xs):  # a chunk again from the state entering it, last chunk first
-            state, part, d_o = xs
-            _, vjp = jax.vjp(functools.partial(_carry, dtype), state, part)
-            return vjp((d_left, d_o))
-
-        _, d_parts = jax.lax.scan(step, jnp.zeros_like(entering[0]),
-                                  (entering, _by_chunk(parts), jnp.moveaxis(_chunked(do, chunk), 1, 0)),
-                                  reverse=True)
-        return parts_vjp(tuple(jnp.moveaxis(d, 0, 1) for d in d_parts))
+        return _backward(*res, do, chunk, sub, scale, dtype, interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
@@ -183,7 +473,8 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, scale=None, dtype=jnp
         raise ValueError("q, k, g [H, L, K], v [H, L, V], beta [H, L]")
     scale = width ** -0.5 if scale is None else float(scale)
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
-    return _rule(f32(q), f32(k), f32(v), f32(g), f32(beta), int(chunk), sub, scale, jnp.dtype(dtype))
+    return _rule(f32(q), f32(k), f32(v), f32(g), f32(beta), int(chunk), sub, scale, jnp.dtype(dtype),
+                 not on_tpu())
 
 
 def gated_delta_flops(seq: int, heads: int, width: int, value_width: int, chunk: int = CHUNK) -> dict:

@@ -1,8 +1,9 @@
-"""The chunked gated delta rule (``ops/gated_delta.py``) against its own
-definition, the recurrence a token at a time, small and on the CPU: forward
-and the gradients of all five inputs, for one chunk and for three, under
-decays from next to none down to ``g = -20`` a token (``exp(-G_j)`` alone
-would overflow float32 inside a chunk)."""
+"""The chunked gated delta rule's kernels (``ops/gated_delta.py``, in
+interpret mode) against their own definition, the recurrence a token at a
+time, small and on the CPU: forward and the gradients of all five inputs, for
+one chunk and for three, several heads (an odd count, padded to whole pairs)
+and keys narrower than values, under decays from next to none down to ``g =
+-20`` a token (``exp(-G_j)`` alone would overflow float32 inside a chunk)."""
 
 import numpy as np
 import pytest
@@ -10,6 +11,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+
+from swiftsnails_tpu.ops import gated_delta
 from swiftsnails_tpu.ops.gated_delta import (
     CORE_SCOPE, _pairs, gated_delta_flops, gated_delta_rule, recurrence)
 
@@ -25,10 +29,11 @@ def _inputs(seq, heads=3, width=16, value_width=16, g_min=-20.0, seed=0):
     return (q, k, v, g, beta), jax.random.normal(ks[5], (heads, seq, value_width))
 
 
-@pytest.mark.parametrize("seq,chunk", [(8, 8), (24, 8), (64, 32), (192, 64)],
-                         ids=["one-chunk", "three-chunks", "two-sub-blocks", "chunk-64"])
-def test_chunks_match_the_recurrence_forward_and_backward(seq, chunk):
-    args, w = _inputs(seq)
+@pytest.mark.parametrize("seq,chunk,value_width", [(8, 8, 16), (24, 8, 16), (64, 32, 16), (192, 64, 16),
+                                                   (64, 32, 32)],
+                         ids=["one-chunk", "three-chunks", "two-sub-blocks", "chunk-64", "wider-values"])
+def test_chunks_match_the_recurrence_forward_and_backward(seq, chunk, value_width):
+    args, w = _inputs(seq, value_width=value_width)
     assert float(args[3].min()) < -15.0 and float(args[3].max()) > -0.01
     fast = lambda *a: gated_delta_rule(*a, chunk=chunk, dtype=jnp.float32)  # noqa: E731
     got, want = jax.jit(fast)(*args), jax.jit(recurrence)(*args)
@@ -57,7 +62,8 @@ def test_the_state_crosses_chunks_and_a_decay_of_one_keeps_it():
 
 
 def test_pairwise_sums_never_raise_an_exponent():
-    """``sum_c x_rc k_jc exp(G_rc - G_jc)`` for ``j <= r``: against the
+    """``sum_c x_rc k_jc exp(G_rc - G_jc)`` for ``j <= r``, as a kernel
+    computes them (two heads on the lanes, four sub-blocks): against the
     differences exponentiated one by one in float64, where ``exp(-G_j)``
     alone is past float32 (``G`` reaches -600 inside the chunk)."""
     rng = np.random.default_rng(0)
@@ -68,7 +74,12 @@ def test_pairwise_sums_never_raise_an_exponent():
     want = np.where(keep, np.einsum("hrc,hjc,hrjc->hrj", x, k, np.exp(np.where(
         keep[None, :, :, None], run[:, :, None, :] - run[:, None, :, :], -np.inf))), 0.0)
     f32 = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-    got = _pairs(f32(x), f32(k), f32(run), 8, jnp.float32)
+
+    def kernel(x_ref, k_ref, run_ref, out_ref):
+        out_ref[...] = _pairs((x_ref[...],), k_ref[...], run_ref[...], 8, jnp.float32)[0][0]
+
+    got = pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct((2, 32, 32), jnp.float32), interpret=True)(
+        f32(x), f32(k), f32(run))
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4, atol=2e-5)
 
@@ -88,11 +99,15 @@ def test_bfloat16_operands_stay_close_and_shapes_are_checked():
         gated_delta_rule(q, k, v, g, g, chunk=32)
 
 
-def test_operations_are_counted_and_the_core_is_scoped():
+def test_operations_are_counted_and_the_core_is_scoped(monkeypatch):
     got = gated_delta_flops(4096, 8, 128, 128)
     per_token_head = 2 * (3 * 128 * 128 + 64 * 5 * 128)
     assert got == {"fwd": 4096 * 8 * per_token_head, "bwd": 3 * 4096 * 8 * per_token_head}
+    monkeypatch.setattr(gated_delta, "on_tpu", lambda: True)  # lowered for a TPU: the kernels are Mosaic calls
     args, w = _inputs(16)
-    text = jax.jit(jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=8) * w), argnums=(0, 3))).lower(
-        *args).as_text(debug_info=True)
-    assert "phase_%s_%s" % CORE_SCOPE in text and "triangular_solve" in text  # one solve a chunk, no inverse by powers
+    text = jax.jit(jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=8) * w), argnums=(0, 3))).trace(
+        *args).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "phase_%s_%s" % CORE_SCOPE in text and "tpu_custom_call" in text
+    for name in ("gated_delta_fwd", "gated_delta_bwd"):  # the solve is the kernels': no XLA solve is left
+        assert name in text, name
+    assert "triangular_solve" not in text

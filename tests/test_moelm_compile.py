@@ -132,13 +132,16 @@ def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkey
     assert whole.memory_analysis().temp_size_in_bytes <= unfused.memory_analysis().temp_size_in_bytes
 
 
-def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache):
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, monkeypatch):
     """Solar-Open2-250B's delta-rule layer as the cell holds it: 8 heads of
-    128, chunks of 64, forward and backward. XLA's throughout: one triangular
-    solve a chunk, and nothing the size of a state a token (``[8, 2048, 128,
-    128]`` float32 is 1 GiB) or of every pair's decay is allocated."""
+    128, chunks of 64, forward and backward: the two kernels, all eight heads
+    a grid step, no XLA solve left, and nothing the size of a state a token
+    (``[8, 2048, 128, 128]`` float32 is 1 GiB) or of every pair's decay is
+    allocated."""
+    from swiftsnails_tpu.ops import gated_delta
     from swiftsnails_tpu.ops.gated_delta import gated_delta_rule
 
+    monkeypatch.setattr(gated_delta, "on_tpu", lambda: True)
     heads, seq, width = 8, 2048, 128
 
     def loss(q, k, v, g, beta, w):
@@ -147,7 +150,9 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache):
     f32, wide = jnp.float32, (heads, seq, width)
     compiled = _compiled(jax.grad(loss, (0, 1, 2, 3, 4)), one_chip, (wide, f32), (wide, f32), (wide, f32),
                          (wide, f32), ((heads, seq), f32), (wide, f32))
-    assert "InvertDiagBlocksLowerTriangular" in compiled.as_text() or "triangular" in compiled.as_text().lower()
+    kernels = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
+    assert len(kernels) == 2 and all("gated_delta_" in line for line in kernels), kernels
+    assert "triangular" not in compiled.as_text().lower()
     assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * width * width * 4 / 4
     assert [o.shape for o in compiled.out_info] == [wide, wide, wide, wide, (heads, seq)]
 

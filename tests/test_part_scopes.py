@@ -6,7 +6,8 @@ kernels stand under the part that is theirs; an unknown pair raises; and the
 parts are metadata (without them the lowered module is the same text).
 
 The steps are lowered for a TPU (no chip needed, nothing is compiled), so
-the kernels are Mosaic calls and not their interpreted bodies; four tiny
+the kernels are Mosaic calls and not their interpreted bodies (the delta
+rule's two stand under ``phase_kda_core`` in all three passes); four tiny
 models: latent attention over a dense and two mixture layers (Moonlight's
 kind), grouped queries under the block-diffusion mask (SDAR's), the layer
 table with delta-rule layers and a gated softmax layer (Solar's), and
@@ -40,7 +41,7 @@ _TOKEN = re.compile(r"phase_([a-z]+)(?:_([a-z]+))?")
 def _lowered(kind, mp, debug_info, tpu=True):
     """The tiny model's ``train_step`` with bfloat16 operands, as lowered for
     a TPU (the kernels Mosaic calls) or for the CPU (their interpreted bodies)."""
-    for mod in (flash_attention, grouped_matmul, sparse_attention):
+    for mod in (flash_attention, gated_delta, grouped_matmul, sparse_attention):
         mp.setattr(mod, "on_tpu", lambda: tpu)
     tr, _ = MODELS[kind]._trainer(matmul_dtype="bfloat16")
     state = jax.eval_shape(tr.init_state)
@@ -116,10 +117,20 @@ def test_every_product_and_call_of_a_parted_phase_names_its_part(lowered, which)
     assert seen - want <= extras
 
 
-@pytest.mark.parametrize("family", ["flash_attention", "grouped_matmul"])
+@pytest.mark.parametrize("family", ["flash_attention", "grouped_matmul", "gated_delta"])
 def test_kernels_stand_under_the_part_that_is_theirs(lowered, family):
     kind, ops, _ = lowered
     calls = [path for op, path in ops if op == "tpu_custom_call" and family in path]
+    if family == "gated_delta":  # the delta rule's two kernels, in every pass, under ``phase_kda_core``
+        if kind != "kda":
+            assert not calls, calls
+            return
+        names = {re.search(r"gated_delta_\w+", p).group(0) for p in calls}
+        assert names == {"gated_delta_fwd", "gated_delta_bwd"}
+        assert all(_TOKEN.findall(p)[-1] == ("kda", "core") for p in calls), calls
+        assert {_pass_of(p) for p in calls if "gated_delta_fwd" in p} == {"forward", "rematerialised"}
+        assert {_pass_of(p) for p in calls if "gated_delta_bwd" in p} == {"backward"}
+        return
     if family == "flash_attention":
         names = {re.search(r"flash_attention_\w+", p).group(0) for p in calls}
         assert names == {"flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"}
