@@ -1,36 +1,50 @@
-"""A mixture-of-experts language model's block stack, as one chip of an
-expert-parallel deployment holds it: a mixer, then a feed-forward part, layer
+"""A language model's block stack, as one chip of an expert- and
+head-parallel deployment holds it: a mixer, then a feed-forward part, layer
 by layer. The mixer is latent attention (MLA, DeepSeek-V3's), grouped-query
-attention (Qwen3-MoE's, with a norm on every head's query and key; or without
-it and without rotary, its output gated), or a gated delta rule with a decay
-per channel (KDA, Kimi Linear's, arXiv:2510.26692); the feed-forward part is
-SwiGLU in the leading dense layers (there may be none) and a mixture of
-experts after them, of which this chip holds ``experts_held`` routed experts
-from ``expert_offset`` on; and the chip holds a slice of the vocabulary.
-Corpus, windows, optimizer wiring, the one ``train_step``, the loss and its
-two objectives are ``models/seqlm.py``'s; this file is the stack they drive.
+attention (Qwen3-MoE's, with a norm on every head's query and key; or
+without it and without rotary, its output gated; or with the norm over the
+whole projection, OLMo 2's), or a gated delta rule: with a decay per channel
+(KDA, Kimi Linear's, arXiv:2510.26692) or with one per head (Gated DeltaNet,
+arXiv:2412.06464); the feed-forward part is SwiGLU in the leading dense
+layers and a mixture of experts after them (either may be none), of which
+this chip holds ``experts_held`` routed experts from ``expert_offset`` on;
+and the chip holds a slice of the vocabulary. Corpus, windows, optimizer
+wiring, the one ``train_step``, the loss and its two objectives are
+``models/seqlm.py``'s; this file is the stack they drive.
 
 **The layer table.** ``mixers`` names each layer's kind of mixer. It follows
 the published keys that are there: ``kv_lora_rank`` marks latent attention,
 else ``num_key_value_heads`` and ``head_dim`` the grouped-query one; with
 ``gqa_layers`` (a list of layer numbers) the layers named run that attention
-and every other layer the delta rule (``linear_attn_config.*``). The
+and every other layer the delta rule (``linear_attn_config.*``); with
+``layer_types`` (a name a layer) ``full_attention`` is that attention and
+``linear_attention`` Gated DeltaNet (``linear_*``). The
 parameters of a kind are stacked on a leading axis: the feed-forward parts by
 theirs (``dense``, ``moe``), and the mixers with them where the table holds
 one kind (the tree of a model of one kind is what it was before the table),
-by their own kind (``gqa``, ``kda``) where it holds several
-(:meth:`MoELMTrainer._layer_table`). ``scoring_func`` names the router's
+by their own kind (``gqa``, ``kda``, ``gdn``) where it holds several
+(:meth:`MoELMTrainer._layer_table`). ``first_k_dense_replace`` equal to
+``num_hidden_layers`` is a dense stack: no router, selection bias or
+``moe_*`` counter at all. ``scoring_func`` names the router's
 scores; ``block_length`` the block-diffusion objective and its attention mask.
 
-**Held heads.** ``num_attention_heads``, ``num_key_value_heads`` and
-``linear_attn_config.num_heads`` are what THIS CHIP holds, as
-``experts_held`` is: where a deployment shares a mixer's heads out, ``W_o``
-maps the held ``heads x width`` channels to ``hidden_size`` and its output is
-a partial sum, which goes on as it is (no exchange is run); the norms, the
-low-rank gates' first halves, the router and the shared experts are whole.
+**Held heads.** ``num_attention_heads``, ``num_key_value_heads``,
+``linear_attn_config.num_heads`` and ``linear_num_*_heads`` are what THIS
+CHIP holds, as ``experts_held`` is: where a deployment shares a mixer's heads
+out, ``W_o`` maps the held ``heads x width`` channels to ``hidden_size`` and
+its output is a partial sum, which goes on as it is (no exchange is run); the
+norms, the low-rank gates' first halves, the router and the shared experts
+are whole. A q/k norm over the whole projection (``qk_norm: width``)
+takes its mean square over the held channels: the deployment would add the
+chips' sums, one ``[L]`` exchange, which is not run either.
 
-Per layer, residual ``x``, ``h = RMSNorm_w(x)`` (every norm has a learned
-gain and ``rms_norm_eps``):
+**The residual order.** ``norm_order: pre`` (the default): per layer,
+residual ``x``, each half reads ``h = RMSNorm_w(x)`` and adds its output to
+``x``. ``norm_order: post`` (OLMo 2's, arXiv:2501.00656; dense layers only):
+each half reads ``h = x`` itself and adds ``RMSNorm_w(its output)``, the same
+gain leaf in the other place (``attn_norm`` after the mixer, ``mlp_norm``
+after the feed-forward part); the final norm stays before the head. Every
+norm has a learned gain and ``rms_norm_eps``:
 
 * **MLA.** ``q = W_q h`` (heads x (nope + rope)); ``[c ; k_rope] = W_kva h``
   (``kv_lora_rank`` + rope; ``k_rope`` is one per token, shared by the heads);
@@ -42,8 +56,10 @@ gain and ``rms_norm_eps``):
   ``k_j = RMSNorm_knorm(W_k h)_j``, ``v_j = (W_v h)_j`` (``num_key_value_heads``
   x ``head_dim``); rotary over the whole head on ``q`` and ``k``; softmax of
   ``q_i . k_(i // group) / sqrt(head_dim)``; ``W_o [o_i]``. No bias anywhere.
-  ``qk_norm: 0`` leaves the two head norms out, ``use_rope: 0`` the rotary
-  (MLA's too), and ``use_gqa_gate: 1`` multiplies ``[o_i]`` elementwise by
+  ``qk_norm: 0`` leaves the two head norms out, ``qk_norm: width``
+  norms ``W_q h`` and ``W_k h`` over their whole held width instead (gains
+  as wide), ``use_rope: 0`` leaves the rotary out (MLA's too), and
+  ``use_gqa_gate: 1`` multiplies ``[o_i]`` elementwise by
   ``sigmoid(W_z h)`` (``W_z [d, heads x head_dim]``) before ``W_o``.
 * **Learned sparse attention** (``sa_config``: DeepSeek-V3.2's DSA on the
   grouped-query layer, every layer). ``h' = stop_gradient(h)``; an indexer of
@@ -82,12 +98,23 @@ gain and ``rms_norm_eps``):
   (``ops/gated_delta.py``, where the chunked form stands). Fresh leaves
   [assumed]: ``a_log = log U(1, 16)``, ``dt_bias`` the inverse softplus of a
   rate log-uniform in (0.001, 0.1), the taps U(-1/2, 1/2) (:func:`init_leaf`).
+* **Gated DeltaNet** (``H = linear_num_value_heads`` heads, keys and queries
+  ``K = linear_key_head_dim`` wide, values ``V = linear_value_head_dim``;
+  ``linear_conv_kernel_dim`` taps; FLA's and Qwen3-Next's shapes). As KDA,
+  but for three things: the decay is one a head, ``g_t = -exp(a_log_head) *
+  softplus(W_a h_t + dt_bias_head)`` (``W_a [d, H]``), handed to the chunked
+  rule equal across the key channels; the state is ``[K, V]`` and ``o_t =
+  S_t^T q_t / sqrt(K)``; the gate is full-rank, ``y_t = RMSNorm_onorm(o_t)``
+  (over the head's ``V``) ``* silu(W_g h_t)`` (``W_g [d, H x V]``).
+  ``linear_allow_neg_eigval`` doubles beta. The same fresh leaves, ``dt_bias``
+  one a head.
 * **Mask and positions.** Causal, rotary at ``0..L-1``. Under block diffusion
   a row is its noised copy followed by its clean copy, 2L positions, each
   rotated by its place in its own copy, under
   ``ops/flash_attention.BlockDiffusion``'s mask.
 * **Feed-forward**, dense layers and shared experts (one SwiGLU as wide as
-  the shared experts together): ``W_down (silu(W_gate y) * (W_up y))``.
+  the shared experts together): ``W_down (silu(W_gate y) * (W_up y))``, ``y``
+  the half's input.
 * **Routed experts.** ``s = sigmoid(W_g y)`` (``scoring_func: softmax``:
   ``softmax(W_g y)``) over all ``router_experts``,
   float32; the ``num_experts_per_tok`` largest of ``s + b`` are chosen (``b``
@@ -127,13 +154,15 @@ the backward pass too (:func:`mm`). The layers run one after another, unrolled
 published widths, and no longer fits the chip), each rematerialised in the
 backward pass (``remat: 1``).
 
-Counters, in ``after_update``'s metrics and the state: ``moe_*`` (above);
-``attn_whole_tile_share`` (metrics only, a constant of the trace): of the tiles
+Counters, in ``after_update``'s metrics and the state: ``moe_*`` (above;
+where there are mixture layers); ``attn_whole_tile_share`` (metrics only, a
+constant of the trace): of the tiles
 an attention kernel visits, the share the mask allows whole
 (``ops/flash_attention.tile_classes``); and,
 where the table has delta-rule layers, ``kda_decay_mean`` (state:
-``kda_decay``): the mean ``alpha`` over the step's tokens, heads and
-channels, the layers averaged; a gate that stopped decaying reads 1, one that
+``kda_decay``) and ``gdn_decay_mean`` (state: ``gdn_decay``): the mean
+``alpha`` over the step's tokens, heads and channels of that kind's layers,
+the layers averaged; a gate that stopped decaying reads 1, one that
 wipes the state 0; under ``sa_config`` ``sparse_live_tile_share`` (state:
 ``sparse_live``; it takes ``attn_whole_tile_share``'s place, which a selection
 has not): the causal tiles that hold a pick, and ``sparse_indexer_kl``
@@ -148,9 +177,12 @@ Config keys (the published names where there is one): ``hidden_size``,
 ``num_hidden_layers``, ``first_k_dense_replace``, ``num_attention_heads``,
 ``kv_lora_rank``, ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``
 (or ``num_key_value_heads``, ``head_dim``), ``rope_theta``, ``use_rope``,
-``qk_norm``, ``use_gqa_gate``, ``gqa_layers``, ``linear_attn_config.num_heads``,
+``qk_norm``, ``use_gqa_gate``, ``gqa_layers``, ``layer_types``,
+``norm_order``, ``linear_attn_config.num_heads``,
 ``linear_attn_config.head_dim``, ``linear_attn_config.short_conv_kernel_size``,
-``kda_allow_neg_eigval``, ``sa_config.topk``, ``sa_config.indexer_num_heads``,
+``kda_allow_neg_eigval``, ``linear_num_key_heads``, ``linear_num_value_heads``,
+``linear_key_head_dim``, ``linear_value_head_dim``, ``linear_conv_kernel_dim``,
+``linear_allow_neg_eigval``, ``sa_config.topk``, ``sa_config.indexer_num_heads``,
 ``sa_config.indexer_head_dim``, ``sa_config.indexer_num_kv_heads``,
 ``sa_config.q_chunk_size``, ``sa_config.kv_chunk_size``,
 ``rope_scaling.mrope_section``, ``rms_norm_eps``,
@@ -166,7 +198,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -234,9 +266,21 @@ L2_EPS = 1e-6  # under the root of a head's sum of squares (KDA's q and k)
 INDEX_NORM_EPS = 1e-6  # the indexer keys' LayerNorm (DeepSeek-V3.2's)
 # what a layer with a selection keeps for the backward pass (``jax.checkpoint``'s names)
 SAVED = ("sparse_selection", "sparse_stats")
-# what a layer's mixer counts, whatever its feed-forward part: -> the name ``stack`` stacks it under
-MIXER_COUNTS = {"decay": "kda_decay", "index_loss": "index_loss", "sparse_live": "sparse_live",
-                "selected": "selected"}
+# what a layer's mixer counts, whatever its feed-forward part: ``stack`` stacks each by its name
+MIXER_COUNTS = ("kda_decay", "gdn_decay", "index_loss", "sparse_live", "selected")
+DELTA = ("kda", "gdn")  # the gated delta-rule mixers: a decay per channel, one per head
+# ``layer_types``' names -> the kind of mixer (None: the attention the other keys give)
+LAYER_TYPES = {"full_attention": None, "linear_attention": "gdn"}
+
+
+class DeltaShape(NamedTuple):
+    """A delta-rule mixer as this chip holds it."""
+
+    heads: int
+    key: int  # q and k a head
+    value: int  # v, the output and the gated norm a head
+    taps: int  # of each causal convolution
+    beta_max: float  # 2 where negative eigenvalues are allowed, else 1
 
 
 def l2_norm(x):
@@ -252,8 +296,16 @@ def causal_conv(u, w):
     return sum(w[j] * padded[:, j: j + seq] for j in range(taps))
 
 
+def _one_of(cfg: Config, key: str, allowed):
+    """``cfg[key]``, the first of ``allowed`` if it is not set."""
+    value = cfg.get_str(key, allowed[0])
+    if value not in allowed:
+        raise ValueError(f"{key} must be one of {allowed}, got {value}")
+    return value
+
+
 def init_leaf(key, name: str, shape, std: float):
-    """A fresh leaf by its name: a norm's gain 1; KDA's ``a_log`` the log of
+    """A fresh leaf by its name: a norm's gain 1; a delta rule's ``a_log`` the log of
     U(1, 16), its ``dt_bias`` the inverse softplus of a rate log-uniform in
     (0.001, 0.1), a convolution's taps U(-1/2, 1/2) (fan-in 4); every other
     leaf N(0, std^2)."""
@@ -296,22 +348,64 @@ class MoELMTrainer(SeqLMTrainer):
                 raise ValueError("num_attention_heads must be a multiple of num_key_value_heads")
         self.rope_theta = cfg.get_float("rope_theta", 10000.0)
         self.use_rope = cfg.get_bool("use_rope", True)
-        self.qk_norm = cfg.get_bool("qk_norm", True)
+        self.qk_norm_width = cfg.get_str("qk_norm", "1") == "width"  # one norm over the held projection
+        self.qk_norm = self.qk_norm_width or cfg.get_bool("qk_norm", True)  # else one a head, or none
+        self.post_norm = _one_of(cfg, "norm_order", ("pre", "post")) == "post"
         self.attn_gate = cfg.get_bool("use_gqa_gate", False)
-        kind = "mla" if self.kv_rank else "gqa"
-        self.mixers = (kind,) * self.n_layers  # the layer table: layer -> kind of mixer
-        if "gqa_layers" in cfg:  # the layers named run that attention, the others the delta rule
-            named = {int(i) for i in cfg.get_str("gqa_layers").strip("[]() ").split(",") if i.strip()}
-            self.mixers = tuple(kind if i in named else "kda" for i in range(self.n_layers))
-        if "kda" in self.mixers:
-            self.kda_heads, self.kda_dim = g("linear_attn_config.num_heads"), g("linear_attn_config.head_dim")
-            self.kda_taps = g("linear_attn_config.short_conv_kernel_size")
-            self.kda_beta_max = 2.0 if cfg.get_bool("kda_allow_neg_eigval", False) else 1.0
+        self._read_table(cfg)
         self.topk = g("sa_config.topk", 0)  # learned sparse attention, if stated
         if self.topk:
             self._read_sparse(cfg)
         self.eps = cfg.get_float("rms_norm_eps", 1e-6)
         self.dense_width = g("intermediate_size", 0)
+        self.init_std = cfg.get_float("init_std", 0.02)
+        self.loss_chunks = g("loss_chunks", 1)
+        self.matmul_dtype = jnp.dtype(cfg.get_str("matmul_dtype", "bfloat16"))
+        self.remat = cfg.get_bool("remat", True)
+        self.vocab_size = g("vocab_size", self.vocab_size)
+        if self.mesh is not None:
+            raise ValueError("moelm holds one chip's share: no mesh (local_train: 1)")
+        if not 0 <= self.n_dense <= self.n_layers:
+            raise ValueError("first_k_dense_replace must lie in 0..num_hidden_layers")
+        self.n_moe = self.n_layers - self.n_dense
+        if self.n_moe:
+            if self.post_norm:
+                raise ValueError("norm_order post runs dense layers only")
+            self._read_mixture(cfg)
+
+    def _read_table(self, cfg: Config) -> None:
+        """The layer table (``mixers``) and each delta-rule kind's shape
+        (``delta``)."""
+        g = cfg.get_int
+        kind = "mla" if self.kv_rank else "gqa"
+        self.mixers = (kind,) * self.n_layers  # the layer table: layer -> kind of mixer
+        if "gqa_layers" in cfg and "layer_types" in cfg:
+            raise ValueError("gqa_layers or layer_types, not both")
+        if "gqa_layers" in cfg:  # the layers named run that attention, the others the delta rule
+            named = {int(i) for i in cfg.get_str("gqa_layers").strip("[]() ").split(",") if i.strip()}
+            self.mixers = tuple(kind if i in named else "kda" for i in range(self.n_layers))
+        if "layer_types" in cfg:  # a name a layer
+            types = [t.strip(" '\"") for t in cfg.get_str("layer_types").strip("[]() ").split(",") if t.strip()]
+            if len(types) != self.n_layers or not set(types) <= set(LAYER_TYPES):
+                raise ValueError(f"layer_types must name each of {self.n_layers} layers one of {sorted(LAYER_TYPES)}")
+            self.mixers = tuple(LAYER_TYPES[t] or kind for t in types)
+        self.delta = {}
+        if "kda" in self.mixers:
+            width = g("linear_attn_config.head_dim")
+            self.delta["kda"] = DeltaShape(
+                g("linear_attn_config.num_heads"), width, width, g("linear_attn_config.short_conv_kernel_size"),
+                2.0 if cfg.get_bool("kda_allow_neg_eigval", False) else 1.0)
+        if "gdn" in self.mixers:
+            heads = g("linear_num_value_heads")
+            if g("linear_num_key_heads", heads) != heads:
+                raise ValueError("Gated DeltaNet here has as many key heads as value heads")
+            self.delta["gdn"] = DeltaShape(
+                heads, g("linear_key_head_dim"), g("linear_value_head_dim"), g("linear_conv_kernel_dim"),
+                2.0 if cfg.get_bool("linear_allow_neg_eigval", False) else 1.0)
+
+    def _read_mixture(self, cfg: Config) -> None:
+        """The mixture layers' router, experts and balance."""
+        g = cfg.get_int
         self.expert_width = g("moe_intermediate_size")
         self.n_shared = g("n_shared_experts", 0)
         self.top_k = g("num_experts_per_tok")
@@ -322,15 +416,6 @@ class MoELMTrainer(SeqLMTrainer):
         self.expert_offset = g("expert_offset", 0)
         self.bias_rate = cfg.get_float("bias_update_rate", 0.001)
         self.aux_alpha = cfg.get_float("aux_loss_alpha", 0.0001)
-        self.init_std = cfg.get_float("init_std", 0.02)
-        self.loss_chunks = g("loss_chunks", 1)
-        self.matmul_dtype = jnp.dtype(cfg.get_str("matmul_dtype", "bfloat16"))
-        self.remat = cfg.get_bool("remat", True)
-        self.vocab_size = g("vocab_size", self.vocab_size)
-        if self.mesh is not None:
-            raise ValueError("moelm holds one chip's share: no mesh (local_train: 1)")
-        if not 0 <= self.n_dense < self.n_layers:
-            raise ValueError("first_k_dense_replace must leave a mixture layer")
         if self.scoring not in ("sigmoid", "softmax"):
             raise ValueError(f"scoring_func must be sigmoid or softmax, got {self.scoring}")
         if self.expert_offset + self.experts_held > self.router_experts:
@@ -353,7 +438,7 @@ class MoELMTrainer(SeqLMTrainer):
         chunk = g("sa_config.q_chunk_size")
         if g("sa_config.kv_chunk_size", chunk) != chunk:
             raise ValueError("sa_config's query and key chunks are one tile")
-        if self.kv_rank or "gqa_layers" in cfg or self.block_length:
+        if self.kv_rank or "gqa_layers" in cfg or "layer_types" in cfg or self.block_length:
             raise ValueError("sa_config selects keys for causal grouped-query attention in every layer")
         self.attention_block = chunk
         if "rope_scaling.mrope_section" in cfg:
@@ -373,20 +458,23 @@ class MoELMTrainer(SeqLMTrainer):
         are stacked by their kind (``dense``, ``moe``); the mixers by theirs
         where the table holds several kinds, and with the layer's
         feed-forward part where it holds one."""
-        ffn = ("dense",) * self.n_dense + ("moe",) * (self.n_layers - self.n_dense)
+        ffn = ("dense",) * self.n_dense + ("moe",) * self.n_moe
         groups = (self.mixers if len(set(self.mixers)) > 1 else ffn, ffn)
         return [tuple((g[i], g[:i].count(g[i])) for g in groups) for i in range(self.n_layers)]
 
     def _mixer_shapes(self, kind: str) -> Dict[str, Any]:
         d, h = self.d_model, self.n_heads
-        if kind == "kda":
-            h, hd = self.kda_heads, self.kda_dim
-            r = hd  # the low-rank gates' rank
-            wide = {k: (d, h * hd) for k in ("wq", "wk", "wv")}
-            taps = {"conv_" + k: (self.kda_taps, h * hd) for k in "qkv"}
-            return {"attn_norm": (d,), **wide, **taps, "f_down": (d, r), "f_up": (r, h * hd),
-                    "a_log": (h,), "dt_bias": (h * hd,), "wb": (d, h), "g_down": (d, r),
-                    "g_up": (r, h * hd), "o_norm": (hd,), "wo": (h * hd, d)}
+        if kind in DELTA:
+            s = self.delta[kind]
+            h, keys, values = s.heads, s.heads * s.key, s.heads * s.value
+            leaves = {"attn_norm": (d,), "wq": (d, keys), "wk": (d, keys), "wv": (d, values),
+                      "conv_q": (s.taps, keys), "conv_k": (s.taps, keys), "conv_v": (s.taps, values),
+                      "a_log": (h,), "wb": (d, h), "o_norm": (s.value,), "wo": (values, d)}
+            if kind == "kda":  # the rate a channel and the gate from low-rank products of rank K
+                r = s.key
+                return {**leaves, "f_down": (d, r), "f_up": (r, keys), "dt_bias": (keys,),
+                        "g_down": (d, r), "g_up": (r, values)}
+            return {**leaves, "wa": (d, h), "dt_bias": (h,), "wg": (d, values)}
         if kind == "mla":
             attn = {"wq": (d, h * (self.nope + self.rope)),
                     "wkv_a": (d, self.kv_rank + self.rope), "kv_norm": (self.kv_rank,),
@@ -394,8 +482,9 @@ class MoELMTrainer(SeqLMTrainer):
         else:
             hd, kv = self.v_dim, self.kv_heads
             attn = {"wq": (d, h * hd), "wk": (d, kv * hd), "wv": (d, kv * hd)}
-            if self.qk_norm:
-                attn.update({"q_norm": (hd,), "k_norm": (hd,)})
+            if self.qk_norm:  # a head's gain, or one over the whole held projection
+                attn.update({"q_norm": (h * hd if self.qk_norm_width else hd,),
+                             "k_norm": (kv * hd if self.qk_norm_width else hd,)})
             if self.attn_gate:
                 attn["wz"] = (d, h * hd)
             if self.topk:
@@ -415,11 +504,12 @@ class MoELMTrainer(SeqLMTrainer):
             return {f"{prefix}_gate": (d, width), f"{prefix}_up": (d, width),
                     f"{prefix}_down": (width, d)}
 
-        e, w = self.experts_held, self.expert_width
-        ffn = {"dense": {"mlp_norm": (d,), **swiglu("mlp", self.dense_width)},
-               "moe": {"mlp_norm": (d,), "router": (d, self.router_experts),
-                       **swiglu("shared", self.n_shared * w),
-                       "experts_gate": (e, d, w), "experts_up": (e, d, w), "experts_down": (e, w, d)}}
+        ffn = {"dense": {"mlp_norm": (d,), **swiglu("mlp", self.dense_width)}}
+        if self.n_moe:
+            e, w = self.experts_held, self.expert_width
+            ffn["moe"] = {"mlp_norm": (d,), "router": (d, self.router_experts),
+                          **swiglu("shared", self.n_shared * w),
+                          "experts_gate": (e, d, w), "experts_up": (e, d, w), "experts_down": (e, w, d)}
         groups = {}
         for kind, (mixer, forward) in zip(self.mixers, self._layer_table()):
             for (group, place), leaves in ((mixer, self._mixer_shapes(kind)), (forward, ffn[forward[0]])):
@@ -437,21 +527,21 @@ class MoELMTrainer(SeqLMTrainer):
         return self.state_of(params)
 
     def state_of(self, params) -> Dict[str, Any]:
-        """A fresh state around ``params``: optimizer slots, the selection
-        bias at zero, and the step's counters (under block diffusion the
-        positions routed are the two copies', and ``noised`` counts the step's
-        masked tokens)."""
-        n_moe = self.n_layers - self.n_dense
-        positions = self._positions()
-        state = {"params": params, "opt": self.opt.init(params),
-                 "router_bias": jnp.zeros((n_moe, self.router_experts), jnp.float32),
-                 "counts": jnp.zeros((n_moe, self.router_experts), jnp.int32),
-                 "choices": jnp.zeros((n_moe, positions, self.top_k), jnp.int32),
-                 "dropped": jnp.zeros((), jnp.int32)}
+        """A fresh state around ``params``: optimizer slots, where there are
+        mixture layers the selection bias at zero, and the step's counters
+        (under block diffusion the positions routed are the two copies', and
+        ``noised`` counts the step's masked tokens)."""
+        n_moe = self.n_moe
+        state = {"params": params, "opt": self.opt.init(params)}
+        if n_moe:
+            state.update({"router_bias": jnp.zeros((n_moe, self.router_experts), jnp.float32),
+                          "counts": jnp.zeros((n_moe, self.router_experts), jnp.int32),
+                          "choices": jnp.zeros((n_moe, self._positions(), self.top_k), jnp.int32),
+                          "dropped": jnp.zeros((), jnp.int32)})
         if self.block_length:
             state["noised"] = jnp.zeros((), jnp.int32)
-        if "kda" in self.mixers:
-            state["kda_decay"] = jnp.zeros((), jnp.float32)
+        for kind in self.delta:
+            state[kind + "_decay"] = jnp.zeros((), jnp.float32)
         if self.topk:
             state.update({"sparse_kl": jnp.zeros((), jnp.float32), "sparse_live": jnp.zeros((), jnp.float32)})
         return state
@@ -460,6 +550,15 @@ class MoELMTrainer(SeqLMTrainer):
 
     def _mm(self, x, w):
         return mm(x, w, self.matmul_dtype)
+
+    def _pre(self, x, gain):
+        """A half's input: ``x`` normed (``norm_order: pre``), or ``x`` itself."""
+        return x if self.post_norm else rms_norm(x, gain, self.eps)
+
+    def _post(self, out, gain):
+        """What a half adds to the residual stream: its output, or, under
+        ``norm_order: post``, its output normed."""
+        return rms_norm(out, gain, self.eps) if self.post_norm else out
 
     def _latent_qkv(self, p, y, shape, spin):
         """MLA: (q, k ``[B, L, heads, nope + rope]``, v ``[B, L, heads, v]``)."""
@@ -476,13 +575,18 @@ class MoELMTrainer(SeqLMTrainer):
 
     def _grouped_qkv(self, p, y, shape, spin):
         """Grouped queries: (q ``[B, L, heads, head_dim]``, k, v ``[B, L,
-        key/value heads, head_dim]``), each head's query and key normed
-        (``qk_norm``)."""
-        heads = lambda w, n: self._mm(y, w).reshape(*shape, n, self.v_dim)  # noqa: E731
-        norm = (lambda t, gain: rms_norm(t, p[gain], self.eps)) if self.qk_norm else (lambda t, gain: t)
-        q = norm(heads(p["wq"], self.n_heads), "q_norm")
-        k = norm(heads(p["wk"], self.kv_heads), "k_norm")
-        return spin(q), spin(k), heads(p["wv"], self.kv_heads)
+        key/value heads, head_dim]``), the query and the key normed
+        (``qk_norm: 1``) a head, or over the held width (``qk_norm: width``)."""
+        def project(w, n, gain=None):
+            t = self._mm(y, p[w])
+            normed = gain is not None and self.qk_norm
+            if normed and self.qk_norm_width:
+                t = rms_norm(t, p[gain], self.eps)
+            t = t.reshape(*shape, n, self.v_dim)
+            return rms_norm(t, p[gain], self.eps) if normed and not self.qk_norm_width else t
+
+        q, k = project("wq", self.n_heads, "q_norm"), project("wk", self.kv_heads, "k_norm")
+        return spin(q), spin(k), project("wv", self.kv_heads)
 
     def _attention(self, p, x, b, positions=None):
         """``x [B * L, d]`` -> (the block's output, same shape; what a
@@ -490,7 +594,7 @@ class MoELMTrainer(SeqLMTrainer):
         none)."""
         seq = x.shape[0] // b
         with part_scope("attn", "in"):
-            y = rms_norm(x, p["attn_norm"], self.eps)
+            y = self._pre(x, p["attn_norm"])
             spin = jax.vmap(lambda t: rotary(t, self.rope_theta, positions)) if self.use_rope else (lambda t: t)
             qkv = self._latent_qkv if self.kv_rank else self._grouped_qkv
             fold = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, seq, t.shape[-1])  # noqa: E731
@@ -549,41 +653,50 @@ class MoELMTrainer(SeqLMTrainer):
         return jnp.concatenate(outs), {"index_loss": sum(losses) / b, "sparse_live": sum(lives) / b,
                                        "selected": jnp.stack(words)}
 
-    def _kda(self, p, x, b):
-        """``x [B * L, d]`` -> (the delta-rule mixer's output, same shape; the
-        mean decay ``alpha`` over its tokens, heads and channels). State and
-        convolution start at zero with each row and run across its documents."""
-        seq, h, hd = x.shape[0] // b, self.kda_heads, self.kda_dim
-        with part_scope("kda", "in"):
-            y = rms_norm(x, p["attn_norm"], self.eps)
+    def _kda(self, p, x, b, kind="kda"):
+        """``x [B * L, d]`` -> (a delta-rule mixer's output, same shape; the
+        mean decay ``alpha`` over its tokens, heads and channels), ``kind``
+        one of :data:`DELTA`. State and convolution start at zero with each
+        row and run across its documents."""
+        s = self.delta[kind]
+        seq, h = x.shape[0] // b, s.heads
+        with part_scope(kind, "in"):
+            y = self._pre(x, p["attn_norm"])
             heads = lambda t: t.reshape(b, seq, h, -1)  # noqa: E731
             conv = lambda c: heads(jax.nn.silu(causal_conv(  # noqa: E731
                 self._mm(y, p["w" + c]).reshape(b, seq, -1), p["conv_" + c])))
             q, k, v = l2_norm(conv("q")), l2_norm(conv("k")), conv("v")
-            rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
-            g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
-            beta = self.kda_beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
+            if kind == "kda":  # a rate a channel
+                rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
+                g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
+            else:  # a rate a head, the same for its channels
+                rate = heads(jax.nn.softplus(self._mm(y, p["wa"]) + p["dt_bias"]))
+                g = -jnp.exp(p["a_log"])[:, None] * jnp.broadcast_to(rate, q.shape)
+            beta = s.beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
             fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, seq, -1)  # noqa: E731
             folded = fold(q), fold(k), fold(v), fold(g), fold(heads(beta))[..., 0]
-        o = gated_delta_rule(*folded, chunk=self.kda_chunk, dtype=self.matmul_dtype)  # names ``kda`` / ``core`` itself
-        with part_scope("kda", "out"):
-            o = o.reshape(b, h, seq, hd).transpose(0, 2, 1, 3).reshape(b * seq, h, hd)
-            gate = jax.nn.sigmoid(self._mm(self._mm(y, p["g_down"]), p["g_up"]))
+        o = gated_delta_rule(*folded, chunk=self.kda_chunk, dtype=self.matmul_dtype, scope=(kind, "core"))
+        with part_scope(kind, "out"):
+            o = o.reshape(b, h, seq, s.value).transpose(0, 2, 1, 3).reshape(b * seq, h, s.value)
+            if kind == "kda":
+                gate = jax.nn.sigmoid(self._mm(self._mm(y, p["g_down"]), p["g_up"]))
+            else:
+                gate = jax.nn.silu(self._mm(y, p["wg"]))
             o = rms_norm(o, p["o_norm"], self.eps).reshape(b * seq, -1) * gate
             return self._mm(o, p["wo"]), jnp.mean(jnp.exp(g))
 
     def _mix(self, kind, p, x, b, positions=None):
-        """A layer's first half: ``x`` + its mixer's output, and what the
-        mixer counted."""
-        if kind == "kda":
-            with phase_scope("kda"):
-                out, decay = self._kda(p, x, b)
-                with part_scope("kda", "out"):  # the residual sum goes where ``W_o`` is
-                    return x + out, {"decay": decay}
+        """A layer's first half: ``x`` + its mixer's output (normed under
+        ``norm_order: post``), and what the mixer counted."""
+        if kind in DELTA:
+            with phase_scope(kind):
+                out, decay = self._kda(p, x, b, kind)
+                with part_scope(kind, "out"):  # the residual sum goes where ``W_o`` is
+                    return x + self._post(out, p["attn_norm"]), {kind + "_decay": decay}
         with phase_scope("attn"):
             out, counted = self._attention(p, x, b, positions)
             with part_scope("attn", "out"):
-                return x + out, counted
+                return x + self._post(out, p["attn_norm"]), counted
 
     def _swiglu(self, p, prefix, y):
         hidden = jax.nn.silu(self._mm(y, p[prefix + "_gate"])) * self._mm(y, p[prefix + "_up"])
@@ -638,7 +751,7 @@ class MoELMTrainer(SeqLMTrainer):
     def _dense_layer(self, x, p, b, positions=None, kind=None):
         x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
         with phase_scope("mlp"):
-            return x + self._swiglu(p, "mlp", rms_norm(x, p["mlp_norm"], self.eps)), counted
+            return x + self._post(self._swiglu(p, "mlp", self._pre(x, p["mlp_norm"])), p["mlp_norm"]), counted
 
     def _moe_layer(self, x, p, bias, b, positions=None, kind=None):
         x, counted = self._mix(kind or self.mixers[0], p, x, b, positions)
@@ -654,8 +767,9 @@ class MoELMTrainer(SeqLMTrainer):
 
     def stack(self, params, tokens, router_bias, positions=None):
         """(the stack's output after the last norm [B * L, d], what the
-        mixture layers counted, stacked by layer; ``kda_decay``, if the table
-        has such layers, is theirs alone)."""
+        mixture layers counted, stacked by layer (nothing in a dense stack,
+        whose ``router_bias`` is None); ``kda_decay``, ``gdn_decay``,
+        where the table has such layers, are theirs alone)."""
         b = tokens.shape[0]
         wrap = jax.checkpoint if self.remat else (lambda f: f)
         if self.remat and self.topk:  # the selection and the probability pass are kept, not run again
@@ -674,10 +788,10 @@ class MoELMTrainer(SeqLMTrainer):
                 x, counted = sparse[kind](x, p, router_bias[i - self.n_dense])
             for name in MIXER_COUNTS:
                 if name in counted:
-                    every.setdefault(MIXER_COUNTS[name], []).append(counted.pop(name))
+                    every.setdefault(name, []).append(counted.pop(name))
             if i >= self.n_dense:
                 seen.append(counted)
-        seen = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *seen)
+        seen = jax.tree_util.tree_map(lambda *a: jnp.stack(a), *seen) if seen else {}
         seen.update({name: jnp.stack(a) for name, a in every.items()})
         with phase_scope("head"):
             return rms_norm(x, params["final_norm"], self.eps), seen
@@ -686,11 +800,11 @@ class MoELMTrainer(SeqLMTrainer):
         if self.block_length:
             return self._diffusion_loss(params, batch, state)
         tokens = batch["tokens"]
-        x, seen = self.stack(params, tokens[:, :-1], state["router_bias"])
+        x, seen = self.stack(params, tokens[:, :-1], state.get("router_bias"))
         with phase_scope("head"):
             ce = token_loss(x, params["head"], tokens[:, 1:].reshape(-1),
                             self.loss_chunks, self._mm)
-        loss = ce + jnp.sum(seen.pop("aux"))
+        loss = ce + jnp.sum(seen.pop("aux")) if "aux" in seen else ce
         if "index_loss" in seen:  # L_I joins as the balance loss does; each reaches leaves the other does not
             loss = loss + jnp.sum(seen["index_loss"])
         return loss, {**seen, "ce_loss": ce}
@@ -702,16 +816,37 @@ class MoELMTrainer(SeqLMTrainer):
         tokens = batch["tokens"]
         rows, seq = tokens.shape
         ids, positions, weights = diffusion_inputs(batch, self.mask_token_id, self.block_length)
-        x, seen = self.stack(params, ids, state["router_bias"], positions)
+        x, seen = self.stack(params, ids, state.get("router_bias"), positions)
         with phase_scope("head"):
             x = x.reshape(rows, 2 * seq, -1)[:, :seq].reshape(rows * seq, -1)
             ce = token_loss(x, params["head"], tokens.reshape(-1), self.loss_chunks, self._mm,
                             weights=weights.reshape(-1))
         with phase_scope("noise"):
             noised = jnp.sum(batch["noised"], dtype=jnp.int32)
-        return ce + jnp.sum(seen.pop("aux")), {**seen, "ce_loss": ce, "noised": noised}
+        loss = ce + jnp.sum(seen.pop("aux")) if "aux" in seen else ce
+        return loss, {**seen, "ce_loss": ce, "noised": noised}
 
     def after_update(self, state, aux):
+        state, metrics = dict(state), {"ce_loss": aux["ce_loss"]}
+        if self.n_moe:
+            state, metrics = self._after_mixture(state, aux, metrics)
+        # a constant of the trace: the mask, the row's positions and the tile (a selection has none)
+        if set(self.mixers) & {"mla", "gqa"} and not self.topk:
+            tiles = tile_classes(self.seq_len * (2 if self.block_length else 1), self.attention_block,
+                                 self.block_length or None)
+            metrics["attn_whole_tile_share"] = jnp.float32(tiles["whole"] / tiles["live"])
+        if self.block_length:
+            state["noised"] = aux["noised"]
+            metrics["diffusion_masked_share"] = aux["noised"] / (self.batch_size * self.seq_len)
+        for kind in self.delta:
+            state[kind + "_decay"] = metrics[kind + "_decay_mean"] = jnp.mean(aux[kind + "_decay"])
+        if self.topk:
+            state["sparse_kl"] = metrics["sparse_indexer_kl"] = jnp.mean(aux["index_loss"])
+            state["sparse_live"] = metrics["sparse_live_tile_share"] = jnp.mean(aux["sparse_live"])
+        return state, metrics
+
+    def _after_mixture(self, state, aux, metrics):
+        """The selection bias's step and the mixture layers' counters."""
         counts, bias = aux["counts"], state["router_bias"]
         if self.bias_rate:
             with phase_scope("opt"):
@@ -721,8 +856,8 @@ class MoELMTrainer(SeqLMTrainer):
         held = counts[:, lo:hi].astype(jnp.float32)
         state = {**state, "router_bias": bias, "counts": counts, "choices": aux["choices"],
                  "dropped": jnp.sum(aux["dropped"])}
-        metrics = {
-            "ce_loss": aux["ce_loss"],
+        return state, {
+            **metrics,
             "moe_held_share": jnp.sum(held) / jnp.sum(counts),
             "moe_load_max_over_mean": jnp.mean(
                 jnp.max(held, axis=-1) / jnp.maximum(jnp.mean(held, axis=-1), 1.0)),
@@ -730,17 +865,3 @@ class MoELMTrainer(SeqLMTrainer):
             "moe_live_tile_share": jnp.mean(aux["live_tile_share"]),
             "moe_tile_fill_share": jnp.mean(aux["tile_fill_share"]),
         }
-        # a constant of the trace: the mask, the row's positions and the tile (a selection has none)
-        if set(self.mixers) != {"kda"} and not self.topk:
-            tiles = tile_classes(self.seq_len * (2 if self.block_length else 1), self.attention_block,
-                                 self.block_length or None)
-            metrics["attn_whole_tile_share"] = jnp.float32(tiles["whole"] / tiles["live"])
-        if self.block_length:
-            state["noised"] = aux["noised"]
-            metrics["diffusion_masked_share"] = aux["noised"] / (self.batch_size * self.seq_len)
-        if "kda_decay" in aux:
-            state["kda_decay"] = metrics["kda_decay_mean"] = jnp.mean(aux["kda_decay"])
-        if self.topk:
-            state["sparse_kl"] = metrics["sparse_indexer_kl"] = jnp.mean(aux["index_loss"])
-            state["sparse_live"] = metrics["sparse_live_tile_share"] = jnp.mean(aux["sparse_live"])
-        return state, metrics

@@ -53,10 +53,12 @@ forward substitution on the VPU, the blocks below joined by products on the
 MXU at float32 precision, ``(I + M)^-1 = (I - M)(I + M^2)`` for the strictly
 block-lower rest ``M``: each such product is six passes of the MXU.
 
-All of it stands under the named scope ``phase_kda_core``, which
-``benchmark/metrics/kernel.kda_roofline.py`` reads; besides the two kernels
+All of it stands under the caller's part scope (``scope``: by default
+``phase_kda_core``, which ``benchmark/metrics/kernel.kda_roofline.py`` reads;
+Gated DeltaNet's mixer passes ``phase_gdn_core``); besides the two kernels
 that holds only what XLA does at the call's edge (a head count made even by
-padding, ``beta``'s trailing axis). Off the chip the kernels run in interpret
+padding, ``beta``'s trailing axis). A decay per head is a ``g`` equal across
+the channels, which is exact here. Off the chip the kernels run in interpret
 mode (``rowdma.on_tpu``).
 """
 
@@ -76,7 +78,7 @@ from swiftsnails_tpu.utils.profiling import part_scope
 CHUNK = 64  # tokens a chunk
 SUB = 16  # tokens a sub-block of the pairwise sums; CHUNK (or a smaller chunk) is a multiple
 HEADS = 8  # heads a grid step at most: the walk's fixed cost a step is paid once for all of them
-CORE_SCOPE = ("kda", "core")  # of ``utils/profiling.PARTS``: the named scope ``phase_kda_core``
+CORE_SCOPE = ("kda", "core")  # of ``utils/profiling.PARTS``: the named scope ``phase_kda_core``, the default
 
 _VMEM_LIMIT = 64 * 1024 * 1024
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -440,29 +442,30 @@ def _backward(q, k, v, g, beta, entering, do, chunk, sub, scale, dtype, interpre
     return dq, dk, dv, dg, dbeta[..., 0]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
-def _rule(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
-    return _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+def _rule(q, k, v, g, beta, chunk, sub, scale, dtype, interpret, scope):
+    return _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret, scope)[0]
 
 
-def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
-    with part_scope(*CORE_SCOPE):
+def _rule_fwd(q, k, v, g, beta, chunk, sub, scale, dtype, interpret, scope):
+    with part_scope(*scope):
         o, entering = _forward(q, k, v, g, beta, chunk, sub, scale, dtype, interpret)
     return o, (q, k, v, g, beta, entering)
 
 
-def _rule_bwd(chunk, sub, scale, dtype, interpret, res, do):
-    with part_scope(*CORE_SCOPE):
+def _rule_bwd(chunk, sub, scale, dtype, interpret, scope, res, do):
+    with part_scope(*scope):
         return _backward(*res, do, chunk, sub, scale, dtype, interpret)
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
-def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, scale=None, dtype=jnp.bfloat16):
+def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, scale=None, dtype=jnp.bfloat16, scope=CORE_SCOPE):
     """``q, k, g [H, L, K]``, ``v [H, L, V]``, ``beta [H, L]`` -> ``o [H, L,
     V]`` float32: :func:`recurrence` by chunks of ``chunk`` tokens (``L`` a
-    multiple of it). ``g`` must not be positive."""
+    multiple of it). ``g`` must not be positive. ``scope``: the pair of
+    ``utils/profiling.PARTS`` the call stands under."""
     heads, seq, width = q.shape
     if seq % chunk:
         raise ValueError(f"{seq} tokens are no whole chunks of {chunk}")
@@ -474,7 +477,7 @@ def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, scale=None, dtype=jnp
     scale = width ** -0.5 if scale is None else float(scale)
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
     return _rule(f32(q), f32(k), f32(v), f32(g), f32(beta), int(chunk), sub, scale, jnp.dtype(dtype),
-                 not on_tpu())
+                 not on_tpu(), tuple(scope))
 
 
 def gated_delta_flops(seq: int, heads: int, width: int, value_width: int, chunk: int = CHUNK) -> dict:

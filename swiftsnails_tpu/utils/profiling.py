@@ -84,18 +84,21 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # says noised, the two copies, their positions, the loss's weights), ``kda``
 # a gated delta-rule mixer (norm, projections, convolutions, gates, the
 # chunked recurrence, which ``ops/gated_delta.py`` names ``phase_kda_core``
-# inside it, the gated norm and the output projection). Scopes are metadata on the operations (no
+# inside it, the gated norm and the output projection), ``gdn`` the other
+# gated delta-rule mixer, Gated DeltaNet's (a decay per head, keys narrower
+# than values, a full-rank SiLU gate), whose recurrence stands under
+# ``phase_gdn_core``. Scopes are metadata on the operations (no
 # operation, no flag): a ``profile_dir`` capture shows them as the name
 # scope of each device operation, and ``benchmark/lib/scopes.py`` sums
 # device time by the innermost one. The prefix stays clear of the ``ssn_*``
 # labels that ``telemetry/audit.py`` groups collective bytes by.
 #
-# Four phases of a language-model step have parts (``phase_<phase>_<part>``,
+# Five phases of a language-model step have parts (``phase_<phase>_<part>``,
 # set inside the phase's scope around the CALL, so that a ``custom_vjp``'s
 # backward pass and the rematerialised forward carry it as they carry the
 # phase; ``benchmark/lib/parts.py`` reads them). ``attn`` / ``in``: the
 # block's norm, the projections to q, k and v with the latent norm or the
-# per-head norms, rotary and the head-major transposes in front of the
+# q/k norms (per head or over the held width), rotary and the head-major transposes in front of the
 # kernels. ``attn`` / ``core``: the ``flash_attention`` call alone, its three
 # kernels and what it does around them in XLA; under a learned selection
 # (``sa_config``) also the probability pass that gives ``L_I`` its ``p``.
@@ -103,13 +106,17 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # and ``L_I`` with its backward kernel. ``attn`` / ``select``: the indexer's
 # score kernel by query chunks, the exact top-k, the packed words and the
 # walks over the live tiles. ``attn`` / ``out``: the way
-# back from head-major, the sigmoid gate where there is one, ``W_o`` and the
-# residual sum. ``kda`` / ``in``: the norm, the q, k and v projections with
+# back from head-major, the sigmoid gate where there is one, ``W_o``, the
+# output's norm under ``norm_order: post``, and the residual sum. ``kda`` / ``in``: the norm, the q, k and v projections with
 # their causal convolutions, SiLU and the l2 norm, the rate's two products
 # and ``g``, beta, the head-major transposes. ``kda`` / ``core``: the chunked
 # recurrence (``ops/gated_delta.py`` sets it, forward and backward).
 # ``kda`` / ``out``: the way back from head-major, the low-rank gate, the
-# gated head norm, ``W_o``, the decay's mean and the residual sum.
+# gated head norm, ``W_o``, the decay's mean and the residual sum. ``gdn`` /
+# ``in``, ``core`` and ``out`` are the same for Gated DeltaNet: its per-head
+# rate ``W_a h`` in place of the low-rank one, its full-rank gate ``silu(W_g
+# h)`` in place of the low-rank sigmoid; under ``norm_order: post`` the
+# mixer's output norm stands in ``out`` and the feed-forward's in ``mlp``.
 # ``experts`` / ``gather``: tokens to rows, a live tile at a time.
 # ``experts`` / ``products``: ``grouped_swiglu``, the grouped kernels, the
 # stacked weights' casts and ``dw``. ``experts`` / ``scatter``: rows back to
@@ -119,9 +126,10 @@ def step_annotation(name: str, step: int) -> jax.profiler.StepTraceAnnotation:
 # into the row layout (inside ``phase_experts``), ``dropped`` and the live
 # tiles' share.
 PHASES = ("prep", "fused", "pull", "push", "dense",
-          "attn", "mlp", "route", "experts", "head", "opt", "noise", "kda")
+          "attn", "mlp", "route", "experts", "head", "opt", "noise", "kda", "gdn")
 PARTS = {"attn": ("in", "core", "out", "index", "select"),
          "kda": ("in", "core", "out"),
+         "gdn": ("in", "core", "out"),
          "experts": ("gather", "products", "scatter"),
          "route": ("score", "plan")}
 

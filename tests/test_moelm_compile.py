@@ -1,6 +1,6 @@
 """The kernels of the mixture-of-experts step (at Moonlight-16B-A3B's and at
 SDAR-30B-A3B-Chat's published widths), the chunked delta rule at
-Solar-Open2-250B's, and the table plane's gather (at the Wide&Deep cell's
+Solar-Open2-250B's and Olmo-Hybrid-7B's, and the table plane's gather (at the Wide&Deep cell's
 shapes) compile for a TPU v5e that is described and not attached: what
 interpret mode cannot show (tile alignment, VMEM, transposed products in
 Mosaic). Compile-only: nothing runs, and no time or result comes of it. The
@@ -132,29 +132,31 @@ def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkey
     assert whole.memory_analysis().temp_size_in_bytes <= unfused.memory_analysis().temp_size_in_bytes
 
 
-def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, monkeypatch):
-    """Solar-Open2-250B's delta-rule layer as the cell holds it: 8 heads of
-    128, chunks of 64, forward and backward: the two kernels, all eight heads
-    a grid step, no XLA solve left, and nothing the size of a state a token
-    (``[8, 2048, 128, 128]`` float32 is 1 GiB) or of every pair's decay is
-    allocated."""
+@pytest.mark.parametrize("heads,seq,width,value", [(8, 2048, 128, 128), (15, 4096, 96, 192)],
+                         ids=["solar", "olmo-hybrid"])
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, monkeypatch, heads, seq, width, value):
+    """Solar-Open2-250B's delta-rule layer as the cell holds it (8 heads of
+    128) and Olmo-Hybrid-7B's Gated DeltaNet (15 heads, keys of 96, values of
+    192: blocks no multiple of 128 lanes, an odd head count padded), chunks of
+    64, forward and backward: the two kernels, no XLA solve left, and nothing
+    the size of a state a token (``[8, 2048, 128, 128]`` float32 is 1 GiB) or
+    of every pair's decay is allocated."""
     from swiftsnails_tpu.ops import gated_delta
     from swiftsnails_tpu.ops.gated_delta import gated_delta_rule
 
     monkeypatch.setattr(gated_delta, "on_tpu", lambda: True)
-    heads, seq, width = 8, 2048, 128
 
     def loss(q, k, v, g, beta, w):
         return jnp.sum(gated_delta_rule(q, k, v, g, beta) * w)
 
-    f32, wide = jnp.float32, (heads, seq, width)
-    compiled = _compiled(jax.grad(loss, (0, 1, 2, 3, 4)), one_chip, (wide, f32), (wide, f32), (wide, f32),
-                         (wide, f32), ((heads, seq), f32), (wide, f32))
+    f32, wide, values = jnp.float32, (heads, seq, width), (heads, seq, value)
+    compiled = _compiled(jax.grad(loss, (0, 1, 2, 3, 4)), one_chip, (wide, f32), (wide, f32), (values, f32),
+                         (wide, f32), ((heads, seq), f32), (values, f32))
     kernels = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
     assert len(kernels) == 2 and all("gated_delta_" in line for line in kernels), kernels
     assert "triangular" not in compiled.as_text().lower()
-    assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * width * width * 4 / 4
-    assert [o.shape for o in compiled.out_info] == [wide, wide, wide, wide, (heads, seq)]
+    assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * width * value * 4 / 4
+    assert [o.shape for o in compiled.out_info] == [wide, wide, values, wide, (heads, seq)]
 
 
 # the Wide&Deep cell's pull (212,992 ids into a 4 GiB table of [2, 128]

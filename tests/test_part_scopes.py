@@ -7,12 +7,14 @@ parts are metadata (without them the lowered module is the same text).
 
 The steps are lowered for a TPU (no chip needed, nothing is compiled), so
 the kernels are Mosaic calls and not their interpreted bodies (the delta
-rule's two stand under ``phase_kda_core`` in all three passes); four tiny
-models: latent attention over a dense and two mixture layers (Moonlight's
-kind), grouped queries under the block-diffusion mask (SDAR's), the layer
-table with delta-rule layers and a gated softmax layer (Solar's), and
-grouped queries under a learned selection (Keye's, at tiles of 256: a tile's
-words are then a block Mosaic tiles)."""
+rule's two stand under ``phase_kda_core`` in all three passes, or under
+``phase_gdn_core`` where Gated DeltaNet calls them); five tiny models: latent
+attention over a dense and two mixture layers (Moonlight's kind), grouped
+queries under the block-diffusion mask (SDAR's), the layer table with
+delta-rule layers and a gated softmax layer (Solar's), grouped queries under
+a learned selection (Keye's, at tiles of 256: a tile's words are then a block
+Mosaic tiles), and a dense stack of Gated DeltaNet layers and a softmax layer
+with OLMo 2's residual order (Olmo-Hybrid's)."""
 
 import contextlib
 import re
@@ -25,6 +27,7 @@ import jax.numpy as jnp
 
 import test_moelm
 import test_moelm_diffusion
+import test_moelm_gdn
 import test_moelm_kda
 import test_moelm_sparse
 from swiftsnails_tpu.models import moelm
@@ -33,7 +36,9 @@ from swiftsnails_tpu.utils.profiling import PARTS, PHASES, part_scope
 
 _TILES_256 = {"seq_len": 512, "sa_config.q_chunk_size": 256, "sa_config.kv_chunk_size": 256}
 MODELS = {"mla": test_moelm, "gqa_bd": test_moelm_diffusion, "kda": test_moelm_kda,
-          "sparse": types.SimpleNamespace(_trainer=lambda **o: test_moelm_sparse._trainer(**{**_TILES_256, **o}))}
+          "sparse": types.SimpleNamespace(_trainer=lambda **o: test_moelm_sparse._trainer(**{**_TILES_256, **o})),
+          "gdn": test_moelm_gdn}
+DELTA = {"kda": "kda", "gdn": "gdn"}  # the models whose delta rule stands under ``phase_<it>_core``
 PASSES = ("forward", "rematerialised", "backward")
 _TOKEN = re.compile(r"phase_([a-z]+)(?:_([a-z]+))?")
 
@@ -104,9 +109,11 @@ def test_every_product_and_call_of_a_parted_phase_names_its_part(lowered, which)
         # the part stands inside its phase's scope (the plan inside ``phase_experts``, where it is made)
         assert tokens[-2] == ("experts" if (phase, part) == ("route", "plan") else phase, ""), path
         seen.add((phase, part))
-    want = {("attn", "in"), ("attn", "core"), ("attn", "out"), ("route", "score"), ("experts", "products")}
-    if kind == "kda":
-        want |= {("kda", "in"), ("kda", "core"), ("kda", "out")}
+    want = {("attn", "in"), ("attn", "core"), ("attn", "out")}
+    if kind != "gdn":  # a dense stack has no mixture layer
+        want |= {("route", "score"), ("experts", "products")}
+    if kind in DELTA:
+        want |= {(DELTA[kind], "in"), (DELTA[kind], "core"), (DELTA[kind], "out")}
     if kind == "sparse":  # the selection's products stand in a loop's body, which this walk does not enter
         want |= {("attn", "index")}
     assert want <= seen, (kind, which, sorted(want - seen))
@@ -121,13 +128,13 @@ def test_every_product_and_call_of_a_parted_phase_names_its_part(lowered, which)
 def test_kernels_stand_under_the_part_that_is_theirs(lowered, family):
     kind, ops, _ = lowered
     calls = [path for op, path in ops if op == "tpu_custom_call" and family in path]
-    if family == "gated_delta":  # the delta rule's two kernels, in every pass, under ``phase_kda_core``
-        if kind != "kda":
+    if family == "gated_delta":  # the delta rule's two kernels, in every pass, under its mixer's ``core``
+        if kind not in DELTA:
             assert not calls, calls
             return
         names = {re.search(r"gated_delta_\w+", p).group(0) for p in calls}
         assert names == {"gated_delta_fwd", "gated_delta_bwd"}
-        assert all(_TOKEN.findall(p)[-1] == ("kda", "core") for p in calls), calls
+        assert all(_TOKEN.findall(p)[-1] == (DELTA[kind], "core") for p in calls), calls
         assert {_pass_of(p) for p in calls if "gated_delta_fwd" in p} == {"forward", "rematerialised"}
         assert {_pass_of(p) for p in calls if "gated_delta_bwd" in p} == {"backward"}
         return
@@ -136,6 +143,9 @@ def test_kernels_stand_under_the_part_that_is_theirs(lowered, family):
         assert names == {"flash_attention_fwd", "flash_attention_dq", "flash_attention_dkv"}
         assert all(_TOKEN.findall(p)[-1] == ("attn", "core") for p in calls), calls
         assert {_pass_of(p) for p in calls} == set(PASSES)
+        return
+    if kind == "gdn":  # no mixture layer, no grouped products
+        assert not calls, calls
         return
     names = {re.search(r"grouped_matmul\w*", p).group(0) for p in calls}
     assert names == {"grouped_matmul", "grouped_matmul_swiglu", "grouped_matmul_dswiglu", "grouped_matmul_dx",
@@ -151,8 +161,11 @@ def test_kernels_stand_under_the_part_that_is_theirs(lowered, family):
 def test_the_plan_stands_inside_the_experts_phase_and_reads_as_route(lowered):
     """``plan_rows`` is called from ``_experts``: its operations' last token
     is ``phase_route_plan``, the one before it ``phase_experts``."""
-    _, _, txt = lowered
+    kind, _, txt = lowered
     plan = set(re.findall(r'loc\("([^"]*phase_route_plan[^"]*)"', txt))
+    if kind == "gdn":  # a dense stack plans nothing
+        assert not plan and "phase_experts" not in txt
+        return
     assert any(re.search(r"phase_route_plan/.*sort", p) for p in plan), sorted(plan)[:5]
     assert all(_TOKEN.findall(p)[-2:] == [("experts", ""), ("route", "plan")] for p in plan)
     assert not re.search(r'loc\("[^"]*phase_experts[^"]*phase_route/', txt)  # the bare scope stood there before
@@ -176,7 +189,9 @@ def test_parts_are_metadata(kind):
         assert _lowered(kind, mp, debug_info=False, tpu=False) == with_parts[False]
         assert body.sub("", _lowered(kind, mp, debug_info=False)) == body.sub("", with_parts[True])
     assert "loc(" not in with_parts[False] and "phase_" not in with_parts[False]
-    assert len(body.findall(with_parts[True])) >= 20  # the Mosaic calls of two mixture layers, three passes
+    # the Mosaic calls of two mixture layers, three passes; of the dense stack's three delta-rule layers and one
+    # softmax layer
+    assert len(body.findall(with_parts[True])) >= (13 if kind == "gdn" else 20)
 
 
 @pytest.mark.parametrize("pair", [("attn", "plan"), ("mlp", "in"), ("warmup", "core"), ("kda", "products"),
@@ -188,7 +203,8 @@ def test_part_scope_raises_on_an_unknown_pair(pair):
 
 def test_the_table_of_parts():
     assert PARTS == {"attn": ("in", "core", "out", "index", "select"), "kda": ("in", "core", "out"),
-                     "experts": ("gather", "products", "scatter"), "route": ("score", "plan")}
+                     "gdn": ("in", "core", "out"), "experts": ("gather", "products", "scatter"),
+                     "route": ("score", "plan")}
     assert set(PARTS) <= set(PHASES)
     # benchmark/lib/parts.py reads phase_[a-z]+_[a-z]+, benchmark/lib/scopes.py the phase before the second _
     assert all(p.isalpha() and p.islower() for parts in PARTS.values() for p in parts)
