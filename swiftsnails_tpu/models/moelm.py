@@ -166,7 +166,11 @@ the layers averaged; a gate that stopped decaying reads 1, one that
 wipes the state 0; under ``sa_config`` ``sparse_live_tile_share`` (state:
 ``sparse_live``; it takes ``attn_whole_tile_share``'s place, which a selection
 has not): the causal tiles that hold a pick, and ``sparse_indexer_kl``
-(state: ``sparse_kl``): ``L_I``, the layers averaged. ``stack``'s counts
+(state: ``sparse_kl``): ``L_I``, the layers averaged, and
+``sparse_tie_row_share`` (metrics only): the queries whose ``topk``-th score
+was tied and had some of its ties left out, so that the top-k searched their
+columns too (``ops/sparse_attention.sparse_top_k``), the layers averaged.
+``stack``'s counts
 carry each layer's packed selection (``selected``), which the train step
 leaves out of the state. The parts ``attn`` /
 ``index`` (the indexer, ``L_I``) and ``attn`` / ``select`` (scores, top-k,
@@ -267,7 +271,7 @@ INDEX_NORM_EPS = 1e-6  # the indexer keys' LayerNorm (DeepSeek-V3.2's)
 # what a layer with a selection keeps for the backward pass (``jax.checkpoint``'s names)
 SAVED = ("sparse_selection", "sparse_stats")
 # what a layer's mixer counts, whatever its feed-forward part: ``stack`` stacks each by its name
-MIXER_COUNTS = ("kda_decay", "gdn_decay", "index_loss", "sparse_live", "selected")
+MIXER_COUNTS = ("kda_decay", "gdn_decay", "index_loss", "sparse_live", "sparse_ties", "selected")
 DELTA = ("kda", "gdn")  # the gated delta-rule mixers: a decay per channel, one per head
 # ``layer_types``' names -> the kind of mixer (None: the attention the other keys give)
 LAYER_TYPES = {"full_attention": None, "linear_attention": "gdn"}
@@ -618,6 +622,7 @@ class MoELMTrainer(SeqLMTrainer):
         query, the attention kernels under that selection, and ``L_I``.
         -> (``[B * H, L, head_dim]``, {``index_loss``: ``L_I``,
         ``sparse_live``: the live tiles' share of the causal ones,
+        ``sparse_ties``: the share of queries whose ties were searched,
         ``selected``: the words ``[B, L / 32, L]``})."""
         hi, di, block, dtype = self.index_heads, self.index_dim, self.attention_block, self.matmul_dtype
         hold = jax.lax.stop_gradient
@@ -632,15 +637,16 @@ class MoELMTrainer(SeqLMTrainer):
             w = self._mm(h, p["index_ww"]).reshape(b, seq, hi) * hi ** -0.5
         n = seq // block
         causal = n * (n + 1) // 2
-        outs, losses, lives, words = [], [], [], []
+        outs, losses, lives, ties, words = [], [], [], [], []
         for r in range(b):
             qr, kr, vr = (t[r * t.shape[0] // b:(r + 1) * t.shape[0] // b] for t in (q, k, v))
             with part_scope("attn", "select"):
-                bits, live = select(hold(qi[r]), hold(ki[r]), hold(w[r]), self.topk, block, dtype)
+                bits, live, tied = select(hold(qi[r]), hold(ki[r]), hold(w[r]), self.topk, block, dtype)
                 # kept for the backward pass, not selected again there
                 sel = jax.tree_util.tree_map(lambda a: checkpoint_name(a, SAVED[0]),
                                              walks(bits, live, self.n_heads // self.kv_heads))
                 lives.append(jnp.sum(live) / causal)
+                ties.append(tied)
                 words.append(sel.bits)
             with part_scope("attn", "core"):
                 o, lse = selected_attention(qr, kr, vr, sel, block=block, dtype=dtype)
@@ -651,7 +657,7 @@ class MoELMTrainer(SeqLMTrainer):
                 losses.append(indexer_loss(qi[r], ki[r], w[r], qr, kr, lse, sel, stats, block, dtype, None))
             outs.append(o)
         return jnp.concatenate(outs), {"index_loss": sum(losses) / b, "sparse_live": sum(lives) / b,
-                                       "selected": jnp.stack(words)}
+                                       "sparse_ties": sum(ties) / b, "selected": jnp.stack(words)}
 
     def _kda(self, p, x, b, kind="kda"):
         """``x [B * L, d]`` -> (a delta-rule mixer's output, same shape; the
@@ -843,6 +849,7 @@ class MoELMTrainer(SeqLMTrainer):
         if self.topk:
             state["sparse_kl"] = metrics["sparse_indexer_kl"] = jnp.mean(aux["index_loss"])
             state["sparse_live"] = metrics["sparse_live_tile_share"] = jnp.mean(aux["sparse_live"])
+            metrics["sparse_tie_row_share"] = jnp.mean(aux["sparse_ties"])
         return state, metrics
 
     def _after_mixture(self, state, aux, metrics):

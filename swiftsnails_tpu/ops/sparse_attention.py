@@ -17,10 +17,10 @@ in float32 exists:
 * :func:`select` — the indexer's scores by query chunks of ``block``
   (:func:`index_scores`, a kernel over the chunk's causal key tiles: ``[block,
   L]`` float32 at a time), the exact top ``topk`` of each query's causal keys
-  (:func:`top_k_mask`, ties to the lower index as ``lax.top_k`` breaks them;
-  every causal key where ``t < topk``), packed into the selection's words
-  ``[L / 32, L]`` int32, and which (query tile, key tile) pairs hold a
-  selected pair.
+  (:func:`sparse_top_k`, a kernel over the same tiles; ties to the lower
+  index as ``lax.top_k`` breaks them; every causal key where ``t < topk``),
+  packed into the selection's words ``[L / 32, L]`` int32, and which (query
+  tile, key tile) pairs hold a selected pair.
 * :func:`walks` — from those live tiles, the two step tables of the attention
   kernels (``flash_attention.Selection``), in the order the causal walks take.
 * :func:`probability_pass` — per query, over ``S_t`` and tile by tile: the
@@ -115,30 +115,162 @@ def index_scores(qi, ki, w, chunk, block, interpret):
 # --------------------------------------------------------- the selection ---
 
 
-def _order_key(x):
-    """float32 -> uint32 in the same order (``-0.0`` made ``+0.0`` first)."""
-    bits = jax.lax.bitcast_convert_type(x + 0.0, jnp.uint32)
-    return jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(0x80000000))
+_ROWS = 64  # the top-k's query rows a grid step (whole rows of words): a pass waits on the last one's counts
+_UNROLL = 4  # key tiles a trip of a counting loop: one tile's compares and adds wait on the last's
+_SIGN = -2 ** 31  # the sign bit of an int32
 
 
-def top_k_mask(scores, valid, k: int):
-    """``[rows, L]`` bool: of each row's ``valid`` entries, the ``k`` largest
-    ``scores``, ties to the lower index (``lax.top_k``'s rule); every valid
-    entry of a row that has ``k`` or fewer. Exact, by the ``k``-th largest
-    value's bits, most significant first (32 passes of a count), and a count
-    of the ties at that value: no sort."""
-    key = jnp.where(valid, _order_key(scores), jnp.uint32(0))
+def _key_value(key):
+    """The float32 whose order key is ``key`` (int32: the sign and magnitude of
+    the float's bits, so that ``-0.0`` and ``+0.0`` are one key and the keys
+    order as the floats do)."""
+    return jax.lax.bitcast_convert_type(jnp.where(key < 0, -key | _SIGN, key), jnp.float32)
 
-    def bit(b, least):
-        cand = least | (jnp.uint32(1) << (31 - b).astype(jnp.uint32))
-        enough = jnp.sum(key >= cand[:, None], axis=1, dtype=jnp.int32) >= k
-        return jnp.where(enough, cand, least)
 
-    least = jax.lax.fori_loop(0, 32, bit, jnp.zeros(key.shape[:1], jnp.uint32))
-    above = key > least[:, None]
-    tie = valid & (key == least[:, None])
-    need = k - jnp.sum(above, axis=1, dtype=jnp.int32)
-    return above | (tie & (jnp.cumsum(tie, axis=1, dtype=jnp.int32) <= need[:, None]))
+def _over_tiles(tiles, body, carry=0):
+    """``body(kj, carry)`` over key tiles ``0..tiles-1``, ``_UNROLL`` of them a
+    trip of the loop."""
+    def trip(i, carry):
+        for u in range(_UNROLL):
+            carry = body(i * _UNROLL + u, carry)
+        return carry
+
+    carry = jax.lax.fori_loop(0, tiles // _UNROLL, trip, carry)
+    return jax.lax.fori_loop(tiles - tiles % _UNROLL, tiles, body, carry)
+
+
+def _top_k_kernel(chunk_ref, scores_ref, words_ref, ties_ref, diag_ref, last_ref, *, topk):
+    r, c = pl.program_id(0), chunk_ref[0]
+    rows, block = diag_ref.shape
+    seq = scores_ref.shape[1]
+    words = rows // 32
+    t = c * block + r * rows + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)  # each row's query
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
+    at = pl.multiple_of(r * rows, rows)
+
+    def scores(kj):  # a key tile before the diagonal one
+        return scores_ref[pl.ds(at, rows), pl.ds(pl.multiple_of(kj * block, block), block)]
+
+    def count(hit):
+        """``[rows, 1]`` int32: the causal keys for which ``hit(scores, first
+        column)`` holds, the chunk's key tiles up to its diagonal one."""
+        def tile(x, col, acc):
+            one = hit(x, col).astype(jnp.int32)
+            parts = [one[:, i:i + _LANES] for i in range(0, block, _LANES)]
+            while len(parts) > 1:  # a tree of lane-aligned slices, then one add into the partials
+                parts = [a + b for a, b in zip(parts[::2], parts[1::2])] + parts[len(parts) - len(parts) % 2:]
+            return acc + parts[0]
+
+        acc = _over_tiles(c, lambda kj, acc: tile(scores(kj), kj * block, acc),
+                          jnp.zeros((rows, _LANES), jnp.int32))
+        return jnp.sum(tile(diag_ref[...], c * block, acc), axis=1, keepdims=True)
+
+    def pack(keep):
+        """Each row's ``keep(scores, columns)`` over the causal tiles, as words."""
+        shift = jnp.left_shift(1, jax.lax.broadcasted_iota(jnp.int32, (rows, block), 0) % 32)
+
+        def tile(x, kj, carry):
+            bits = jnp.where(keep(x, kj * block + lane), shift, 0)
+            for w in range(words):  # distinct bits: the sum is their OR
+                words_ref[pl.ds(r * words + w, 1), pl.ds(pl.multiple_of(kj * block, block), block)] = jnp.sum(
+                    bits[w * 32:(w + 1) * 32], axis=0, keepdims=True)
+            return carry
+
+        _over_tiles(c, lambda kj, carry: tile(scores(kj), kj, carry))
+        tile(diag_ref[...], c, 0)
+
+    @pl.when(r == 0)
+    def _():  # the words of the key tiles after the chunk stay zeros
+        words_ref[...] = jnp.zeros_like(words_ref)
+        ties_ref[...] = jnp.zeros_like(ties_ref)
+
+    # the diagonal tile, NaN past each row's query: it then neither counts nor is kept
+    diag_ref[...] = jnp.where(c * block + lane <= t, scores_ref[pl.ds(at, rows), pl.ds(
+        pl.multiple_of(c * block, block), block)], jnp.nan)
+    few = c * block + (r + 1) * rows <= topk  # every row of the block has topk or fewer causal keys
+
+    @pl.when(few)
+    def _():
+        pack(lambda x, col: col <= t)
+
+    @pl.when(jnp.logical_not(few))
+    def _():
+        # the topk-th largest key, its bits most significant first (over key ^ _SIGN,
+        # which orders as unsigned); a row is done once a candidate keeps exactly
+        # topk, those being its top topk whatever ties lie below, and the passes
+        # stop once every row of the block is
+        few_keys = t < topk  # topk or fewer causal keys: all of them
+
+        def unsure(carry):
+            b, _, done = carry
+            return (b < 32) & jnp.any(done == 0)
+
+        def bit(carry):
+            b, least, done = carry
+            cand = least | jnp.left_shift(1, 31 - b)
+            value = _key_value(cand ^ _SIGN)
+            hits = count(lambda x, col: x >= value)
+            keep = (done == 0) & (hits >= topk)
+            return b + 1, jnp.where(keep, cand, least), jnp.where((done == 0) & (hits == topk), 1, done)
+
+        zeros = jnp.zeros((rows, 1), jnp.int32)
+        _, least, done = jax.lax.while_loop(unsure, bit, (0, zeros, few_keys.astype(jnp.int32)))
+        # keep what is above least, and what equals it up to column last
+        least = jnp.where(few_keys, -jnp.inf, _key_value(least ^ _SIGN))
+        last_ref[...] = jnp.broadcast_to(jnp.where((done == 1) & ~few_keys, seq, t), last_ref.shape)
+
+        @pl.when(jnp.any(done == 0))
+        def _():
+            # a row not done has more than topk keys at or above its topk-th: it is
+            # tied, and some ties are left out; ties go to the lower column
+            # (``lax.top_k``'s rule), so the last one kept is found by its column's bits
+            search = done == 0
+            ties_ref[...] += jnp.sum(search.astype(jnp.int32))
+            need = topk - count(lambda x, col: x > least)
+            width = (seq - 1).bit_length()
+
+            def col_bit(b, m):
+                cand = m | jnp.left_shift(1, width - 1 - b)
+                before = count(lambda x, col: (x == least) & (col + lane < cand))
+                return jnp.where(before < need, cand, m)
+
+            m = jax.lax.fori_loop(0, width, col_bit, zeros)
+            last_ref[...] = jnp.broadcast_to(jnp.where(search, m, last_ref[:, :1]), last_ref.shape)
+
+        last = last_ref[:, :1]
+        pack(lambda x, col: (x > least) | ((x == least) & (col <= last)))
+
+
+def sparse_top_k(scores, chunk, topk: int, interpret):
+    """Of each row of query chunk ``chunk``'s scores (``[block, L]`` float32,
+    finite, :func:`index_scores`), the ``topk`` largest among its causal
+    keys, ties to the lower column (``lax.top_k``'s rule), every causal key
+    of a row that has ``topk`` or fewer, packed (:func:`pack_bits`' layout):
+    -> (``[block / 32, L]`` int32, the rows whose ``topk``-th value was tied
+    and had some of its ties left out). Exact, with no sort: counting passes
+    find each row's ``topk``-th value bit by bit, a block of rows at a time,
+    over the chunk's causal key tiles alone (the scores stay in VMEM; the
+    tiles after the chunk's are neither read nor counted, their words are
+    zeros), and stop once a candidate keeps exactly ``topk`` in every row (at
+    most 32); a row tied at its ``topk``-th value finds its last kept tie by
+    the column's bits."""
+    block, seq = scores.shape
+    if not interpret:  # where the score kernel writes them, as XLA's own top-k had them
+        scores = pltpu.with_memory_space_constraint(scores, pltpu.VMEM)
+    words, ties = pl.pallas_call(
+        functools.partial(_top_k_kernel, topk=topk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(block // _ROWS,),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=[pl.BlockSpec((block // 32, seq), lambda r, c: (0, 0)),
+                       pl.BlockSpec((1, _LANES), lambda r, c: (0, 0))],
+            scratch_shapes=[pltpu.VMEM((_ROWS, block), jnp.float32),
+                            pltpu.VMEM((_ROWS, _LANES), jnp.int32)]),
+        out_shape=[jax.ShapeDtypeStruct((block // 32, seq), jnp.int32),
+                   jax.ShapeDtypeStruct((1, _LANES), jnp.int32)],
+        name="sparse_top_k", **_params(interpret, ("arbitrary",)),
+    )(jnp.reshape(chunk, (1,)).astype(jnp.int32), scores)
+    return words, ties[0, 0]
 
 
 def pack_bits(keep):
@@ -151,25 +283,24 @@ def pack_bits(keep):
 
 def select(qi, ki, w, topk: int, block: int, dtype=jnp.bfloat16, interpret=None):
     """(the selection's words ``[L / 32, L]`` int32, the live tiles ``[n, n]``
-    bool, query tile by key tile), by query chunks of ``block``: the chunk's
-    scores, their top ``topk`` among its causal keys, packed."""
+    bool, query tile by key tile, the share of queries whose ``topk``-th
+    value was tied and had some of its ties left out), by query chunks of
+    ``block``: the chunk's scores, their top ``topk`` among its causal keys,
+    packed (:func:`sparse_top_k`)."""
     if interpret is None:
         interpret = not on_tpu()
     seq = ki.shape[0]
-    if block % 32 or seq % block:
-        raise ValueError(f"{seq} positions do not split into tiles of {block} (a multiple of 32)")
+    if block % _ROWS or seq % block:
+        raise ValueError(f"{seq} positions do not split into tiles of {block} (a multiple of {_ROWS})")
     n = seq // block
     qi, ki = qi.astype(dtype), ki.astype(dtype)
 
     def chunk(c):
-        scores = index_scores(qi, ki, w, c, block, interpret)
-        t = c * block + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
-        keep = top_k_mask(scores, jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1) <= t, topk)
-        words = pack_bits(keep)
-        return words, jnp.any(words.reshape(block // 32, n, block) != 0, axis=(0, 2))
+        words, tied = sparse_top_k(index_scores(qi, ki, w, c, block, interpret), c, topk, interpret)
+        return words, jnp.any(words.reshape(block // 32, n, block) != 0, axis=(0, 2)), tied
 
-    words, live = jax.lax.map(chunk, jnp.arange(n))
-    return words.reshape(seq // 32, seq), live
+    words, live, tied = jax.lax.map(chunk, jnp.arange(n))
+    return words.reshape(seq // 32, seq), live, jnp.sum(tied) / seq
 
 
 def _table(visit, kind, qi, kj, head, run, size):

@@ -183,9 +183,9 @@ KEYE = {"seq": 16384, "heads": 32, "kv": 4, "dim": 128, "index_heads": 16, "inde
 def test_sparse_kernels_compile_at_published_widths(one_chip, no_cache, kernel):
     """The three attention kernels on a selection's run-time walks (the
     tables as long as the causal walk, the dkv one 8 heads deep: 4,224
-    steps in scalar memory), the score kernel with the top-k by chunks, the
-    probability pass and the indexer's backward kernel, with the 32 heads'
-    probabilities recomputed in VMEM."""
+    steps in scalar memory), the score kernel and the top-k kernel by
+    chunks, the probability pass and the indexer's backward kernel, with the
+    32 heads' probabilities recomputed in VMEM."""
     from swiftsnails_tpu.ops import sparse_attention as sa
 
     m = KEYE
@@ -216,5 +216,7 @@ def test_sparse_kernels_compile_at_published_widths(one_chip, no_cache, kernel):
         shapes = main + (((m["heads"], seq), f32),) + index + sel + (((seq, 128), f32),)
     compiled = _compiled(fn, one_chip, *shapes)
     assert "tpu_custom_call" in compiled.as_text()
+    if kernel == "select":  # the top-k is a kernel of its own beside the score kernel, not XLA's loop
+        assert "sparse_top_k" in compiled.as_text() and "sparse_index_scores" in compiled.as_text()
     # the scores stay in VMEM a tile at a time: nothing the size of [heads, L, L] is allocated
     assert compiled.memory_analysis().temp_size_in_bytes < m["heads"] * seq * seq * 4 / 4

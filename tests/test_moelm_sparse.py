@@ -96,25 +96,65 @@ def test_the_indexers_scores_are_the_formula():
         assert not np.asarray(got[:, seen:]).any()
 
 
-@pytest.mark.parametrize("k", [1, 7, 64, 600])
-def test_the_selection_is_lax_top_k_with_its_ties(k):
-    """Ties (a tenth of the scores are one value, a tenth are zeros of either
-    sign) go to the lower index; rows with ``k`` or fewer causal keys keep
-    them all."""
-    rows, seq = 64, 512
-    rng = np.random.default_rng(k)
+def _top_k_want(scores, c, k):
+    """``lax.top_k``'s choice among each row's causal keys (row ``i`` of
+    chunk ``c`` is query ``c * rows + i``), as a ``[rows, L]`` bool mask."""
+    rows, seq = scores.shape
+    valid = np.arange(seq)[None, :] <= c * rows + np.arange(rows)[:, None]
+    _, idx = jax.lax.top_k(jnp.where(valid, jnp.asarray(scores) + 0.0, -jnp.inf), min(k, seq))
+    want = np.zeros((rows, seq), bool)
+    want[np.arange(rows)[:, None], np.asarray(idx)] = True
+    return want & valid
+
+
+def _tied_scores(rows, seq, seed):
+    """A tenth of the scores one value, a tenth zeros of either sign."""
+    rng = np.random.default_rng(seed)
     scores = rng.normal(size=(rows, seq)).astype(np.float32)
     scores[rng.random((rows, seq)) < 0.1] = 0.75
     scores[rng.random((rows, seq)) < 0.1] = 0.0
     scores[rng.random((rows, seq)) < 0.05] = -0.0
-    t = 200 + 4 * np.arange(rows)[:, None] - 150 * (np.arange(rows)[:, None] < 8)
-    valid = np.arange(seq)[None, :] <= t
-    got = np.asarray(sa.top_k_mask(jnp.asarray(scores), jnp.asarray(valid), k))
-    _, idx = jax.lax.top_k(jnp.where(valid, jnp.asarray(scores) + 0.0, -jnp.inf), min(k, seq))
-    want = np.zeros((rows, seq), bool)
-    want[np.arange(rows)[:, None], np.asarray(idx)] = True
-    np.testing.assert_array_equal(got, want & valid)
-    np.testing.assert_array_equal(got.sum(axis=1), np.minimum(valid.sum(axis=1), k))
+    return scores
+
+
+@pytest.mark.parametrize("k", [1, 7, 64, 600])
+def test_the_selection_is_lax_top_k_with_its_ties(k):
+    """The top-k kernel by query chunks (the first, one inside, the last):
+    ties go to the lower index; rows with ``k`` or fewer causal keys keep
+    them all (``k`` 7 and 64 put both kinds of row in one block of 32, 600
+    every row of every chunk); the key tiles after the chunk are not read
+    (they hold noise here, where the score kernel writes zeros) and their
+    words are zeros."""
+    seq = 512
+    for c in (0, 1, 3):
+        scores = _tied_scores(BLOCK, seq, 10 * k + c)
+        words, tied = sa.sparse_top_k(jnp.asarray(scores), jnp.int32(c), k, True)
+        got = np.asarray(fa.unpack_bits(words))
+        want = _top_k_want(scores, c, k)
+        np.testing.assert_array_equal(got, want, err_msg=f"chunk {c}")
+        t = c * BLOCK + np.arange(BLOCK)
+        np.testing.assert_array_equal(got.sum(axis=1), np.minimum(t + 1, k))
+        assert 0 <= int(tied) <= int((t >= k).sum())
+
+
+@pytest.mark.parametrize("k", [100, 257])
+def test_the_top_k_kernel_where_a_block_mixes_few_and_many_keys(k):
+    """A ``k`` that is no multiple of 32: in the block of 32 rows where the
+    rows reach ``k + 1`` causal keys, some keep all theirs and the rest run
+    the counting passes; a row's ties, where some must be left out, are
+    searched by column (and counted), the others not."""
+    seq, c = 512, (k + 1) // BLOCK
+    rng = np.random.default_rng(k)
+    scores = (rng.integers(-4, 5, (BLOCK, seq)) / 2).astype(np.float32)  # nine values: most k-th ones tied
+    scores[rng.random((BLOCK, seq)) < 0.05] = -0.0
+    words, tied = sa.sparse_top_k(jnp.asarray(scores), jnp.int32(c), k, True)
+    np.testing.assert_array_equal(np.asarray(fa.unpack_bits(words)), _top_k_want(scores, c, k))
+    # the rows whose k-th value is tied with ties left out: as lax.top_k leaves some out
+    t = c * BLOCK + np.arange(BLOCK)
+    kth = np.sort(np.where(np.arange(seq)[None, :] <= t[:, None], scores + 0.0, -np.inf), axis=1)[:, -k]
+    ties = (scores + 0.0 == kth[:, None]) & (np.arange(seq)[None, :] <= t[:, None])
+    above = ((scores + 0.0 > kth[:, None]) & (np.arange(seq)[None, :] <= t[:, None])).sum(axis=1)
+    assert int(tied) == int(((t >= k) & (k - above < ties.sum(axis=1))).sum()) > 0
 
 
 def test_packed_words_and_the_walks():
@@ -142,10 +182,35 @@ def test_packed_words_and_the_walks():
     np.testing.assert_array_equal(np.asarray(full.by_key)[rows], fa._steps(fa.Causal(), n, 2)[rows])
 
 
+def test_select_against_a_dense_top_k_with_planted_ties():
+    """The score kernel and the top-k kernel by chunks of 512 over 2,048
+    keys (topk 256): with small whole-number operands every score is exact
+    whichever way it is summed and most rows' 256th value is tied, the
+    words and live tiles are a dense ``lax.top_k`` mask's, packed; with
+    random operands no row's ties are searched."""
+    seq, block, topk = 2048, 512, 256
+    rng = np.random.default_rng(43)
+    qi = jnp.asarray(rng.integers(-2, 3, (4, seq, 16)), jnp.float32)
+    ki = jnp.asarray(rng.integers(-2, 3, (seq, 16)), jnp.float32)
+    w = jnp.asarray(rng.integers(-2, 3, (seq, 4)) / 4, jnp.float32)
+    words, live, share = sa.select(qi, ki, w, topk, block)
+    causal = np.tril(np.ones((seq, seq), bool))
+    _, idx = jax.lax.top_k(jnp.where(causal, _index_dense(qi, ki, w) + 0.0, -jnp.inf), topk)
+    keep = np.zeros((seq, seq), bool)
+    keep[np.arange(seq)[:, None], np.asarray(idx)] = True
+    want = sa.pack_bits(jnp.asarray(keep & causal))
+    np.testing.assert_array_equal(np.asarray(words), np.asarray(want))
+    n = seq // block
+    np.testing.assert_array_equal(np.asarray(live), (keep & causal).reshape(n, block, n, block).any(axis=(1, 3)))
+    assert 0 < float(share) <= 1 - topk / seq
+    _, _, _, qi, ki, w = _operands(seq=seq, hi=16)  # a score is exactly 0 where all 16 heads' ReLU are
+    assert float(sa.select(qi, ki, w, topk, block)[2]) == 0.0
+
+
 @pytest.fixture(scope="module")
 def selected():
     q, k, v, qi, ki, w = _operands()
-    words, live = sa.select(qi, ki, w, 64, BLOCK)
+    words, live, _ = sa.select(qi, ki, w, 64, BLOCK)
     sel = sa.walks(words, live, 2)
     return (q, k, v, qi, ki, w), sel, np.asarray(fa.unpack_bits(words))
 
