@@ -103,7 +103,7 @@ norm has a learned gain and ``rms_norm_eps``:
   ``linear_conv_kernel_dim`` taps; FLA's and Qwen3-Next's shapes). As KDA,
   but for three things: the decay is one a head, ``g_t = -exp(a_log_head) *
   softplus(W_a h_t + dt_bias_head)`` (``W_a [d, H]``), handed to the chunked
-  rule equal across the key channels; the state is ``[K, V]`` and ``o_t =
+  rule once a head, which runs its per-head kernels; the state is ``[K, V]`` and ``o_t =
   S_t^T q_t / sqrt(K)``; the gate is full-rank, ``y_t = RMSNorm_onorm(o_t)``
   (over the head's ``V``) ``* silu(W_g h_t)`` (``W_g [d, H x V]``).
   ``linear_allow_neg_eigval`` doubles beta. The same fresh leaves, ``dt_bias``
@@ -675,9 +675,9 @@ class MoELMTrainer(SeqLMTrainer):
             if kind == "kda":  # a rate a channel
                 rate = jax.nn.softplus(self._mm(self._mm(y, p["f_down"]), p["f_up"]) + p["dt_bias"])
                 g = -jnp.exp(p["a_log"])[:, None] * heads(rate)
-            else:  # a rate a head, the same for its channels
+            else:  # a rate a head, ``[B, L, h, 1]``: the rule's per-head kernels
                 rate = heads(jax.nn.softplus(self._mm(y, p["wa"]) + p["dt_bias"]))
-                g = -jnp.exp(p["a_log"])[:, None] * jnp.broadcast_to(rate, q.shape)
+                g = -jnp.exp(p["a_log"])[:, None] * rate
             beta = s.beta_max * jax.nn.sigmoid(self._mm(y, p["wb"]))
             fold = lambda t: t.transpose(0, 2, 1, 3).reshape(b * h, seq, -1)  # noqa: E731
             folded = fold(q), fold(k), fold(v), fold(g), fold(heads(beta))[..., 0]

@@ -1,5 +1,6 @@
-"""The gated delta rule with a decay per channel (Kimi Delta Attention,
-arXiv:2510.26692), by chunks, as Mosaic kernels: the recurrent mixer beside
+"""The gated delta rule by chunks, as Mosaic kernels, in two forms: a decay
+per channel (Kimi Delta Attention, arXiv:2510.26692) and a decay per head
+(Gated DeltaNet, arXiv:2412.06464). The recurrent mixer beside
 ``ops/flash_attention.py``'s softmax.
 
 Per head, with ``q_t, k_t`` of width K, ``v_t`` of width V, a log-decay
@@ -21,45 +22,65 @@ the running sum of ``g`` inside a chunk and ``S_0`` the state entering it::
     S_C = diag(exp(G_C)) S_0 + sum_j (k_j * exp(G_C - G_j)) u~_j^T
 
 No exponent here is ever positive: ``exp(-G_j)`` alone overflows float32
-under a fast decay, so the pairwise sums go by sub-blocks of ``SUB`` tokens:
-inside a sub-block the differences ``G_rc - G_jc`` themselves are
-exponentiated (elementwise, float32), between sub-blocks both sides are
-scaled through ``G`` at the row block's first token (``exp(G_r - ref) *
-exp(ref - G_j)``, each factor at most 1) and multiplied on the MXU.
+under a fast decay.
+
+*A decay per channel* (``g [H, L, K]``): the pairwise sums go by sub-blocks
+of ``SUB`` tokens: inside a sub-block the differences ``G_rc - G_jc``
+themselves are exponentiated (elementwise, float32), between sub-blocks both
+sides are scaled through ``G`` at the row block's first token (``exp(G_r -
+ref) * exp(ref - G_j)``, each factor at most 1) and multiplied on the MXU.
+
+*A decay per head* (``g [H, L]`` or ``[H, L, 1]``): ``G_rc - G_jc`` is one
+number a pair, so each pairwise sum is one product masked by the chunk's
+``[C, C]`` decays ``D_rj = exp(G_r - G_j)`` (``j <= r``; 0 above the
+diagonal): ``A = diag(beta) (K K^T * D)``, and ``Q K^T * D`` for the outputs.
+The differences are summed directly, ``G_r - G_j = sum_(j < t <= r) g_t``,
+one product with a triangle of ones, so none is a difference of two large
+sums. The state decays by the scalar ``exp(G_C)``.
 
 What is float32: ``g``, its running sums, every exponential, the triangular
-solve, the state. The operands of the other matrix products are rounded to
-``dtype`` and accumulated in float32, forward and backward.
+solve, the state, and the pairwise sums inside a sub-block; in the per-head
+form every pairwise sum and its gradients, each one product at float32
+precision (rounding the pairs between sub-blocks would add a product at
+``dtype`` beside it, not take one away). The operands of the other matrix
+products (the per-channel pairwise sums between sub-blocks among them) are
+rounded to ``dtype`` and accumulated in float32, forward and backward.
 
-Two kernels, each over the grid (group of heads, chunk), a group's chunks in
-order; a grid step holds ``HEADS`` heads' chunk in VMEM and nothing of a
+Two kernels a form, each over the grid (group of heads, chunk), a group's
+chunks in order; a grid step holds a group's chunk in VMEM and nothing of a
 chunk's own goes out to HBM:
 
-* ``gated_delta_fwd`` computes the chunk's parts (:func:`_chunk`: the running
-  sums, :func:`_pairs`, the solve by :func:`_inverse`), then carries the
-  state, which stays in a VMEM scratch from chunk to chunk (:func:`_carry`),
-  writes ``o`` and keeps the state entering each chunk for the backward pass.
-* ``gated_delta_bwd`` walks the chunks last first from the states kept,
-  computes each chunk's parts again, carries ``dS`` in VMEM and gives the
-  gradients of all five inputs: through the carry, through the solve (a
-  product with the inverse's transpose) and through the pairwise sums.
+* ``gated_delta_fwd`` / ``gated_delta_head_fwd`` compute the chunk's parts
+  (:func:`_chunk` / :func:`_head_chunk`: the running sums, the pairwise
+  sums, the solve by :func:`_inverse`), then carry the state, which stays in
+  a VMEM scratch from chunk to chunk (:func:`_carry`), write ``o`` and keep
+  the state entering each chunk for the backward pass.
+* ``gated_delta_bwd`` / ``gated_delta_head_bwd`` walk the chunks last first
+  from the states kept, compute each chunk's parts again, carry ``dS`` in
+  VMEM and give the gradients of all five inputs: through the carry, through
+  the solve (a product with the inverse's transpose) and through the
+  pairwise sums (:func:`_channel_grads` / :func:`_head_grads`). Through the
+  per-head decays ``g``'s gradient is one product of ``dD * D`` with the
+  triangle again.
 
-The pairwise sums inside a sub-block are the work of the VPU: there two
-heads' chunks lie side by side on the lanes, channels down the sublanes
-(:func:`_pack`), so that a token's sum over its channels is a sum down the
-sublanes, and a key ``d`` tokens back is a roll of the lanes. The solve
-(:func:`_inverse`) is float32: the unit-lower diagonal blocks of ``SUB`` by
-forward substitution on the VPU, the blocks below joined by products on the
-MXU at float32 precision, ``(I + M)^-1 = (I - M)(I + M^2)`` for the strictly
-block-lower rest ``M``: each such product is six passes of the MXU.
+In the per-channel form the pairwise sums inside a sub-block are the work of
+the VPU: there two heads' chunks lie side by side on the lanes, channels down
+the sublanes (:func:`_pack`), so that a token's sum over its channels is a
+sum down the sublanes, and a key ``d`` tokens back is a roll of the lanes; a
+grid step holds an even number of heads. A per-head grid step holds any
+number of heads, the most up to ``HEAD_STEP`` that divides the heads, so
+nothing is padded. The solve (:func:`_inverse`) is float32: the unit-lower diagonal
+blocks of ``SUB`` by forward substitution on the VPU, the blocks below joined
+by products on the MXU at float32 precision, ``(I + M)^-1 = (I - M)(I +
+M^2)`` for the strictly block-lower rest ``M``: each such product is six
+passes of the MXU.
 
 All of it stands under the caller's part scope (``scope``: by default
 ``phase_kda_core``, which ``benchmark/metrics/kernel.kda_roofline.py`` reads;
-Gated DeltaNet's mixer passes ``phase_gdn_core``); besides the two kernels
-that holds only what XLA does at the call's edge (a head count made even by
-padding, ``beta``'s trailing axis). A decay per head is a ``g`` equal across
-the channels, which is exact here. Off the chip the kernels run in interpret
-mode (``rowdma.on_tpu``).
+Gated DeltaNet's mixer passes ``phase_gdn_core``); besides the kernels that
+holds only what XLA does at the call's edge (a head count made whole steps by
+padding, ``beta``'s trailing axis). The form follows ``g``'s shape alone.
+Off the chip the kernels run in interpret mode (``rowdma.on_tpu``).
 """
 
 from __future__ import annotations
@@ -78,6 +99,7 @@ from swiftsnails_tpu.utils.profiling import part_scope
 CHUNK = 64  # tokens a chunk
 SUB = 16  # tokens a sub-block of the pairwise sums; CHUNK (or a smaller chunk) is a multiple
 HEADS = 8  # heads a grid step at most: the walk's fixed cost a step is paid once for all of them
+HEAD_STEP = 16  # heads a grid step at most in the per-head form, which needs no pairs: Olmo's 15 in one
 CORE_SCOPE = ("kda", "core")  # of ``utils/profiling.PARTS``: the named scope ``phase_kda_core``, the default
 
 _VMEM_LIMIT = 64 * 1024 * 1024
@@ -295,7 +317,7 @@ class _Parts(NamedTuple):
     aqk: jax.Array  # the queries' against the keys, at and below, times scale
     inv: jax.Array  # (I + diag(beta) akk)^-1
     x: jax.Array  # [W | U]
-    pairs: tuple  # (walk, packed rows, strips): what the backward pass of the sums reads
+    pairs: tuple  # what the backward pass of the sums reads: (walk, packed rows, strips); a head's decays D
 
 
 def _chunk(q, k, v, g, beta, scale, sub, dtype):
@@ -315,6 +337,28 @@ def _chunk(q, k, v, g, beta, scale, sub, dtype):
                   akk, aqk * scale, inv, x, (walk, xT, across))
 
 
+def _head_chunk(q, k, v, g, beta, scale, sub, dtype):
+    """A chunk's parts for ``h`` heads with a decay a head: ``q, k [h, C,
+    K]``, ``v [h, C, V]``, ``g, beta [h, C, 1]``. The pairwise sums are one
+    float32 product: the keys' (rows) and the queries' (rows ``C`` on)
+    against the keys, times the decays ``D``."""
+    h, c, _ = q.shape
+    rows, cols = _iota((1, c, c), 1), _iota((1, c, c), 2)
+    lower = jnp.broadcast_to(_ones_where(cols <= rows), (h, c, c))
+    gaps = _exact(lower, jnp.where(rows > cols, jnp.broadcast_to(g, (h, c, c)), 0.0), _NN)  # G_r - G_j
+    run = gaps[:, :, :1] + g[:, :1, :]  # G [h, C, 1]
+    decays = jnp.exp(jnp.where(cols <= rows, gaps, -jnp.inf))
+    kq = jnp.concatenate([k, q], axis=1)
+    both = jnp.concatenate([decays] * 2, axis=1) * _exact(kq, k, _NT)
+    akk, aqk = jnp.where(cols < rows, both[:, :c], 0.0), both[:, c:]
+    last, grow = run[:, c - 1:, :], jnp.exp(run)
+    k_in = k * grow
+    inv = _inverse(beta * akk, sub)
+    x = _exact(inv, beta * jnp.concatenate([k_in, v], axis=2), _NN)
+    return _Parts(run, grow, q * grow * scale, k_in, k * jnp.exp(last - run),
+                  jnp.exp(jnp.broadcast_to(last, (h, 1, q.shape[2]))), akk, aqk * scale, inv, x, decays)
+
+
 def _carry(p, state, dtype):
     """One chunk: the state entering it (transposed, ``[h, V, K]``) -> (its
     outputs ``[h, C, V]``, the state leaving it)."""
@@ -324,27 +368,39 @@ def _carry(p, state, dtype):
     return o, state * p.decay + _dot(fresh, p.k_out, _TN, dtype)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, entering_ref, state_ref, *, scale, sub, dtype):
+def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, entering_ref, state_ref, *, parts, scale, sub, dtype):
     @pl.when(pl.program_id(1) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
     state = state_ref[...]
     entering_ref[...] = state
-    p = _chunk(q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...], scale, sub, dtype)
+    p = parts(q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...], scale, sub, dtype)
     o, state_ref[...] = _carry(p, state, dtype)
     o_ref[...] = o
 
 
+class _Grads(NamedTuple):
+    """The backward pass's gradients of a chunk's parts that the pairwise
+    sums and the decays take over (:func:`_channel_grads`, :func:`_head_grads`)."""
+
+    akk: jax.Array  # of the keys' pairwise sums
+    aqk: jax.Array  # of the queries' against the keys (``_Parts.aqk``, scale in it)
+    q_in: jax.Array
+    k_in: jax.Array
+    k_out: jax.Array
+    decay: jax.Array  # [h, 1, K]
+
+
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, d_state_ref, *, scale, sub, dtype):
+                dq_ref, dk_ref, dv_ref, dg_ref, db_ref, d_state_ref, *, parts, grads, scale, sub, dtype):
     @pl.when(pl.program_id(1) == 0)
     def _():
         d_state_ref[...] = jnp.zeros_like(d_state_ref)
 
     q, k, v, g, beta = q_ref[...], k_ref[...], v_ref[...], g_ref[...], b_ref[...]
     state, d_leaving, do = entering_ref[...], d_state_ref[...], do_ref[...]
-    p = _chunk(q, k, v, g, beta, scale, sub, dtype)
+    p = parts(q, k, v, g, beta, scale, sub, dtype)
     c, width = q.shape[1], q.shape[2]
     w = p.x[..., :width]
     # the carry, backwards
@@ -366,14 +422,44 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, entering_ref, do_ref,
     dv_ref[...] = d_rhs[..., width:]
     d_k_in = d_rhs[..., :width]
     # the parts, backwards
-    (d_k_pairs, d_q_pairs), d_k, d_run = _pairs_bwd((beta * d_a, d_aqk * scale), (k, q), k, *p.pairs, dtype)
-    d_run = d_run + d_q_in * p.q_in + d_k_in * p.k_in - d_k_out * p.k_out
-    d_last = jnp.sum(d_k_out * p.k_out, axis=1, keepdims=True) + d_decay * p.decay
+    grads(p, q, k, _Grads(beta * d_a, d_aqk, d_q_in, d_k_in, d_k_out, d_decay), rows, cols, scale, sub, dtype,
+          dq_ref, dk_ref, dg_ref)
+
+
+def _channel_grads(p, q, k, d, rows, cols, scale, sub, dtype, dq_ref, dk_ref, dg_ref):
+    """A decay a channel: through the walk and the strips of :func:`_pairs`."""
+    c, width = q.shape[1], q.shape[2]
+    (d_k_pairs, d_q_pairs), d_k, d_run = _pairs_bwd((d.akk, d.aqk * scale), (k, q), k, *p.pairs, dtype)
+    d_run = d_run + d.q_in * p.q_in + d.k_in * p.k_in - d.k_out * p.k_out
+    d_last = jnp.sum(d.k_out * p.k_out, axis=1, keepdims=True) + d.decay * p.decay
     d_run = d_run + jnp.where(_iota((1, c, width), 1) == c - 1, d_last, 0.0)
-    dq_ref[...] = d_q_pairs + d_q_in * p.grow * scale
-    dk_ref[...] = d_k + d_k_pairs + d_k_in * p.grow + d_k_out * jnp.exp(p.run[:, c - 1:, :] - p.run)
+    dq_ref[...] = d_q_pairs + d.q_in * p.grow * scale
+    dk_ref[...] = d_k + d_k_pairs + d.k_in * p.grow + d.k_out * jnp.exp(p.run[:, c - 1:, :] - p.run)
     after = jnp.broadcast_to(_ones_where(cols >= rows), (q.shape[0], c, c))
     dg_ref[...] = _exact(after, d_run, _NN)  # the sums from each token to the chunk's end
+
+
+def _head_grads(p, q, k, d, rows, cols, scale, sub, dtype, dq_ref, dk_ref, dg_ref):
+    """A decay a head: through the float32 product of :func:`_head_chunk`
+    and the decays ``D``. ``g_t`` lies in ``G_r - G_j`` for ``j < t <= r``
+    and in ``G_r`` for ``t <= r``: with ``E = dD * D`` and ``dG`` the
+    gradient of the running sums themselves, ``dg_t = sum_(r >= t) (sum_(j <
+    t) E_rj + dG_r)``, one product with the triangle: ``E``'s last column
+    (no gap has it) holds ``dG``."""
+    c = q.shape[1]
+    d_pairs = jnp.concatenate([d.akk, d.aqk * scale], axis=1) * jnp.concatenate([p.pairs] * 2, axis=1)
+    by_row = _exact(d_pairs, k, _NN)  # [dKK K; dQK K]
+    by_key = _exact(d_pairs, jnp.concatenate([k, q], axis=1), _TN)  # dKK^T K + dQK^T Q
+    spread = d.akk * p.akk + d.aqk * p.aqk  # E
+    leaving = jnp.sum(d.k_out * p.k_out, axis=2, keepdims=True)
+    d_run = jnp.sum(d.q_in * p.q_in + d.k_in * p.k_in, axis=2, keepdims=True) - leaving
+    d_last = jnp.sum(leaving, axis=1, keepdims=True) + jnp.sum(d.decay * p.decay, axis=2, keepdims=True)
+    d_run = d_run + jnp.where(_iota((1, c, 1), 1) == c - 1, d_last, 0.0)
+    after = jnp.broadcast_to(_ones_where(cols >= rows), (q.shape[0], c, c))
+    sums = _exact(after, jnp.where(cols == c - 1, d_run, spread), _NN)
+    dg_ref[...] = jnp.sum(jnp.where((cols < rows) | (cols == c - 1), sums, 0.0), axis=2, keepdims=True)
+    dq_ref[...] = by_row[:, c:] + d.q_in * p.grow * scale
+    dk_ref[...] = by_row[:, :c] + by_key + d.k_in * p.grow + d.k_out * jnp.exp(p.run[:, c - 1:, :] - p.run)
 
 
 def _heads(h):
@@ -381,6 +467,32 @@ def _heads(h):
     two heads to the lanes of the inside walk."""
     step = min(HEADS, h + h % 2)
     return step, -(-h // step) * step
+
+
+def _head_steps(h):
+    """(heads a grid step, the heads) of the per-head form: the most up to
+    ``HEAD_STEP`` that divide ``h``, so that no head is padded."""
+    return max(n for n in range(1, min(HEAD_STEP, h) + 1) if h % n == 0), h
+
+
+class _Form(NamedTuple):
+    """A form of the kernels: their names, a chunk's parts, the gradients
+    through them, the grouping of heads."""
+
+    fwd: str
+    bwd: str
+    parts: object
+    grads: object
+    heads: object
+
+
+_CHANNEL = _Form("gated_delta_fwd", "gated_delta_bwd", _chunk, _channel_grads, _heads)
+_HEAD = _Form("gated_delta_head_fwd", "gated_delta_head_bwd", _head_chunk, _head_grads, _head_steps)
+
+
+def _form(g):
+    """The form ``g [H, L, K]`` or ``[H, L, 1]`` (a decay a head) takes."""
+    return _HEAD if g.shape[2] == 1 else _CHANNEL
 
 
 def _pad(a, heads):
@@ -405,15 +517,16 @@ def _specs(step, chunk, n, reverse=False):
 
 def _forward(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
     h, seq, width = q.shape
-    value = v.shape[2]
-    step, heads = _heads(h)
+    value, decays = v.shape[2], g.shape[2]
+    form = _form(g)
+    step, heads = form.heads(h)
     n = seq // chunk
     tokens, state = _specs(step, chunk, n)
     f32 = jnp.float32
     o, entering = _call(
-        functools.partial(_fwd_kernel, scale=scale, sub=sub, dtype=dtype), "gated_delta_fwd",
+        functools.partial(_fwd_kernel, parts=form.parts, scale=scale, sub=sub, dtype=dtype), form.fwd,
         (heads // step, n), interpret,
-        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1)],
+        [tokens(width), tokens(width), tokens(value), tokens(decays), tokens(1)],
         [tokens(value), state(value, width)],
         [jax.ShapeDtypeStruct((heads, seq, value), f32), jax.ShapeDtypeStruct((heads, n, value, width), f32)],
         (step, value, width),
@@ -423,19 +536,20 @@ def _forward(q, k, v, g, beta, chunk, sub, scale, dtype, interpret):
 
 def _backward(q, k, v, g, beta, entering, do, chunk, sub, scale, dtype, interpret):
     h, seq, width = q.shape
-    value = v.shape[2]
-    step, heads = _heads(h)
+    value, decays = v.shape[2], g.shape[2]
+    form = _form(g)
+    step, heads = form.heads(h)
     n = seq // chunk
     tokens, state = _specs(step, chunk, n, reverse=True)
     f32 = jnp.float32
     like = lambda w: jax.ShapeDtypeStruct((heads, seq, w), f32)  # noqa: E731
     grads = _call(
-        functools.partial(_bwd_kernel, scale=scale, sub=sub, dtype=dtype), "gated_delta_bwd",
-        (heads // step, n), interpret,
-        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1), state(value, width),
+        functools.partial(_bwd_kernel, parts=form.parts, grads=form.grads, scale=scale, sub=sub, dtype=dtype),
+        form.bwd, (heads // step, n), interpret,
+        [tokens(width), tokens(width), tokens(value), tokens(decays), tokens(1), state(value, width),
          tokens(value)],
-        [tokens(width), tokens(width), tokens(value), tokens(width), tokens(1)],
-        [like(width), like(width), like(value), like(width), like(1)],
+        [tokens(width), tokens(width), tokens(value), tokens(decays), tokens(1)],
+        [like(width), like(width), like(value), like(decays), like(1)],
         (step, value, width),
     )(*(_pad(a, heads) for a in (q, k, v, g, beta[..., None])), entering, _pad(do, heads))
     dq, dk, dv, dg, dbeta = (a[:h] for a in grads)
@@ -462,18 +576,23 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 
 
 def gated_delta_rule(q, k, v, g, beta, chunk: int = CHUNK, scale=None, dtype=jnp.bfloat16, scope=CORE_SCOPE):
-    """``q, k, g [H, L, K]``, ``v [H, L, V]``, ``beta [H, L]`` -> ``o [H, L,
-    V]`` float32: :func:`recurrence` by chunks of ``chunk`` tokens (``L`` a
-    multiple of it). ``g`` must not be positive. ``scope``: the pair of
-    ``utils/profiling.PARTS`` the call stands under."""
+    """``q, k [H, L, K]``, ``v [H, L, V]``, ``beta [H, L]``, ``g [H, L, K]``
+    (a decay a channel) or ``[H, L]`` / ``[H, L, 1]`` (a decay a head, which
+    takes the per-head kernels) -> ``o [H, L, V]`` float32:
+    :func:`recurrence` by chunks of ``chunk`` tokens (``L`` a multiple of
+    it), with ``g`` broadcast over the channels. ``g`` must not be positive.
+    ``scope``: the pair of ``utils/profiling.PARTS`` the call stands under."""
     heads, seq, width = q.shape
     if seq % chunk:
         raise ValueError(f"{seq} tokens are no whole chunks of {chunk}")
     sub = min(SUB, chunk)
     if chunk % sub:
         raise ValueError(f"a chunk of {chunk} is no whole sub-blocks of {sub}")
-    if k.shape != q.shape or g.shape != q.shape or v.shape[:2] != (heads, seq) or beta.shape != (heads, seq):
-        raise ValueError("q, k, g [H, L, K], v [H, L, V], beta [H, L]")
+    if g.shape == (heads, seq):
+        g = g[..., None]
+    if (k.shape != q.shape or g.shape not in (q.shape, (heads, seq, 1)) or v.shape[:2] != (heads, seq)
+            or beta.shape != (heads, seq)):
+        raise ValueError("q, k [H, L, K], g [H, L, K] or [H, L(, 1)], v [H, L, V], beta [H, L]")
     scale = width ** -0.5 if scale is None else float(scale)
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
     return _rule(f32(q), f32(k), f32(v), f32(g), f32(beta), int(chunk), sub, scale, jnp.dtype(dtype),

@@ -1,9 +1,14 @@
 """The chunked gated delta rule's kernels (``ops/gated_delta.py``, in
 interpret mode) against their own definition, the recurrence a token at a
 time, small and on the CPU: forward and the gradients of all five inputs, for
-one chunk and for three, several heads (an odd count, padded to whole pairs)
-and keys narrower than values, under decays from next to none down to ``g =
--20`` a token (``exp(-G_j)`` alone would overflow float32 inside a chunk)."""
+one chunk and for three, several heads (an odd count, padded to whole pairs
+in the per-channel form, in whole steps in the per-head one) and keys
+narrower than values, under decays from next to none down to ``g = -20`` a
+token (``exp(-G_j)`` alone would overflow float32 inside a chunk); both
+forms, a decay a channel and a decay a head, and the two against each other
+on a decay a head."""
+
+import re
 
 import numpy as np
 import pytest
@@ -18,13 +23,14 @@ from swiftsnails_tpu.ops.gated_delta import (
     CORE_SCOPE, _pairs, gated_delta_flops, gated_delta_rule, recurrence)
 
 
-def _inputs(seq, heads=3, width=16, value_width=16, g_min=-20.0, seed=0):
+def _inputs(seq, heads=3, width=16, value_width=16, g_min=-20.0, seed=0, per_head=False):
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     unit = lambda x: x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731
     q, k = unit(jax.random.normal(ks[0], (heads, seq, width))), unit(jax.random.normal(ks[1], (heads, seq, width)))
     v = jax.random.normal(ks[2], (heads, seq, value_width))
-    # log-uniform between -1e-3 and g_min: slow and fast channels side by side
-    g = -jnp.exp(jax.random.uniform(ks[3], (heads, seq, width), minval=np.log(1e-3), maxval=np.log(-g_min)))
+    # log-uniform between -1e-3 and g_min: slow and fast channels (or heads) side by side
+    g = -jnp.exp(jax.random.uniform(ks[3], (heads, seq) if per_head else (heads, seq, width),
+                                    minval=np.log(1e-3), maxval=np.log(-g_min)))
     beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (heads, seq)))  # (0, 2): negative eigenvalues allowed
     return (q, k, v, g, beta), jax.random.normal(ks[5], (heads, seq, value_width))
 
@@ -43,6 +49,55 @@ def test_chunks_match_the_recurrence_forward_and_backward(seq, chunk, value_widt
     for name, a, b in zip(("q", "k", "v", "g", "beta"), grads(fast), grads(recurrence)):
         assert bool(jnp.isfinite(a).all()), name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-4, err_msg=name)
+
+
+def _spread(g, width):
+    """A decay a head ``[H, L]`` as the same decay on each of ``width`` channels."""
+    return jnp.broadcast_to(g[..., None], g.shape + (width,))
+
+
+@pytest.mark.parametrize("seq,chunk,heads,width,value_width,trailing", [
+    (8, 8, 3, 16, 16, False), (24, 8, 3, 16, 16, True), (192, 64, 3, 16, 16, False), (64, 32, 3, 16, 32, True),
+    (32, 16, 27, 8, 16, False)],
+    ids=["one-chunk", "three-chunks", "chunk-64", "keys-narrower", "odd-heads-in-steps"])
+def test_a_decay_a_head_takes_its_kernels_and_matches_the_recurrence(seq, chunk, heads, width, value_width,
+                                                                      trailing):
+    """``g [H, L]`` (or ``[H, L, 1]``: ``trailing``) runs the per-head
+    kernels, and they are the recurrence with ``g`` on every channel: forward
+    and the gradients of all five inputs (``g``'s summed over the channels),
+    every one finite; 27 heads go nine to a grid step, three steps, none padded."""
+    args, w = _inputs(seq, heads=heads, width=width, value_width=value_width, per_head=True)
+    q, k, v, g, beta = args
+    assert float(g.min()) < -15.0 and float(g.max()) > -0.01
+    assert gated_delta._form(g[..., None]) is gated_delta._HEAD and gated_delta._head_steps(heads) == (min(heads, 9), heads)
+    fast = lambda q, k, v, g, b: gated_delta_rule(  # noqa: E731
+        q, k, v, g[..., None] if trailing else g, b, chunk=chunk, dtype=jnp.float32)
+    slow = lambda q, k, v, g, b: recurrence(q, k, v, _spread(g, width), b)  # noqa: E731
+    got, want = jax.jit(fast)(*args), jax.jit(slow)(*args)
+    assert bool(jnp.isfinite(got).all())
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=1e-4)
+    grads = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args)  # noqa: E731
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), grads(fast), grads(slow)):
+        assert a.shape == b.shape and bool(jnp.isfinite(a).all()), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=1e-4, err_msg=name)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5), (jnp.bfloat16, 1e-2)], ids=["float32", "bfloat16"])
+def test_the_two_forms_agree_on_a_decay_a_head(dtype, tol):
+    """The per-channel kernels given the decay on every channel and the
+    per-head kernels given it once: the same outputs and gradients (``g``'s
+    summed over the channels), to float32's rounding with float32 operands,
+    and within bfloat16's with bfloat16 operands (the per-channel form's
+    pairs between sub-blocks among them; the per-head pairs stay float32)."""
+    args, w = _inputs(128, heads=5, width=16, value_width=32, g_min=-3.0, per_head=True)
+    width = args[0].shape[2]
+    head = lambda q, k, v, g, b: gated_delta_rule(q, k, v, g, b, chunk=64, dtype=dtype)  # noqa: E731
+    channel = lambda q, k, v, g, b: gated_delta_rule(q, k, v, _spread(g, width), b, chunk=64, dtype=dtype)  # noqa: E731
+    rel = lambda a, b: float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))  # noqa: E731
+    assert rel(jax.jit(head)(*args), jax.jit(channel)(*args)) < tol
+    grads = lambda f: jax.jit(jax.grad(lambda *a: jnp.sum(f(*a) * w), argnums=(0, 1, 2, 3, 4)))(*args)  # noqa: E731
+    for name, a, b in zip(("q", "k", "v", "g", "beta"), grads(head), grads(channel)):
+        assert rel(a, b) < tol, (name, rel(a, b))
 
 
 def test_the_state_crosses_chunks_and_a_decay_of_one_keeps_it():
@@ -97,6 +152,8 @@ def test_bfloat16_operands_stay_close_and_shapes_are_checked():
         gated_delta_rule(q[:, :48], k[:, :48], v[:, :48], g[:, :48], beta[:, :48], chunk=24)
     with pytest.raises(ValueError):  # beta is one a token and head
         gated_delta_rule(q, k, v, g, g, chunk=32)
+    with pytest.raises(ValueError):  # g is one a channel, or one a head
+        gated_delta_rule(q, k, v, g[..., :2], beta, chunk=32)
 
 
 def test_operations_are_counted_and_the_core_is_scoped(monkeypatch):
@@ -110,4 +167,10 @@ def test_operations_are_counted_and_the_core_is_scoped(monkeypatch):
     assert "phase_%s_%s" % CORE_SCOPE in text and "tpu_custom_call" in text
     for name in ("gated_delta_fwd", "gated_delta_bwd"):  # the solve is the kernels': no XLA solve is left
         assert name in text, name
-    assert "triangular_solve" not in text
+    assert "triangular_solve" not in text and "gated_delta_head" not in text
+    per_head = jax.jit(jax.grad(lambda *a: jnp.sum(gated_delta_rule(*a, chunk=8) * w), argnums=(0, 3))).trace(
+        *args[:3], args[3][..., 0], args[4]).lower(lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "phase_%s_%s" % CORE_SCOPE in per_head
+    for name in ("gated_delta_head_fwd", "gated_delta_head_bwd"):  # a decay a head: its own pair, and no other
+        assert name in per_head, name
+    assert not re.search(r"gated_delta_(fwd|bwd)", per_head) and "triangular_solve" not in per_head

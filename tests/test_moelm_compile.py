@@ -132,15 +132,19 @@ def test_grouped_products_compile_at_published_widths(one_chip, no_cache, monkey
     assert whole.memory_analysis().temp_size_in_bytes <= unfused.memory_analysis().temp_size_in_bytes
 
 
-@pytest.mark.parametrize("heads,seq,width,value", [(8, 2048, 128, 128), (15, 4096, 96, 192)],
-                         ids=["solar", "olmo-hybrid"])
-def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, monkeypatch, heads, seq, width, value):
+@pytest.mark.parametrize("heads,seq,width,value,per_head", [(8, 2048, 128, 128, False), (15, 4096, 96, 192, False),
+                                                           (15, 4096, 96, 192, True)],
+                         ids=["solar", "olmo-hybrid", "olmo-hybrid-head"])
+def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, monkeypatch, heads, seq, width, value,
+                                                         per_head):
     """Solar-Open2-250B's delta-rule layer as the cell holds it (8 heads of
     128) and Olmo-Hybrid-7B's Gated DeltaNet (15 heads, keys of 96, values of
-    192: blocks no multiple of 128 lanes, an odd head count padded), chunks of
-    64, forward and backward: the two kernels, no XLA solve left, and nothing
-    the size of a state a token (``[8, 2048, 128, 128]`` float32 is 1 GiB) or
-    of every pair's decay is allocated."""
+    192: blocks no multiple of 128 lanes), chunks of 64, forward and
+    backward: the two kernels of the form ``g``'s shape picks (a decay a
+    channel: an odd head count padded; a decay a head, as the mixer passes
+    it: 15 heads in one grid step), no XLA solve left, and nothing the size of a
+    state a token (``[8, 2048, 128, 128]`` float32 is 1 GiB) or of every
+    pair's decay is allocated."""
     from swiftsnails_tpu.ops import gated_delta
     from swiftsnails_tpu.ops.gated_delta import gated_delta_rule
 
@@ -150,13 +154,15 @@ def test_chunked_delta_rule_compiles_at_published_widths(one_chip, no_cache, mon
         return jnp.sum(gated_delta_rule(q, k, v, g, beta) * w)
 
     f32, wide, values = jnp.float32, (heads, seq, width), (heads, seq, value)
+    decays = (heads, seq) if per_head else wide
     compiled = _compiled(jax.grad(loss, (0, 1, 2, 3, 4)), one_chip, (wide, f32), (wide, f32), (values, f32),
-                         (wide, f32), ((heads, seq), f32), (values, f32))
+                         (decays, f32), ((heads, seq), f32), (values, f32))
     kernels = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line]
-    assert len(kernels) == 2 and all("gated_delta_" in line for line in kernels), kernels
+    form = "gated_delta_head_" if per_head else "gated_delta_"
+    assert len(kernels) == 2 and all(form + "fwd" in line or form + "bwd" in line for line in kernels), kernels
     assert "triangular" not in compiled.as_text().lower()
     assert compiled.memory_analysis().temp_size_in_bytes < heads * seq * width * value * 4 / 4
-    assert [o.shape for o in compiled.out_info] == [wide, wide, values, wide, (heads, seq)]
+    assert [o.shape for o in compiled.out_info] == [wide, wide, values, decays, (heads, seq)]
 
 
 # the Wide&Deep cell's pull (212,992 ids into a 4 GiB table of [2, 128]

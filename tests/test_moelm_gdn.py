@@ -17,7 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from swiftsnails_tpu.framework.trainer import TrainLoop
+from swiftsnails_tpu.models import moelm
 from swiftsnails_tpu.models.moelm import MoELMTrainer
+from swiftsnails_tpu.ops import gated_delta
 from swiftsnails_tpu.ops.gated_delta import gated_delta_rule, recurrence
 from swiftsnails_tpu.utils.config import Config, ConfigError
 from swiftsnails_tpu.utils.metrics import MetricsLogger
@@ -137,6 +139,31 @@ def test_gated_deltanet_layer_matches_reference(olmo, order):
     np.testing.assert_allclose(np.asarray(alone), np.asarray(got[tr.seq_len:]), rtol=1e-5, atol=1e-6)
 
 
+def test_the_mixer_hands_the_rule_a_decay_a_head(olmo, monkeypatch):
+    """Gated DeltaNet's mixer gives ``gated_delta_rule`` one decay a head
+    (``[B * h, L, 1]``, no broadcast over the key channels), so the per-head
+    kernels run; the layer still matches the reference, and the mean decay
+    is the one the decay on every channel reads."""
+    tr, keys = _trainer()
+    p = dict(_layer(tr.init_state()["params"]["gdn"], 2))
+    x = _x(tr, seed=5)
+    handed = []
+
+    def rule(q, k, v, g, beta, **kw):
+        handed.append((q, g))
+        return gated_delta_rule(q, k, v, g, beta, **kw)
+
+    monkeypatch.setattr(moelm, "gated_delta_rule", rule)
+    got, decay = tr._kda(p, x, tr.batch_size, "gdn")
+    (q, g), = handed
+    assert g.shape == q.shape[:2] + (1,) and q.shape == (tr.batch_size * 2, tr.seq_len, 16)
+    assert gated_delta._form(g) is gated_delta._HEAD
+    want = _rows(tr, lambda r: olmo.reference_math(keys).gdn(p, r), x)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-5)
+    spread = np.mean(np.exp(np.broadcast_to(np.asarray(g, np.float64), q.shape)))  # on every channel, as before
+    assert float(decay) == pytest.approx(spread, rel=1e-5) and 0.5 < float(decay) < 1.0  # a float32 sum's rounding
+
+
 def test_softmax_layer_with_whole_width_norms_matches_reference(olmo):
     tr, keys = _trainer()
     math = olmo.reference_math(keys)
@@ -250,9 +277,9 @@ def test_the_loop_runs_a_dense_stack_and_the_step_is_scoped(tmp_path):
 @pytest.mark.parametrize("seq,chunk", [(32, 16), (128, 64)], ids=["two-chunks", "chunk-64"])
 def test_chunked_rule_with_a_decay_a_head_and_keys_narrower_than_values(seq, chunk):
     """``gated_delta_rule`` (interpret mode) with ``g`` equal across a
-    head's key channels, K 16 and V 32, against the recurrence, forward and
-    the gradients of all five inputs, ``g``'s summed over the channels as
-    the mixer's broadcast sums it."""
+    head's key channels (the per-channel kernels on a decay a head), K 16
+    and V 32, against the recurrence, forward and the gradients of all five
+    inputs, ``g``'s summed over the channels as a broadcast sums it."""
     heads, width, value = 3, 16, 32
     ks = jax.random.split(jax.random.PRNGKey(1), 6)
     unit = lambda x: x / jnp.sqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)  # noqa: E731

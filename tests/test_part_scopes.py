@@ -8,7 +8,7 @@ parts are metadata (without them the lowered module is the same text).
 The steps are lowered for a TPU (no chip needed, nothing is compiled), so
 the kernels are Mosaic calls and not their interpreted bodies (the delta
 rule's two stand under ``phase_kda_core`` in all three passes, or under
-``phase_gdn_core`` where Gated DeltaNet calls them); five tiny models: latent
+``phase_gdn_core`` where Gated DeltaNet calls its per-head pair); five tiny models: latent
 attention over a dense and two mixture layers (Moonlight's kind), grouped
 queries under the block-diffusion mask (SDAR's), the layer table with
 delta-rule layers and a gated softmax layer (Solar's), grouped queries under
@@ -39,6 +39,7 @@ MODELS = {"mla": test_moelm, "gqa_bd": test_moelm_diffusion, "kda": test_moelm_k
           "sparse": types.SimpleNamespace(_trainer=lambda **o: test_moelm_sparse._trainer(**{**_TILES_256, **o})),
           "gdn": test_moelm_gdn}
 DELTA = {"kda": "kda", "gdn": "gdn"}  # the models whose delta rule stands under ``phase_<it>_core``
+DELTA_KERNELS = {"kda": "gated_delta_", "gdn": "gated_delta_head_"}  # the kernels' names before ``fwd`` / ``bwd``
 PASSES = ("forward", "rematerialised", "backward")
 _TOKEN = re.compile(r"phase_([a-z]+)(?:_([a-z]+))?")
 
@@ -132,11 +133,12 @@ def test_kernels_stand_under_the_part_that_is_theirs(lowered, family):
         if kind not in DELTA:
             assert not calls, calls
             return
+        form = DELTA_KERNELS[kind]  # a decay a channel (KDA) or a head (Gated DeltaNet): the form's own pair
         names = {re.search(r"gated_delta_\w+", p).group(0) for p in calls}
-        assert names == {"gated_delta_fwd", "gated_delta_bwd"}
+        assert names == {form + "fwd", form + "bwd"}
         assert all(_TOKEN.findall(p)[-1] == (DELTA[kind], "core") for p in calls), calls
-        assert {_pass_of(p) for p in calls if "gated_delta_fwd" in p} == {"forward", "rematerialised"}
-        assert {_pass_of(p) for p in calls if "gated_delta_bwd" in p} == {"backward"}
+        assert {_pass_of(p) for p in calls if form + "fwd" in p} == {"forward", "rematerialised"}
+        assert {_pass_of(p) for p in calls if form + "bwd" in p} == {"backward"}
         return
     if family == "flash_attention":
         names = {re.search(r"flash_attention_\w+", p).group(0) for p in calls}
